@@ -1,13 +1,18 @@
 """ctypes binding for the C++ socket shuttle, with a pure-Python fallback.
 
-Builds ``native/shuttle.cpp`` on first use (g++ -O2 -shared -fPIC); when the
-toolchain or build is unavailable the Python implementation (threads +
-stdlib sockets — IO releases the GIL anyway, but framing runs in Python)
-keeps everything working.
+Builds ``native/shuttle.cpp`` on first use (g++ -O2 -shared -fPIC) into a
+file named by a hash of the source, so a binary built from other source (or
+copied in from another checkout) is never loaded. When the toolchain or
+build is unavailable the Python implementation (threads + stdlib sockets —
+IO releases the GIL anyway, but framing runs in Python) keeps everything
+working; which of the two planes a process runs is logged once and
+published as the ``distar_shuttle_native`` gauge.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import socket
 import struct
@@ -18,29 +23,50 @@ from typing import Optional, Tuple
 
 _DIR = os.path.dirname(__file__)
 _SRC = os.path.join(_DIR, "native", "shuttle.cpp")
-_SO = os.path.join(_DIR, "native", "libshuttle.so")
-
 _lib = None
+_lib_tried = False
 _lib_lock = threading.Lock()
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib
-    with _lib_lock:
-        if _lib is not None:
-            return _lib
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            try:
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", _SO, _SRC, "-lpthread"],
-                    check=True,
-                    capture_output=True,
-                )
-            except (OSError, subprocess.CalledProcessError):
-                return None
+def _build() -> Optional[ctypes.CDLL]:
+    with open(_SRC, "rb") as f:
+        key = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_DIR, "native", f"libshuttle-{key}.so")
+    if not os.path.exists(so):
+        # built aside, then renamed: a crashed or concurrent build never
+        # leaves a half-written file under the keyed name
+        tmp = f"{so}.{os.getpid()}.tmp"
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            subprocess.run(
+                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC, "-lpthread"],
+                check=True,
+                capture_output=True,
+            )
+            os.replace(tmp, so)
+        except (OSError, subprocess.CalledProcessError):
+            return None
+    try:
+        return ctypes.CDLL(so)
+    except OSError:
+        return None
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    global _lib, _lib_tried
+    with _lib_lock:
+        if _lib_tried:
+            return _lib
+        _lib_tried = True
+        lib = _build()
+        from ..obs import get_registry
+
+        get_registry().gauge(
+            "distar_shuttle_native",
+            "1 = the C++ shuttle plane is loaded, 0 = the Python fallback runs",
+        ).set(0.0 if lib is None else 1.0)
+        logging.getLogger(__name__).info(
+            "shuttle plane: %s", "python fallback" if lib is None else "native")
+        if lib is None:
             return None
         lib.shuttle_serve.argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_uint64, ctypes.c_int, ctypes.c_int,

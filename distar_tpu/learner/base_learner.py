@@ -75,7 +75,7 @@ DEFAULT_LEARNER_CONFIG = Config(
             # for the static memory_analysis footprint (cache-served when
             # the live step already compiled)
             "perf": {"aot": "auto", "aot_compile": False,
-                     "mem_sample_every": 16},
+                     "mem_sample_every": 1},
             # training-dynamics observatory (obs/dynamics.py): the in-jit
             # diagnostics tree is computed every step; every_n gates gauge
             # EXPORT; anomalies (non-finite loss/grads, grad explosion,
@@ -98,6 +98,11 @@ class BaseLearner:
         self.logger, self.scalar_sink, self.variable_record = build_logger(
             os.path.join(root, "logs"), f"{self.name}_rank{self.rank}", to_console=self.rank == 0
         )
+        dev = jax.devices()[0]
+        self.logger.info(
+            f"devices: platform={dev.platform} device_kind={dev.device_kind!r} "
+            f"count={jax.device_count()}"
+        )
         self.timer = EasyTimer()
         self.last_iter = CountVar(0)
         self._checkpointer = AsyncCheckpointer()
@@ -107,6 +112,10 @@ class BaseLearner:
         )
         self.log_buffer: Dict[str, Any] = {}
         self.metrics = get_registry()
+        self.metrics.gauge(
+            "distar_device_count", "jax devices this process sees",
+            platform=dev.platform, kind=dev.device_kind,
+        ).set(jax.device_count())
         prof = self.cfg.learner.get("profile", {})
         self.hooks: HookRegistry = default_hooks(
             save_freq=self.cfg.learner.save_freq,
@@ -125,7 +134,7 @@ class BaseLearner:
             token=self.name,
             registry=self.metrics,
             aot_compile=bool(pcfg.get("aot_compile", False)),
-            mem_sample_every=int(pcfg.get("mem_sample_every", 16)),
+            mem_sample_every=int(pcfg.get("mem_sample_every", 1)),
         )
         self._dynamics = DynamicsMonitor(
             dict(self.cfg.learner.get("dynamics", {}) or {}),
@@ -524,6 +533,7 @@ class BaseLearner:
                     data = next(self._dataloader)
                 t_data = self.timer.value
                 self.log_buffer["data_time"] = t_data
+                self._perf.note_batch(data)
                 self.hooks.call("before_iter", self)
                 # stash aux refs (e.g. the SL pre-step hidden carry) so an
                 # anomaly bundle can reconstruct the step's exact inputs
@@ -544,14 +554,17 @@ class BaseLearner:
                 # anomaly writes a black-box bundle
                 self._dynamics.on_step(self, log_vars, data)
                 self.last_iter.add(1)
+                # before the hooks, so the metrics export among them carries
+                # THIS iteration's step time and memory sample
+                iters_total.inc()
+                step_time.observe(t_train)
+                data_wait.observe(t_data)
+                self._perf.on_step(t_train, frames_per_iter)
                 # host-callback phase = everything after the device step:
                 # hook pass (log reduction, checkpoint scheduling, weight
                 # publication) — the third leg of the step breakdown
                 with self.timer:
                     self.hooks.call("after_iter", self)
-                iters_total.inc()
-                step_time.observe(t_train)
-                data_wait.observe(t_data)
                 record_step_phases(
                     {
                         "data_wait": t_data,
@@ -560,7 +573,6 @@ class BaseLearner:
                     },
                     registry=self.metrics,
                 )
-                self._perf.on_step(t_train, frames_per_iter)
                 self._profile_tick()
             self.hooks.call("after_run", self)
 

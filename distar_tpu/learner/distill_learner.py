@@ -252,10 +252,10 @@ class DistillLearner(BaseLearner):
         data.pop("trace_age_s", None)
         data = self._strip_batch(self._cap(data))
         batch = jax.tree.map(jnp.asarray, data)
-        self._perf_note_step_args(
-            self._train_step, self._state["params"], self._state["opt_state"], batch)
         params, opt_state, info = self._train_step(
             self._state["params"], self._state["opt_state"], batch)
+        # after the call: the background flop count re-uses this trace
+        self._perf_note_step_args(self._train_step, params, opt_state, batch)
         self._state = {"params": params, "opt_state": opt_state}
         log = {k: float(v) for k, v in jax.device_get(info).items()}
         self._g_kl.set(log["divergence"])

@@ -114,6 +114,7 @@ class RLDataLoader:
         # sleeps in cond.wait instead of a 5 ms busy-poll (the timeout is a
         # liveness backstop, not the wake mechanism)
         self._cond = threading.Condition()
+        self._failed: Optional[BaseException] = None
         # keep_trace: the loop leaves spans open so THIS consumer records the
         # terminal hop (cache entries are (traj, trace_ctx) tuples)
         self._cache = adapter.start_pull_loop(
@@ -148,6 +149,18 @@ class RLDataLoader:
         rl_learner.py:90-101)."""
         return self.buffered() / max(self._cache_size, 1)
 
+    def fail(self, error: BaseException) -> None:
+        """The producers are gone for good: make the consumer blocked in (or
+        next entering) ``__next__`` raise ``error`` instead of waiting for
+        trajectories that will never come."""
+        with self._cond:
+            self._failed = error
+            self._cond.notify_all()
+
+    def close(self) -> None:
+        """End the iteration: a consumer blocked in ``__next__`` stops."""
+        self.fail(StopIteration())
+
     def __iter__(self) -> Iterator[Dict]:
         return self
 
@@ -156,6 +169,8 @@ class RLDataLoader:
         traces: List[Optional[dict]] = []
         waited_s = 0.0
         while len(trajs) < self._batch_size:
+            if self._failed is not None:
+                raise self._failed
             if self._cache:
                 traj, ctx = self._cache.popleft()
                 trajs.append(traj)
@@ -165,7 +180,9 @@ class RLDataLoader:
                 # busy-polling; the timeout only bounds a missed notify
                 t0 = time.monotonic()
                 with self._cond:
-                    self._cond.wait_for(lambda: bool(self._cache), timeout=0.5)
+                    self._cond.wait_for(
+                        lambda: bool(self._cache) or self._failed is not None,
+                        timeout=0.5)
                 waited_s += time.monotonic() - t0
         self._m_wait.observe(waited_s)
         # close out the actor-minted pipeline spans: the batch reaching the

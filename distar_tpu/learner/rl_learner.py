@@ -196,8 +196,7 @@ class RLLearner(BaseLearner):
         set_context_mesh(self.mesh)  # ring attention resolves sp at trace time
         batch = self._cap(next(self._dataloader))
         self.optimizer = self._build_optimizer()
-        # jit the init: eager init dispatches thousands of tiny ops, which is
-        # painfully slow on a remote/tunneled device
+        # jit the init: eager init dispatches thousands of tiny ops
         def init_fn(rng, spatial, entity, scalar, entity_num, hidden, action, sun, vf):
             return self.model.init(
                 rng, spatial, entity, scalar, entity_num, hidden, action, sun, B, T,
@@ -270,6 +269,7 @@ class RLLearner(BaseLearner):
         # (obs/perf.py) — the sanity bar a trace's collective bucket is read
         # against
         self._perf.set_collectives(self.mesh, self._state["params"])
+        self._perf.set_state_bytes(self._state)
 
     def shard_batch(self, batch):
         """Place a host batch onto the mesh: B sharded over dp everywhere
@@ -463,15 +463,14 @@ class RLLearner(BaseLearner):
         trace_age = data.pop("trace_age_s", None)
         if not on_device:
             data = self.shard_batch(self._cap(data))
-        self._perf_note_step_args(
-            self._train_step,
-            self._state["params"], self._state["opt_state"], data,
-            jnp.asarray(only_value),
-        )
         params, opt_state, info = self._train_step(
             self._state["params"], self._state["opt_state"], data,
             jnp.asarray(only_value),
         )
+        # after the call (the new state has the donated one's types): the
+        # background flop count then re-uses this trace instead of racing it
+        self._perf_note_step_args(
+            self._train_step, params, opt_state, data, jnp.asarray(only_value))
         self._state = {"params": params, "opt_state": opt_state}
         # one batched D2H transfer — per-scalar float() would round-trip
         # once per metric across the ~60-entry loss grid every iteration

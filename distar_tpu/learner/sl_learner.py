@@ -166,6 +166,10 @@ class SLLearner(BaseLearner):
         # not an opaque XLA sharding error on the first step
         flat_sh = batch_sharding(self.mesh, batch_size=B)
         self._shardings = dict(repl=repl, param=param_sh, opt=opt_sh, flat=flat_sh)
+        # the carry the step is first called with must be typed like the one
+        # it hands back (out_shardings below), or the second iteration
+        # re-traces and re-compiles the whole step
+        self._hidden = jax.device_put(self._hidden, flat_sh)
         self._train_step = jax.jit(
             make_sl_train_step(
                 self.model, self.loss_cfg, self.optimizer, B,
@@ -180,6 +184,7 @@ class SLLearner(BaseLearner):
         )
         # analytic per-step collective estimate (obs/perf.py)
         self._perf.set_collectives(self.mesh, self._state["params"])
+        self._perf.set_state_bytes(self._state)
 
     def evaluate(self, dataloader, max_batches: int = 0) -> Dict[str, float]:
         """Held-out metric pass: run the SL forward + loss/metric grid over
@@ -283,13 +288,13 @@ class SLLearner(BaseLearner):
                 "new_episodes": new_episodes,
                 "traj_lens": traj_lens,
             }
-        self._perf_note_step_args(
-            self._train_step,
-            self._state["params"], self._state["opt_state"], data, self._hidden,
-        )
         params, opt_state, out_state, info = self._train_step(
             self._state["params"], self._state["opt_state"], data, self._hidden
         )
+        # after the call (the new state has the donated one's types): the
+        # background flop count then re-uses this trace instead of racing it
+        self._perf_note_step_args(
+            self._train_step, params, opt_state, data, self._hidden)
         self._state = {"params": params, "opt_state": opt_state}
         self._hidden = jax.tree.map(jax.lax.stop_gradient, out_state)
         # one batched D2H transfer instead of a round-trip per metric

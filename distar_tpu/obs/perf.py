@@ -4,10 +4,10 @@ One code path for the three consumers of XLA's cost and memory
 introspection (previously bench.py, tools/memstats.py and the learner each
 did their own): ``flops_of_lowered``/``flops_of_compiled`` extract flop
 counts, ``memory_report`` normalises ``memory_analysis()``, ``peak_flops``
-maps a device kind to its datasheet bf16 peak — and ``PerfMonitor`` turns
-them into the live ``distar_perf_*`` gauges the BaseLearner run loop
-publishes every iteration, so the PR 3 telemetry pipeline (TSDB, shipper,
-health rules) sees MFU and HBM fleet-wide.
+looks a device kind up in the datasheet bf16 peak table — and
+``PerfMonitor`` turns them into the live ``distar_perf_*`` gauges the
+BaseLearner run loop publishes every iteration, so the PR 3 telemetry
+pipeline (TSDB, shipper, health rules) sees MFU and HBM fleet-wide.
 
 jax is imported lazily (importing obs never imports jax); everything here
 is best-effort — a backend without cost/memory introspection degrades to
@@ -21,28 +21,35 @@ from typing import Dict, Optional
 
 from .registry import MetricsRegistry, get_registry
 
-# peak bf16 matmul throughput per chip, for the MFU estimate (the table
-# bench.py's headline MFU and the impossible-timing recheck both key off)
+# peak bf16 matmul throughput per chip, keyed by the EXACT
+# ``device.device_kind`` string jax reports. Sources: Google Cloud TPU
+# documentation, system-architecture pages "TPU v4" (275 TFLOP/s), "TPU v5e"
+# (197), "TPU v5p" (459), "TPU v6e" (918). Each generation is listed under
+# both spellings jax's own sources use for it across libtpu versions.
 PEAK_FLOPS: Dict[str, float] = {
-    "v4": 275e12,
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v5": 459e12,
-    "v6 lite": 918e12,
-    "v6e": 918e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
 }
 
 
 def peak_flops(device_kind: str) -> Optional[float]:
-    """Datasheet bf16 peak for a ``device.device_kind`` string (longest
-    matching table entry wins), or None for unknown kinds (CPU hosts)."""
-    kind = (device_kind or "").lower()
-    best = None
-    for name, peak in PEAK_FLOPS.items():
-        if name in kind and (best is None or len(name) > best[0]):
-            best = (len(name), peak)
-    return best[1] if best else None
+    """Datasheet bf16 peak for a ``device.device_kind`` string. A device
+    that is not a TPU (CPU hosts) has no peak: None. A TPU kind missing from
+    the table is an error, not a default — an MFU against a guessed peak, or
+    one that silently vanishes, is worse than none."""
+    kind = device_kind or ""
+    if kind in PEAK_FLOPS:
+        return PEAK_FLOPS[kind]
+    if kind.upper().startswith("TPU"):
+        raise KeyError(
+            f"unknown TPU device_kind {kind!r}: add its datasheet peak to "
+            "obs.perf.PEAK_FLOPS with the source")
+    return None
 
 
 def flops_of_lowered(lowered) -> float:
@@ -127,6 +134,20 @@ def estimate_collective_bytes(mesh, params) -> Dict[str, float]:
     return out
 
 
+def shard_bytes_by_device(tree) -> Dict[str, float]:
+    """Bytes each device really holds of a pytree of jax arrays, summed over
+    ``addressable_shards`` (``platform:id`` -> bytes). Host leaves count
+    nowhere."""
+    import jax
+
+    out: Dict[str, float] = {}
+    for x in jax.tree.leaves(tree):
+        for shard in getattr(x, "addressable_shards", ()):
+            label = f"{shard.device.platform}:{shard.device.id}"
+            out[label] = out.get(label, 0.0) + shard.data.nbytes
+    return out
+
+
 class PerfMonitor:
     """Per-learner live perf gauges.
 
@@ -139,13 +160,14 @@ class PerfMonitor:
     """
 
     def __init__(self, token: str, registry: Optional[MetricsRegistry] = None,
-                 aot_compile: bool = False, mem_sample_every: int = 16):
+                 aot_compile: bool = False, mem_sample_every: int = 1):
         self._registry = registry or get_registry()
         self._token = token
         self._aot_compile = aot_compile
         self._mem_sample_every = max(1, int(mem_sample_every))
         self._lock = threading.Lock()
         self._analysis_started = False
+        self._batch_noted = False
         self._steps_seen = 0
         self.flops_per_step = 0.0
         self.peak: Optional[float] = None
@@ -170,7 +192,8 @@ class PerfMonitor:
 
     # ------------------------------------------------------------- AOT side
     def note_step_args(self, jitted, *args) -> None:
-        """First-iteration hook: snapshot shape specs of the step args and
+        """First-iteration hook, called AFTER the step's first call with
+        arguments typed like the ones it got: snapshot their specs and
         extract flops (and, with ``aot_compile``, the static HBM footprint)
         in the background. Idempotent; never raises into the train loop."""
         with self._lock:
@@ -180,8 +203,11 @@ class PerfMonitor:
         try:
             import jax
 
+            # with the shardings: the types then match the live call's, so
+            # lower() finds its trace in jit's cache instead of re-tracing
             specs = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, "sharding", None))
                 if hasattr(x, "shape") and hasattr(x, "dtype") else x,
                 args,
             )
@@ -197,6 +223,7 @@ class PerfMonitor:
         try:
             import jax
 
+            # an unknown TPU kind raises into the logged failure below
             self.peak = peak_flops(jax.devices()[0].device_kind)
             lowered = jitted.lower(*specs)
             flops = flops_of_lowered(lowered)
@@ -237,7 +264,7 @@ class PerfMonitor:
                 self._g_mfu.set(vals["mfu"])
         self.last = vals
         self._steps_seen += 1
-        if self._steps_seen % self._mem_sample_every == 1:
+        if (self._steps_seen - 1) % self._mem_sample_every == 0:
             self.sample_memory()
 
     def sample_memory(self) -> None:
@@ -265,6 +292,28 @@ class PerfMonitor:
                     ).set(float(peak))
         except Exception:
             self._c_fail.inc()
+
+    def set_state_bytes(self, state) -> None:
+        """Publish where the train state (params + optimizer state) lives:
+        bytes per device, from the arrays' own shards."""
+        for device, n in shard_bytes_by_device(state).items():
+            self._registry.gauge(
+                "distar_perf_state_bytes",
+                "parameter + optimizer-state bytes held by each device",
+                token=self._token, device=device,
+            ).set(n)
+
+    def note_batch(self, batch) -> None:
+        """First placed batch only: bytes of it each device holds."""
+        if self._batch_noted:
+            return
+        self._batch_noted = True
+        for device, n in shard_bytes_by_device(batch).items():
+            self._registry.gauge(
+                "distar_perf_batch_bytes",
+                "bytes of one placed training batch held by each device",
+                token=self._token, device=device,
+            ).set(n)
 
     def set_collectives(self, mesh, params) -> None:
         """Publish the analytic per-step collective estimate for this

@@ -9,24 +9,28 @@ attention):
   and the value matmul all stay in VMEM; both matmuls hit the MXU at
   (512 x 64/128) tiles. Saves the HBM round-trips XLA's unfused
   mask->softmax->matmul chain can incur at small batch.
-* ``scatter_add_connection`` — per-batch scatter-add of entity embeddings
-  into the flattened (H*W, D) map via a fori_loop of dynamic row updates
-  (entity count is static at 512; padding rows write via a validity mask to
-  row 0 with zero weight).
-* ``scatter_add_onehot`` — the same scatter-add as a chunked one-hot
-  matmul: the [N, chunk] one-hot tile is built in VMEM (iota-compare) and
-  consumed by the MXU, replacing the loop kernel's serial row updates.
+* ``scatter_add_onehot`` — per-batch scatter-add of entity embeddings into
+  the flattened (H*W, D) map as a chunked one-hot matmul: the [N, chunk]
+  one-hot tile is built in VMEM (iota-compare) and consumed by the MXU.
 
-All run under ``interpret=True`` on CPU (tests compare against the jnp
-reference implementations) and lower natively on TPU. Enable via
-``attn_impl='pallas'`` on ops.Transformer (model config key
-``encoder.entity.attention_impl``) and ``impl='pallas'|'pallas_onehot'``
-on ops.scatter_connection; defaults should follow
-``tools/bench_kernels.py``'s on-silicon table.
+Both compile for a v5e at flagship shapes in bf16 and f32, forward and
+grad (``tests/test_tpu_compile.py``), and run natively on a TPU; everywhere
+else ``resolve_interpret`` puts them in interpret mode, says so once at
+warning level and counts it (``distar_pallas_interpret_fallbacks_total``),
+so a run that was meant for the chip can fail on a non-zero count. There is no loop-of-row-
+updates scatter kernel: the TPU compiler refuses a dynamic single-row
+update on a packed bf16 tile, and the whole (H*W, D) output tile it needs
+per program does not fit VMEM at the learner's B*T.
+
+Enable via ``attn_impl='pallas'`` on ops.Transformer (model config key
+``encoder.entity.attention_impl``) and ``impl='pallas_onehot'`` on
+ops.scatter_connection. The defaults stay ``xla`` until an on-chip A/B
+decides them (ROADMAP D2).
 """
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -34,7 +38,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import get_registry
+
 NEG_INF = -1e9
+
+
+def interpret_fallbacks():
+    """Counter of pallas_calls traced in interpret mode because the backend
+    was not a TPU (an explicit ``interpret=`` argument is the caller's choice
+    and is not counted)."""
+    return get_registry().counter(
+        "distar_pallas_interpret_fallbacks_total",
+        "pallas_calls traced in interpret mode because the backend is not a TPU",
+    )
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """The one place that picks interpret mode: an explicit argument wins;
+    ``None`` means native on a TPU backend and interpret anywhere else."""
+    if interpret is not None:
+        return interpret
+    if jax.default_backend() == "tpu":
+        return False
+    counter = interpret_fallbacks()
+    if not counter.value:
+        logging.getLogger(__name__).warning(
+            "Pallas kernels run in INTERPRET mode: backend is %r, not tpu",
+            jax.default_backend(),
+        )
+    counter.inc()
+    return True
 
 
 # --------------------------------------------------------------- attention
@@ -75,8 +108,7 @@ def masked_attention(
 
 
 def _masked_attention_fwd_kernel(q, k, v, mask, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, H, N, Dh = q.shape
     scale = 1.0 / (Dh ** 0.5)
     mask2 = mask[:, None, None, :].astype(jnp.float32)  # [B, 1, 1, N]
@@ -138,78 +170,6 @@ def masked_attention_reference(q, k, v, mask):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-# ----------------------------------------------------------------- scatter
-def _scatter_kernel(emb_ref, idx_ref, out_ref, *, n_entities: int):
-    # zero the output tile, then accumulate entity rows at dynamic offsets.
-    # idx lives in SMEM: scalar reads that drive dynamic slices belong there
-    # (and VMEM's (8, 128) block-tiling rule doesn't apply to SMEM blocks).
-    out_ref[0] = jnp.zeros_like(out_ref[0])
-
-    def body(i, _):
-        row = idx_ref[0, i]  # flat cell index (already validity-masked)
-        out_ref[0, pl.ds(row, 1), :] += emb_ref[0, pl.ds(i, 1), :]
-        return 0
-
-    jax.lax.fori_loop(0, n_entities, body, 0)
-
-
-def scatter_add_connection(
-    embeddings: jnp.ndarray,  # [B, N, D] (invalid entities must be zeroed)
-    flat_idx: jnp.ndarray,  # [B, N] int cell index (clipped to [0, H*W))
-    hw: int,
-    interpret: Optional[bool] = None,  # None: native on TPU, interpret elsewhere
-) -> jnp.ndarray:
-    """Per-batch scatter-add; returns [B, H*W, D]. Differentiable: the
-    scatter-add's VJP w.r.t. embeddings is a plain gather of the output
-    cotangent at the same indices (XLA backward).
-
-    Out-of-range indices are CLIPPED to [0, hw-1] here, in the public
-    wrapper — identical semantics to ``scatter_add_onehot`` by construction,
-    so switching ``impl`` strings can never silently change forward or
-    gradient behaviour (the kernels themselves used to disagree: ``pl.ds``
-    clamped where the one-hot matmul dropped)."""
-    flat_idx = jnp.clip(flat_idx.astype(jnp.int32), 0, hw - 1)
-    return _scatter_add_connection_core(embeddings, flat_idx, hw, interpret)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _scatter_add_connection_core(embeddings, flat_idx, hw, interpret):
-    return _scatter_add_fwd_kernel(embeddings, flat_idx, hw, interpret)
-
-
-def _scatter_add_fwd_kernel(embeddings, flat_idx, hw, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, N, D = embeddings.shape
-
-    return pl.pallas_call(
-        functools.partial(_scatter_kernel, n_entities=N),
-        out_shape=jax.ShapeDtypeStruct((B, hw, D), embeddings.dtype),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, N, D), lambda b: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, N), lambda b: (b, 0), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, hw, D), lambda b: (b, 0, 0), memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )(embeddings, flat_idx.astype(jnp.int32))
-
-
-def _scatter_add_vjp_fwd(embeddings, flat_idx, hw, interpret):
-    return _scatter_add_fwd_kernel(embeddings, flat_idx, hw, interpret), flat_idx
-
-
-def _scatter_add_vjp_bwd(hw, interpret, flat_idx, dout):
-    # d(embeddings)[b, n] = dout[b, idx[b, n]] (idx pre-clipped by the wrapper)
-    demb = jnp.take_along_axis(
-        dout, flat_idx.astype(jnp.int32)[..., None].clip(0, hw - 1), axis=1
-    )
-    return demb, None
-
-
-_scatter_add_connection_core.defvjp(_scatter_add_vjp_fwd, _scatter_add_vjp_bwd)
-
-
 # ------------------------------------------------- scatter via one-hot matmul
 def _scatter_onehot_kernel(emb_ref, idx_ref, out_ref, *, chunk: int):
     # out[cells] = onehot(idx)^T @ emb for this (batch, cell-chunk) tile.
@@ -235,12 +195,10 @@ def scatter_add_onehot(
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Per-batch scatter-add as a chunked one-hot matmul ([B, hw, D]).
-    Out-of-range indices are CLIPPED to [0, hw-1] in this public wrapper —
-    the same clamp as ``scatter_add_connection``, so forward AND gradient
-    semantics are identical across ``impl`` strings (the raw one-hot kernel
-    would otherwise DROP out-of-range rows where the loop kernel clamps).
-    Trades `2*N*hw*D` MXU FLOPs for the serial dynamic-row updates of the
-    loop kernel; gather backward."""
+    Out-of-range indices are CLIPPED to [0, hw-1] in this public wrapper
+    (the raw one-hot kernel would DROP out-of-range rows), which is what
+    ``ops.scatter_connection`` does before either ``impl``. Costs
+    `2*N*hw*D` MXU FLOPs; gather backward."""
     flat_idx = jnp.clip(flat_idx.astype(jnp.int32), 0, hw - 1)
     return _scatter_add_onehot_core(embeddings, flat_idx, hw, interpret)
 
@@ -251,8 +209,7 @@ def _scatter_add_onehot_core(embeddings, flat_idx, hw, interpret):
 
 
 def _scatter_onehot_fwd_kernel(embeddings, flat_idx, hw, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, N, D = embeddings.shape
     # cell chunk per program: big enough to amortise the emb reload, small
     # enough that the [N, chunk] one-hot tile stays comfortably in VMEM
@@ -285,9 +242,8 @@ def _scatter_onehot_vjp_fwd(embeddings, flat_idx, hw, interpret):
 
 
 def _scatter_onehot_vjp_bwd(hw, interpret, flat_idx, dout):
-    # indices reach the core pre-clipped by the public wrapper, so the
-    # gather backward matches the loop kernel's exactly; the in_range guard
-    # stays for direct core callers
+    # indices reach the core pre-clipped by the public wrapper; the in_range
+    # guard stays for direct core callers
     idx = flat_idx.astype(jnp.int32)
     in_range = (idx >= 0) & (idx < hw)
     demb = jnp.take_along_axis(dout, idx[..., None].clip(0, hw - 1), axis=1)
@@ -295,3 +251,14 @@ def _scatter_onehot_vjp_bwd(hw, interpret, flat_idx, dout):
 
 
 _scatter_add_onehot_core.defvjp(_scatter_onehot_vjp_fwd, _scatter_onehot_vjp_bwd)
+
+
+def scatter_add_reference(embeddings, flat_idx, hw: int):
+    """jnp oracle with identical semantics (the math of
+    ``ops.scatter_connection``'s XLA add path on pre-flattened indices)."""
+    B, N, D = embeddings.shape
+    flat_idx = jnp.clip(flat_idx.astype(jnp.int32), 0, hw - 1)
+    bias = jnp.arange(B, dtype=jnp.int32)[:, None] * hw
+    buf = jnp.zeros((B * hw, D), embeddings.dtype)
+    buf = buf.at[(flat_idx + bias).reshape(-1)].add(embeddings.reshape(B * N, D))
+    return buf.reshape(B, hw, D)

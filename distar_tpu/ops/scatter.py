@@ -11,9 +11,9 @@ and scatters per channel). 'cover' mode uses `.set` with the reference's
 same last-writer-wins-ish semantics (ties resolved by scatter order is NOT
 guaranteed; use 'add' in training, as the reference default config does).
 
-A Pallas kernel for the fused scatter+conv-project path lives in
-`pallas_kernels.py` once profiling justifies it; this op is already
-memory-bound-optimal under XLA.
+``impl='pallas_onehot'`` routes add mode through the one-hot-matmul Pallas
+kernel in `pallas_kernels.py`; which of the two is faster on the chip is
+not measured (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -25,7 +25,7 @@ def scatter_connection(
     locations: jnp.ndarray,  # [B, N, 2] as (x, y) int
     spatial_size,  # (H, W)
     mode: str = "add",
-    impl: str = "xla",  # 'xla' | 'pallas' | 'pallas_onehot' (add mode only)
+    impl: str = "xla",  # 'xla' | 'pallas_onehot' (add mode only)
 ) -> jnp.ndarray:
     """Return [B, H, W, D] map with embeddings scattered at entity cells."""
     B, N, D = embeddings.shape
@@ -34,14 +34,13 @@ def scatter_connection(
     y = jnp.clip(locations[..., 1].astype(jnp.int32), 0, H - 1)
     flat_idx = y * W + x  # [B, N] in row-major (y, x) order
 
-    if impl in ("pallas", "pallas_onehot"):
+    if impl == "pallas_onehot":
         assert mode == "add", "pallas scatter implements add mode"
-        from .pallas_kernels import scatter_add_connection, scatter_add_onehot
+        from .pallas_kernels import scatter_add_onehot
 
-        kernel = scatter_add_onehot if impl == "pallas_onehot" else scatter_add_connection
-        return kernel(embeddings, flat_idx, H * W).reshape(B, H, W, D)
+        return scatter_add_onehot(embeddings, flat_idx, H * W).reshape(B, H, W, D)
     if impl != "xla":
-        raise ValueError(f"unknown scatter impl {impl!r} (xla|pallas|pallas_onehot)")
+        raise ValueError(f"unknown scatter impl {impl!r} (xla|pallas_onehot)")
 
     batch_bias = jnp.arange(B, dtype=jnp.int32)[:, None] * (H * W)
     flat = (flat_idx + batch_bias).reshape(-1)  # [B*N]

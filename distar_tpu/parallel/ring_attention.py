@@ -88,16 +88,11 @@ def ring_self_attention(
 ) -> jnp.ndarray:
     """Convenience wrapper: shard the sequence over the mesh's sp axis and
     run ring attention; output sharded like q."""
-    try:  # top-level export landed in newer jax; this image predates it
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     sp = mesh.shape["sp"]
     assert q.shape[2] % sp == 0, f"sequence {q.shape[2]} not divisible by sp={sp}"
     spec_qkv = P(None, None, "sp", None)
     spec_mask = P(None, "sp")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", axis_size=sp),
         mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),

@@ -20,9 +20,11 @@ from distar_tpu.obs.perf import (
 
 def test_peak_flops_table():
     assert peak_flops("TPU v5 lite") == 197e12
-    assert peak_flops("TPU v5p") == 459e12  # longest match wins over "v5"
+    assert peak_flops("TPU v5") == 459e12  # exact keys: no substring match
     assert peak_flops("cpu") is None
     assert peak_flops("") is None
+    with pytest.raises(KeyError, match="unknown TPU device_kind"):
+        peak_flops("TPU v5 lite pod")  # a TPU not in the table is an error
 
 
 def test_flops_and_memory_helpers_on_real_lowering():

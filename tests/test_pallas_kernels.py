@@ -4,10 +4,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distar_tpu.ops import pallas_kernels
 from distar_tpu.ops.pallas_kernels import (
     masked_attention,
     masked_attention_reference,
-    scatter_add_connection,
+    scatter_add_onehot,
+    scatter_add_reference,
 )
 from distar_tpu.ops import scatter_connection, sequence_mask
 
@@ -62,7 +64,7 @@ def test_scatter_add_matches_jnp(rng):
     x = jnp.asarray(rng.integers(0, W, (B, N)))
     y = jnp.asarray(rng.integers(0, H, (B, N)))
     flat = (y * W + x).astype(jnp.int32)
-    got = scatter_add_connection(emb, flat, H * W, interpret=True)
+    got = scatter_add_onehot(emb, flat, H * W, interpret=True)
     want = scatter_connection(emb, jnp.stack([x, y], -1), (H, W), "add").reshape(B, H * W, D)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -72,51 +74,47 @@ def test_scatter_add_collisions(rng):
     B, N, D = 1, 4, 2
     emb = jnp.ones((B, N, D))
     flat = jnp.zeros((B, N), jnp.int32)  # all collide on cell 0
-    out = scatter_add_connection(emb, flat, 9, interpret=True)
+    out = scatter_add_onehot(emb, flat, 9, interpret=True)
     np.testing.assert_allclose(np.asarray(out[0, 0]), [4.0, 4.0])
     assert float(jnp.abs(out[0, 1:]).sum()) == 0.0
 
 
-def test_scatter_onehot_matches_loop_variant(rng):
-    """MXU one-hot formulation == loop formulation (incl. collisions), fwd
-    and grad, also at an hw that does NOT divide the cell chunk."""
-    from distar_tpu.ops.pallas_kernels import scatter_add_onehot
-
+def test_scatter_onehot_matches_reference_unaligned_hw(rng):
+    """MXU one-hot formulation == jnp reference (incl. collisions), fwd and
+    grad, at an hw that does NOT divide the cell chunk."""
     B, N, D, H, W = 2, 16, 8, 9, 7  # hw=63: exercises the padded last chunk
     emb = jnp.asarray(rng.standard_normal((B, N, D)).astype(np.float32))
     flat = jnp.asarray(rng.integers(0, H * W, (B, N))).astype(jnp.int32)
     flat = flat.at[0, :4].set(0)  # forced collisions
-    want = scatter_add_connection(emb, flat, H * W, interpret=True)
+    want = scatter_add_reference(emb, flat, H * W)
     got = scatter_add_onehot(emb, flat, H * W, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
     g1 = jax.grad(lambda e: jnp.sum(scatter_add_onehot(e, flat, H * W, True) ** 2))(emb)
-    g2 = jax.grad(lambda e: jnp.sum(scatter_add_connection(e, flat, H * W, True) ** 2))(emb)
+    g2 = jax.grad(lambda e: jnp.sum(scatter_add_reference(e, flat, H * W) ** 2))(emb)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5, atol=1e-5)
 
 
-def test_scatter_oob_clipped_identically_in_both_wrappers(rng):
-    """Out-of-range indices are clipped to [0, hw-1] in BOTH public wrappers:
+def test_scatter_oob_clipped_like_the_reference(rng):
+    """Out-of-range indices are clipped to [0, hw-1] in the public wrapper,
+    exactly as the jnp reference (and ops.scatter_connection) clips them:
     switching impl strings can never silently change forward or gradient
-    semantics (the raw one-hot kernel would drop what the loop kernel
-    clamps — the wrappers unify on clamp)."""
-    from distar_tpu.ops.pallas_kernels import scatter_add_onehot
-
+    semantics (the raw one-hot kernel would drop them)."""
     B, N, D, hw = 1, 4, 2, 8
     emb = jnp.asarray(rng.standard_normal((B, N, D)).astype(np.float32))
     flat = jnp.asarray([[0, 3, -2, hw + 5]], jnp.int32)  # last two OOB
-    out_loop = scatter_add_connection(emb, flat, hw, interpret=True)
+    out_ref = scatter_add_reference(emb, flat, hw)
     out_onehot = scatter_add_onehot(emb, flat, hw, interpret=True)
-    np.testing.assert_allclose(np.asarray(out_loop), np.asarray(out_onehot),
+    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_onehot),
                                rtol=1e-5, atol=1e-5)
     # clamp semantics: the OOB entities landed on cells 0 and hw-1
-    np.testing.assert_allclose(np.asarray(out_loop[0, 0]),
+    np.testing.assert_allclose(np.asarray(out_onehot[0, 0]),
                                np.asarray(emb[0, 0] + emb[0, 2]), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(out_loop[0, hw - 1]),
+    np.testing.assert_allclose(np.asarray(out_onehot[0, hw - 1]),
                                np.asarray(emb[0, 3]), rtol=1e-5)
     # gradients agree too, and flow THROUGH the clamped cells (not zeroed)
     g1 = jax.grad(lambda e: jnp.sum(scatter_add_onehot(e, flat, hw, True) ** 2))(emb)
-    g2 = jax.grad(lambda e: jnp.sum(scatter_add_connection(e, flat, hw, True) ** 2))(emb)
+    g2 = jax.grad(lambda e: jnp.sum(scatter_add_reference(e, flat, hw) ** 2))(emb)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5, atol=1e-5)
     assert float(jnp.abs(g1[0, 2:]).sum()) > 0.0  # clamped, so grads flow
 
@@ -158,21 +156,48 @@ def test_scatter_add_vjp_is_gather(rng):
     emb = jnp.asarray(rng.standard_normal((B, N, D)).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, HW, (B, N)), jnp.int32)
 
-    def xla_ref(e):
-        bias = jnp.arange(B, dtype=jnp.int32)[:, None] * HW
-        buf = jnp.zeros((B * HW, D))
-        return buf.at[(idx + bias).reshape(-1)].add(e.reshape(-1, D)).reshape(B, HW, D)
-
-    ga = jax.grad(lambda e: jnp.sum(scatter_add_connection(e, idx, HW, True) ** 2))(emb)
-    gb = jax.grad(lambda e: jnp.sum(xla_ref(e) ** 2))(emb)
+    ga = jax.grad(lambda e: jnp.sum(scatter_add_onehot(e, idx, HW, True) ** 2))(emb)
+    gb = jax.grad(lambda e: jnp.sum(scatter_add_reference(e, idx, HW) ** 2))(emb)
     np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), rtol=1e-4, atol=1e-4)
+
+
+def test_interpret_choice_is_one_logged_counted_place(monkeypatch, caplog):
+    """``interpret=None`` resolves in ONE helper: on the CPU backend it picks
+    interpret mode, logs once at warning level and counts every fallback; an
+    explicit argument is returned untouched and never counted; every kernel
+    entry goes through the helper."""
+    import inspect
+    import logging
+
+    from distar_tpu.obs import MetricsRegistry
+
+    reg = MetricsRegistry()  # fresh: the "once" is per process registry
+    monkeypatch.setattr(pallas_kernels, "get_registry", lambda: reg)
+    counted = lambda: pallas_kernels.interpret_fallbacks().value
+    with caplog.at_level(logging.WARNING, logger=pallas_kernels.__name__):
+        assert pallas_kernels.resolve_interpret(None) is True
+        assert pallas_kernels.resolve_interpret(None) is True
+    assert counted() == 2
+    assert [r.levelno for r in caplog.records] == [logging.WARNING]  # once
+    assert "INTERPRET" in caplog.records[0].getMessage()
+    for explicit in (True, False):
+        assert pallas_kernels.resolve_interpret(explicit) is explicit
+    assert counted() == 2
+    # a test that needs the native branch steers the backend query itself
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_kernels.resolve_interpret(None) is False
+    assert counted() == 2
+    src = inspect.getsource(pallas_kernels)
+    assert src.count("default_backend()") == 2  # the test + its log argument
+    assert src.count("pl.pallas_call(") == src.count(
+        "interpret = resolve_interpret(interpret)")
 
 
 @pytest.mark.slow
 def test_small_model_trains_with_pallas_ops():
     """Full small-model SL train step with BOTH pallas hot-ops enabled
-    (attention_impl='pallas', scatter impl='pallas', interpret on CPU):
-    the A/B the bench runs on silicon must be a real training path.
+    (attention_impl='pallas', scatter impl='pallas_onehot', interpret on
+    CPU): the A/B the bench runs on silicon must be a real training path.
 
     Runs in a SUBPROCESS: pallas interpret mode at train-step scale leaves
     native state behind that can segfault unrelated later jit compiles in
@@ -194,7 +219,7 @@ model = {
                    "head_dim": 8, "attention_impl": "pallas"},
         "spatial": {"down_channels": [4, 4, 8], "project_dim": 4,
                     "resblock_num": 1, "fc_dim": 16},
-        "scatter": {"output_dim": 4, "impl": "pallas"},
+        "scatter": {"output_dim": 4, "impl": "pallas_onehot"},
         "core_lstm": {"hidden_size": 32, "num_layers": 1},
     },
     "policy": {

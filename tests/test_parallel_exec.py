@@ -334,8 +334,7 @@ def test_bench_multichip_case(tmp_path):
     """BENCH_MODE=multichip emits a SUSPECT-gated scaling artifact with
     dp=1/2/4 step times (CPU-derived, structural only)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, BENCH_MODE="multichip", BENCH_MULTICHIP_ITERS="2",
-               BENCH_COMPILE_CACHE="/tmp/jax_cache_distar_tpu")
+    env = dict(os.environ, BENCH_MODE="multichip", BENCH_MULTICHIP_ITERS="2")
     env.pop("JAX_PLATFORMS", None)
     out = subprocess.run(
         [sys.executable, os.path.join(repo, "bench.py"), "--run"],

@@ -47,12 +47,9 @@ SMALL_MODEL = {
 
 
 def _pin_cpu() -> None:
-    import jax
+    from distar_tpu.parallel.executor import select_backend
 
-    jax.config.update("jax_platforms", "cpu")
-    from distar_tpu.utils.compile_cache import configure as _cc
-
-    _cc(jax, "/tmp/jax_cache_distar_tpu")
+    select_backend("cpu")
 
 
 def make_scripted_replay(seed: int, n_actions: int = 30):
