@@ -60,12 +60,16 @@ class BatchedInference:
                 h, r, method=model.sample_action,
             )
         )
-        self._teacher = jax.jit(
-            lambda p, d, h, a, n: model.apply(
-                p, d["spatial_info"], d["entity_info"], d["scalar_info"], d["entity_num"],
-                h, a, n, method=model.teacher_logits,
-            )
-        )
+        def teacher(p, d, h, a, n):
+            # the frozen pass under one scope of its own, apart from the
+            # sampling pass that shares its modules' names (obs.STEP_SCOPES)
+            with jax.named_scope("teacher"):
+                return model.apply(
+                    p, d["spatial_info"], d["entity_info"], d["scalar_info"], d["entity_num"],
+                    h, a, n, method=model.teacher_logits,
+                )
+
+        self._teacher = jax.jit(teacher)
 
     def _zero_hidden(self):
         z = jnp.zeros((self.num_slots, self._hidden_size))

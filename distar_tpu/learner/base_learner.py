@@ -20,12 +20,12 @@ from ..obs import (
     DYNAMICS_DEFAULTS,
     DynamicsMonitor,
     PerfMonitor,
+    feed_spans,
     get_registry,
-    record_step_phases,
+    loop_spans,
     tree_spec,
 )
-from ..utils import Config, EasyTimer, build_logger, deep_merge_dicts
-from ..utils.timing import sw as global_stopwatch
+from ..utils import Config, build_logger, deep_merge_dicts
 from ..utils.checkpoint import (
     AsyncCheckpointer,
     CheckpointCorruptError,
@@ -103,7 +103,6 @@ class BaseLearner:
             f"devices: platform={dev.platform} device_kind={dev.device_kind!r} "
             f"count={jax.device_count()}"
         )
-        self.timer = EasyTimer()
         self.last_iter = CountVar(0)
         self._checkpointer = AsyncCheckpointer()
         self._ckpt_manager = CheckpointManager(
@@ -112,6 +111,10 @@ class BaseLearner:
         )
         self.log_buffer: Dict[str, Any] = {}
         self.metrics = get_registry()
+        # the phases of the run loop and of the feeder thread: spans on the
+        # profiler's clock and the phase histograms (obs/profiler.py)
+        self.spans = loop_spans(self.metrics)
+        self._feed_spans = feed_spans(self.name, self.metrics)
         self.metrics.gauge(
             "distar_device_count", "jax devices this process sees",
             platform=dev.platform, kind=dev.device_kind,
@@ -509,9 +512,6 @@ class BaseLearner:
         step_time = self.metrics.histogram(
             "distar_learner_step_seconds", "device train-step wall time"
         )
-        data_wait = self.metrics.histogram(
-            "distar_learner_data_wait_seconds", "dataloader wait per iteration"
-        )
         # a gauge (not histogram) on purpose: the NaN/Inf health rule needs
         # the raw last value — a reservoir quantile would mask non-finites
         loss_gauge = self.metrics.gauge(
@@ -528,52 +528,49 @@ class BaseLearner:
         @auto_checkpoint(lambda: self.save(self.checkpoint_path(), sync=True))
         def _run():
             self.hooks.call("before_run", self)
+            spans = self.spans
             while self.last_iter.val < max_iterations and not self._stop_requested:
-                with self.timer:
-                    data = next(self._dataloader)
-                t_data = self.timer.value
-                self.log_buffer["data_time"] = t_data
-                self._perf.note_batch(data)
-                self.hooks.call("before_iter", self)
-                # stash aux refs (e.g. the SL pre-step hidden carry) so an
-                # anomaly bundle can reconstruct the step's exact inputs
-                self._dynamics.before_step(self)
-                with self.timer:
-                    log_vars = self._train(data)
-                t_train = self.timer.value
-                self.log_buffer["train_time"] = t_train
-                self.log_buffer.update(log_vars)
-                loss = log_vars.get("total_loss")
-                if loss is not None:
-                    try:
-                        loss_gauge.set(float(loss))
-                    except (TypeError, ValueError):
-                        pass
-                # detection + gauge export from the already-fetched host log
-                # (no extra device sync); the batch is only touched if an
-                # anomaly writes a black-box bundle
-                self._dynamics.on_step(self, log_vars, data)
-                self.last_iter.add(1)
-                # before the hooks, so the metrics export among them carries
-                # THIS iteration's step time and memory sample
-                iters_total.inc()
-                step_time.observe(t_train)
-                data_wait.observe(t_data)
-                self._perf.on_step(t_train, frames_per_iter)
-                # host-callback phase = everything after the device step:
-                # hook pass (log reduction, checkpoint scheduling, weight
-                # publication) — the third leg of the step breakdown
-                with self.timer:
-                    self.hooks.call("after_iter", self)
-                record_step_phases(
-                    {
-                        "data_wait": t_data,
-                        "device_step": t_train,
-                        "host_callback": self.timer.value,
-                    },
-                    registry=self.metrics,
-                )
-                self._profile_tick()
+                # every line of an iteration is under exactly one leaf span:
+                # data_wait, pre_step, device_step's prepare / dispatch /
+                # fetch (in _train), post_step, host_callback, tick
+                with spans.step("train", self.last_iter.val):
+                    with spans.span("data_wait") as waited:
+                        data = next(self._dataloader)
+                    with spans.span("pre_step"):
+                        self.log_buffer["data_time"] = waited.seconds
+                        self._perf.note_batch(data)
+                        self.hooks.call("before_iter", self)
+                        # stash aux refs (e.g. the SL pre-step hidden carry) so an
+                        # anomaly bundle can reconstruct the step's exact inputs
+                        self._dynamics.before_step(self)
+                    with spans.span("device_step") as stepped:
+                        log_vars = self._train(data)
+                    with spans.span("post_step"):
+                        t_train = stepped.seconds
+                        self.log_buffer["train_time"] = t_train
+                        self.log_buffer.update(log_vars)
+                        loss = log_vars.get("total_loss")
+                        if loss is not None:
+                            try:
+                                loss_gauge.set(float(loss))
+                            except (TypeError, ValueError):
+                                pass
+                        # detection + gauge export from the already-fetched host
+                        # log (no extra device sync); the batch is only touched
+                        # if an anomaly writes a black-box bundle
+                        self._dynamics.on_step(self, log_vars, data)
+                        self.last_iter.add(1)
+                        # before the hooks, so the metrics export among them
+                        # carries THIS iteration's step time and memory sample
+                        iters_total.inc()
+                        step_time.observe(t_train)
+                        self._perf.on_step(t_train, frames_per_iter)
+                    # everything after the device step: hook pass (log
+                    # reduction, checkpoint scheduling, weight publication)
+                    with spans.span("host_callback"):
+                        self.hooks.call("after_iter", self)
+                    with spans.span("tick"):
+                        self._profile_tick()
             self.hooks.call("after_run", self)
 
         try:
@@ -587,7 +584,4 @@ class BaseLearner:
                     req["session"].stop()
                 req["error"] = "learner run ended before the capture completed"
                 self._finish_profile(req)
-        # drain per-region stopwatch samples into the registry (decorated
-        # regions anywhere in the process accumulate between reports)
-        global_stopwatch.report(registry=self.metrics)
         self._checkpointer.wait()  # drain the async writer before returning

@@ -103,22 +103,27 @@ def make_distill_train_step(model: Model, loss_cfg: DistillLossConfig,
             "teacher_logit": batch["teacher_logit"],
             "mask": batch["mask"],
         }
-        return compute_distill_loss(inputs, loss_cfg)
+        with jax.named_scope("loss"):
+            return compute_distill_loss(inputs, loss_cfg)
 
-    def train_step(params, opt_state, batch):
+    def distill_train_step(params, opt_state, batch):
         (_, info), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        info["grad_norm"] = optax.global_norm(grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("diagnostics/grad_norm"):
+            info["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         if dynamics is not None:
             from ..obs import dynamics_tree
 
-            info.update(dynamics_tree(
-                params, grads, updates=updates, batch=batch, spec=dynamics
-            ))
-        params = optax.apply_updates(params, updates)
+            with jax.named_scope("diagnostics/dynamics_tree"):
+                info.update(dynamics_tree(
+                    params, grads, updates=updates, batch=batch, spec=dynamics
+                ))
+        with jax.named_scope("optimizer"):
+            params = optax.apply_updates(params, updates)
         return params, opt_state, info
 
-    return train_step
+    return distill_train_step
 
 
 class DistillLearner(BaseLearner):
@@ -245,31 +250,35 @@ class DistillLearner(BaseLearner):
         return data
 
     def _train(self, data) -> Dict[str, Any]:
-        data = dict(data)
-        data.pop("_on_device", None)
-        model_last_iter = np.asarray(data.pop("model_last_iter", 0.0))
-        data.pop("trace_span_ids", None)
-        data.pop("trace_age_s", None)
-        data = self._strip_batch(self._cap(data))
-        batch = jax.tree.map(jnp.asarray, data)
-        params, opt_state, info = self._train_step(
-            self._state["params"], self._state["opt_state"], batch)
-        # after the call: the background flop count re-uses this trace
-        self._perf_note_step_args(self._train_step, params, opt_state, batch)
-        self._state = {"params": params, "opt_state": opt_state}
-        log = {k: float(v) for k, v in jax.device_get(info).items()}
-        self._g_kl.set(log["divergence"])
-        for head in ("action_type", "delay", "queued", "selected_units",
-                     "target_unit", "target_location"):
-            g = self._g_head_kl.get(head)
-            if g is None:
-                g = self._g_head_kl[head] = self.metrics.gauge(
-                    "distar_distill_head_kl",
-                    "per-action-head masked KL vs the teacher", head=head)
-            g.set(log[f"kl/{head}"])
-        self._g_teacher_gen.set(float(np.max(model_last_iter)))
-        if getattr(self, "_pending_save", False):
-            self._pending_save = False
-            self.save(self.checkpoint_path(), sync=True)
-            self.logger.info(f"admin checkpoint saved: {self.checkpoint_path()}")
+        spans = self.spans
+        with spans.span("prepare"):
+            data = dict(data)
+            data.pop("_on_device", None)
+            model_last_iter = np.asarray(data.pop("model_last_iter", 0.0))
+            data.pop("trace_span_ids", None)
+            data.pop("trace_age_s", None)
+            data = self._strip_batch(self._cap(data))
+            batch = jax.tree.map(jnp.asarray, data)
+        with spans.span("dispatch"):
+            params, opt_state, info = self._train_step(
+                self._state["params"], self._state["opt_state"], batch)
+            # after the call: the background flop count re-uses this trace
+            self._perf_note_step_args(self._train_step, params, opt_state, batch)
+            self._state = {"params": params, "opt_state": opt_state}
+        with spans.span("fetch"):
+            log = {k: float(v) for k, v in jax.device_get(info).items()}
+            self._g_kl.set(log["divergence"])
+            for head in ("action_type", "delay", "queued", "selected_units",
+                         "target_unit", "target_location"):
+                g = self._g_head_kl.get(head)
+                if g is None:
+                    g = self._g_head_kl[head] = self.metrics.gauge(
+                        "distar_distill_head_kl",
+                        "per-action-head masked KL vs the teacher", head=head)
+                g.set(log[f"kl/{head}"])
+            self._g_teacher_gen.set(float(np.max(model_last_iter)))
+            if getattr(self, "_pending_save", False):
+                self._pending_save = False
+                self.save(self.checkpoint_path(), sync=True)
+                self.logger.info(f"admin checkpoint saved: {self.checkpoint_path()}")
         return log

@@ -122,30 +122,38 @@ def make_rl_train_step(model: Model, loss_cfg: ReinforcementLossConfig, optimize
         import dataclasses
 
         cfg = dataclasses.replace(loss_cfg, only_update_value=False)
-        total, info = compute_rl_loss(inputs, cfg)
-        total_value_only = info["td/total"]
-        total = jnp.where(only_update_value, total_value_only, total)
+        with jax.named_scope("loss"):
+            total, info = compute_rl_loss(inputs, cfg)
+            total_value_only = info["td/total"]
+            total = jnp.where(only_update_value, total_value_only, total)
         return total, info
 
-    def train_step(params, opt_state, batch, only_update_value):
+    def rl_train_step(params, opt_state, batch, only_update_value):
         (_, info), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch, only_update_value
         )
-        info["grad_norm"] = optax.global_norm(grads)
+        # as in the SL step: what the model's modules do not name is named
+        # here, under obs.STEP_SCOPES
+        with jax.named_scope("diagnostics/grad_norm"):
+            info["grad_norm"] = optax.global_norm(grads)
         if save_grad:
-            info.update(leaf_norms(grads, "grad_norm"))
-            info.update(leaf_norms(params, "param_norm"))
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("diagnostics/leaf_norms"):
+                info.update(leaf_norms(grads, "grad_norm"))
+                info.update(leaf_norms(params, "param_norm"))
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         if dynamics is not None:
             from ..obs import dynamics_tree
 
-            info.update(dynamics_tree(
-                params, grads, updates=updates, batch=batch, spec=dynamics
-            ))
-        params = optax.apply_updates(params, updates)
+            with jax.named_scope("diagnostics/dynamics_tree"):
+                info.update(dynamics_tree(
+                    params, grads, updates=updates, batch=batch, spec=dynamics
+                ))
+        with jax.named_scope("optimizer"):
+            params = optax.apply_updates(params, updates)
         return params, opt_state, info
 
-    return train_step
+    return rl_train_step
 
 
 class RLLearner(BaseLearner):
@@ -265,6 +273,16 @@ class RLLearner(BaseLearner):
             # scalars replicate
             out_shardings=(param_sh, opt_sh, repl),
         )
+        # the step's only_update_value argument, replicated on the mesh like
+        # the rest of its arguments. A bare jnp.asarray is the one argument
+        # whose sharding the call leaves open; a lowering from the call's
+        # argument types (jit.lower of their ShapeDtypeStructs: the perf
+        # monitor's cost analysis, the benchmark's memory analysis) then pins
+        # it to its device, a program that differs from the call's in that one
+        # annotation, which the compile cache keys apart: the step compiled
+        # twice in full (190 s each at the flagship size on a v5e)
+        self._value_only_flags = {
+            v: jax.device_put(np.asarray(v), repl) for v in (False, True)}
         # analytic per-step collective estimate from the live mesh + params
         # (obs/perf.py) — the sanity bar a trace's collective bucket is read
         # against
@@ -310,11 +328,13 @@ class RLLearner(BaseLearner):
     def _place_batch(self, batch):
         """Prefetch placement: everything device-put ahead of time except the
         host-side staleness/trace fields."""
-        batch = self._cap(dict(batch))
-        model_last_iter = np.asarray(batch.pop("model_last_iter"))
-        span_ids = batch.pop("trace_span_ids", None)
-        trace_age = batch.pop("trace_age_s", None)
-        out = self.shard_batch(batch)
+        with self._feed_spans.span("cap"):
+            batch = self._cap(dict(batch))
+            model_last_iter = np.asarray(batch.pop("model_last_iter"))
+            span_ids = batch.pop("trace_span_ids", None)
+            trace_age = batch.pop("trace_age_s", None)
+        with self._feed_spans.span("put"):
+            out = self.shard_batch(batch)
         out["model_last_iter"] = model_last_iter
         if span_ids is not None:
             out["trace_span_ids"] = span_ids
@@ -453,37 +473,39 @@ class RLLearner(BaseLearner):
         return {"only_update_value": self._remaining_value_pretrain > 0}
 
     def _train(self, data) -> Dict[str, Any]:
-        only_value = self.step_value_pretrain()
-        data = dict(data)  # callers may reuse the batch dict
-        on_device = data.pop("_on_device", False)
-        model_last_iter = np.asarray(data.pop("model_last_iter"))
-        staleness = self.last_iter.val - model_last_iter
-        # pipeline-span fields minted in the actor (host-side: never sharded)
-        span_ids = data.pop("trace_span_ids", None)
-        trace_age = data.pop("trace_age_s", None)
-        if not on_device:
-            data = self.shard_batch(self._cap(data))
-        params, opt_state, info = self._train_step(
-            self._state["params"], self._state["opt_state"], data,
-            jnp.asarray(only_value),
-        )
-        # after the call (the new state has the donated one's types): the
-        # background flop count then re-uses this trace instead of racing it
-        self._perf_note_step_args(
-            self._train_step, params, opt_state, data, jnp.asarray(only_value))
-        self._state = {"params": params, "opt_state": opt_state}
-        # one batched D2H transfer — per-scalar float() would round-trip
-        # once per metric across the ~60-entry loss grid every iteration
-        log = {k: float(v) for k, v in jax.device_get(info).items()}
-        log["staleness/mean"] = float(staleness.mean())
-        log["staleness/max"] = float(staleness.max())
-        log["staleness/std"] = float(staleness.std())
-        if trace_age is not None and len(trace_age):
-            # wall-clock counterpart of iteration staleness: seconds from the
-            # trajectory's birth in the actor to this train step (span ids in
-            # trace_span_ids attribute outliers to specific trajectories)
-            log["trace/age_s_mean"] = float(np.mean(trace_age))
-            log["trace/age_s_max"] = float(np.max(trace_age))
-            self._last_span_ids = list(span_ids or [])
-        self._apply_admin_requests()
+        spans = self.spans
+        with spans.span("prepare"):
+            only_value = self._value_only_flags[self.step_value_pretrain()]
+            data = dict(data)  # callers may reuse the batch dict
+            on_device = data.pop("_on_device", False)
+            model_last_iter = np.asarray(data.pop("model_last_iter"))
+            staleness = self.last_iter.val - model_last_iter
+            # pipeline-span fields minted in the actor (host-side: never sharded)
+            span_ids = data.pop("trace_span_ids", None)
+            trace_age = data.pop("trace_age_s", None)
+            if not on_device:
+                data = self.shard_batch(self._cap(data))
+        with spans.span("dispatch"):
+            params, opt_state, info = self._train_step(
+                self._state["params"], self._state["opt_state"], data, only_value)
+            # after the call (the new state has the donated one's types): the
+            # background flop count then re-uses this trace instead of racing it
+            self._perf_note_step_args(
+                self._train_step, params, opt_state, data, only_value)
+            self._state = {"params": params, "opt_state": opt_state}
+        with spans.span("fetch"):
+            # one batched D2H transfer — per-scalar float() would round-trip
+            # once per metric across the ~60-entry loss grid every iteration
+            log = {k: float(v) for k, v in jax.device_get(info).items()}
+            log["staleness/mean"] = float(staleness.mean())
+            log["staleness/max"] = float(staleness.max())
+            log["staleness/std"] = float(staleness.std())
+            if trace_age is not None and len(trace_age):
+                # wall-clock counterpart of iteration staleness: seconds from the
+                # trajectory's birth in the actor to this train step (span ids in
+                # trace_span_ids attribute outliers to specific trajectories)
+                log["trace/age_s_mean"] = float(np.mean(trace_age))
+                log["trace/age_s_max"] = float(np.max(trace_age))
+                self._last_span_ids = list(span_ids or [])
+            self._apply_admin_requests()
         return log

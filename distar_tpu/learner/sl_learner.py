@@ -61,38 +61,49 @@ def make_sl_train_step(model: Model, loss_cfg: SupervisedLossConfig, optimizer,
             hidden_state, batch_size,
             method=model.sl_forward,
         )
-        total, info = compute_sl_loss(
-            logits,
-            batch["action_info"],
-            batch["action_mask"],
-            batch["selected_units_num"],
-            batch["entity_num"],
-            loss_cfg,
-        )
+        with jax.named_scope("loss"):
+            total, info = compute_sl_loss(
+                logits,
+                batch["action_info"],
+                batch["action_mask"],
+                batch["selected_units_num"],
+                batch["entity_num"],
+                loss_cfg,
+            )
         return total, (info, out_state)
 
-    def train_step(params, opt_state, batch, hidden_state):
+    # the function's name is the compiled program's (``jit_sl_train_step`` in
+    # a trace) and the head of its compile-cache key: an executable cached
+    # before the scopes below existed carries none of them, and is not served
+    def sl_train_step(params, opt_state, batch, hidden_state):
         (_, (info, out_state)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, batch, hidden_state
         )
-        info["grad_norm"] = optax.global_norm(grads)
+        # every operation of the step sits under one of obs.STEP_SCOPES: the
+        # model's modules name theirs, the rest is named here
+        with jax.named_scope("diagnostics/grad_norm"):
+            info["grad_norm"] = optax.global_norm(grads)
         if save_grad:
             # per-parameter norms (reference save_grad TB dumps)
-            info.update(leaf_norms(grads, "grad_norm"))
-            info.update(leaf_norms(params, "param_norm"))
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("diagnostics/leaf_norms"):
+                info.update(leaf_norms(grads, "grad_norm"))
+                info.update(leaf_norms(params, "param_norm"))
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         if dynamics is not None:
             # pre-step params + post-clip updates: ratios/censuses describe
             # exactly this step (obs/dynamics.py)
             from ..obs import dynamics_tree
 
-            info.update(dynamics_tree(
-                params, grads, updates=updates, batch=batch, spec=dynamics
-            ))
-        params = optax.apply_updates(params, updates)
+            with jax.named_scope("diagnostics/dynamics_tree"):
+                info.update(dynamics_tree(
+                    params, grads, updates=updates, batch=batch, spec=dynamics
+                ))
+        with jax.named_scope("optimizer"):
+            params = optax.apply_updates(params, updates)
         return params, opt_state, out_state, info
 
-    return train_step
+    return sl_train_step
 
 
 class SLLearner(BaseLearner):
@@ -244,11 +255,13 @@ class SLLearner(BaseLearner):
         assemble into global arrays on a pod."""
         from ..parallel.feeder import assemble_global
 
-        data = self._cap(dict(data))
-        host = {k: np.asarray(data.pop(k)) for k in ("new_episodes", "traj_lens") if k in data}
-        out = jax.tree.map(
-            lambda x: assemble_global(jnp.asarray(x), self._shardings["flat"]), data
-        )
+        with self._feed_spans.span("cap"):
+            data = self._cap(dict(data))
+            host = {k: np.asarray(data.pop(k)) for k in ("new_episodes", "traj_lens") if k in data}
+        with self._feed_spans.span("put"):
+            out = jax.tree.map(
+                lambda x: assemble_global(jnp.asarray(x), self._shardings["flat"]), data
+            )
         out.update(host)
         out["_on_device"] = True
         return out
@@ -262,45 +275,49 @@ class SLLearner(BaseLearner):
         return {"hidden_state": self._hidden}
 
     def _train(self, data) -> Dict[str, Any]:
-        data = dict(data)  # callers may reuse the batch dict
-        on_device = data.pop("_on_device", False)
-        if not on_device:
-            data = self._cap(data)
-        new_episodes = np.asarray(data.pop("new_episodes"))
-        traj_lens = data.pop("traj_lens", None)
-        if new_episodes.any():
-            # reset hidden state for restarted trajectories (reference
-            # sl_learner.py:31-35)
-            keep = jnp.asarray(~new_episodes, jnp.float32)[:, None]
-            self._hidden = tuple((h * keep, c * keep) for h, c in self._hidden)
-        if not on_device:
-            data = jax.tree.map(
-                lambda x: jax.device_put(jnp.asarray(x), self._shardings["flat"]), data
+        spans = self.spans
+        with spans.span("prepare"):
+            data = dict(data)  # callers may reuse the batch dict
+            on_device = data.pop("_on_device", False)
+            if not on_device:
+                data = self._cap(data)
+            new_episodes = np.asarray(data.pop("new_episodes"))
+            traj_lens = data.pop("traj_lens", None)
+            if new_episodes.any():
+                # reset hidden state for restarted trajectories (reference
+                # sl_learner.py:31-35)
+                keep = jnp.asarray(~new_episodes, jnp.float32)[:, None]
+                self._hidden = tuple((h * keep, c * keep) for h, c in self._hidden)
+            if not on_device:
+                data = jax.tree.map(
+                    lambda x: jax.device_put(jnp.asarray(x), self._shardings["flat"]), data
+                )
+            debug_on = self.cfg.learner.get("debug_loss_spike", False)
+            if debug_on:
+                # the step's exact inputs: batch + post-reset hidden (params are
+                # donated, so a spike's checkpoint is one Adam step past — noted
+                # in the snapshot)
+                pre_step = {
+                    "batch": data,
+                    "hidden_state": self._hidden,
+                    "new_episodes": new_episodes,
+                    "traj_lens": traj_lens,
+                }
+        with spans.span("dispatch"):
+            params, opt_state, out_state, info = self._train_step(
+                self._state["params"], self._state["opt_state"], data, self._hidden
             )
-        debug_on = self.cfg.learner.get("debug_loss_spike", False)
-        if debug_on:
-            # the step's exact inputs: batch + post-reset hidden (params are
-            # donated, so a spike's checkpoint is one Adam step past — noted
-            # in the snapshot)
-            pre_step = {
-                "batch": data,
-                "hidden_state": self._hidden,
-                "new_episodes": new_episodes,
-                "traj_lens": traj_lens,
-            }
-        params, opt_state, out_state, info = self._train_step(
-            self._state["params"], self._state["opt_state"], data, self._hidden
-        )
-        # after the call (the new state has the donated one's types): the
-        # background flop count then re-uses this trace instead of racing it
-        self._perf_note_step_args(
-            self._train_step, params, opt_state, data, self._hidden)
-        self._state = {"params": params, "opt_state": opt_state}
-        self._hidden = jax.tree.map(jax.lax.stop_gradient, out_state)
-        # one batched D2H transfer instead of a round-trip per metric
-        log = {k: float(v) for k, v in jax.device_get(info).items()}
-        if debug_on:
-            self._loss_spike_guard(log, pre_step)
+            # after the call (the new state has the donated one's types): the
+            # background flop count then re-uses this trace instead of racing it
+            self._perf_note_step_args(
+                self._train_step, params, opt_state, data, self._hidden)
+            self._state = {"params": params, "opt_state": opt_state}
+            self._hidden = jax.tree.map(jax.lax.stop_gradient, out_state)
+        with spans.span("fetch"):
+            # one batched D2H transfer instead of a round-trip per metric
+            log = {k: float(v) for k, v in jax.device_get(info).items()}
+            if debug_on:
+                self._loss_spike_guard(log, pre_step)
         return log
 
     # snapshots per run: a misbehaving trigger must not flood the disk

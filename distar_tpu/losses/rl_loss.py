@@ -161,121 +161,126 @@ def compute_rl_loss(
     gammas = dict(cfg.gammas)
 
     # ------------------------------------------------ policy gradient (vtrace)
-    total_pg = 0.0
-    for field, field_w in cfg.pg_weights:
-        if field not in values or field not in rewards:
-            continue
-        reward = rewards[field].astype(jnp.float32)
-        baseline = values[field]
-        field_pg = 0.0
-        for head in HEADS:
-            adv = jax.lax.stop_gradient(
-                vtrace_advantages(
-                    clipped_rhos[head], clipped_rhos[head], reward, baseline,
-                    gammas=cfg.pg_gamma, lambda_=cfg.vtrace_lambda,
+    with jax.named_scope("vtrace"):
+        total_pg = 0.0
+        for field, field_w in cfg.pg_weights:
+            if field not in values or field not in rewards:
+                continue
+            reward = rewards[field].astype(jnp.float32)
+            baseline = values[field]
+            field_pg = 0.0
+            for head in HEADS:
+                adv = jax.lax.stop_gradient(
+                    vtrace_advantages(
+                        clipped_rhos[head], clipped_rhos[head], reward, baseline,
+                        gammas=cfg.pg_gamma, lambda_=cfg.vtrace_lambda,
+                    )
                 )
-            )
-            pg = -adv * target_action_logp[head] * step_mask
-            if head not in ALWAYS_ON:
-                pg = pg * masks["actions_mask"][head]
-            if field in FIELD_MASKS:
-                pg = pg * masks[FIELD_MASKS[field]]
-            pg = pg.mean()
-            field_pg += pg * head_w[head]
-            info[f"pg/{field}/{head}"] = pg
-        total_pg += field_w * field_pg
-    info["pg/total"] = total_pg
+                pg = -adv * target_action_logp[head] * step_mask
+                if head not in ALWAYS_ON:
+                    pg = pg * masks["actions_mask"][head]
+                if field in FIELD_MASKS:
+                    pg = pg * masks[FIELD_MASKS[field]]
+                pg = pg.mean()
+                field_pg += pg * head_w[head]
+                info[f"pg/{field}/{head}"] = pg
+            total_pg += field_w * field_pg
+        info["pg/total"] = total_pg
 
     # ------------------------------------------------------------------ UPGO
-    total_upgo = 0.0
-    upgo_adv_base = jax.lax.stop_gradient(
-        upgo_returns(rewards["winloss"].astype(jnp.float32), values["winloss"])
-        - values["winloss"][:-1]
-    )
-    for head in HEADS:
-        adv = clipped_rhos[head] * upgo_adv_base
-        ug = -adv * target_action_logp[head] * step_mask
-        if head not in ALWAYS_ON:
-            ug = ug * masks["actions_mask"][head]
-        ug = ug.mean()
-        total_upgo += ug * head_w[head]
-        info[f"upgo/{head}"] = ug
-    total_upgo = total_upgo * cfg.upgo_weight
-    info["upgo/total"] = total_upgo
+    with jax.named_scope("upgo"):
+        total_upgo = 0.0
+        upgo_adv_base = jax.lax.stop_gradient(
+            upgo_returns(rewards["winloss"].astype(jnp.float32), values["winloss"])
+            - values["winloss"][:-1]
+        )
+        for head in HEADS:
+            adv = clipped_rhos[head] * upgo_adv_base
+            ug = -adv * target_action_logp[head] * step_mask
+            if head not in ALWAYS_ON:
+                ug = ug * masks["actions_mask"][head]
+            ug = ug.mean()
+            total_upgo += ug * head_w[head]
+            info[f"upgo/{head}"] = ug
+        total_upgo = total_upgo * cfg.upgo_weight
+        info["upgo/total"] = total_upgo
 
     # ---------------------------------------------------------------- critic
-    total_critic = 0.0
-    for field, field_w in cfg.baseline_weights:
-        if field not in values or field not in rewards:
-            continue
-        reward = rewards[field].astype(jnp.float32)
-        baseline = values[field]
-        returns = jax.lax.stop_gradient(
-            generalized_lambda_returns(reward, gammas[field], baseline, cfg.td_lambda)
-        )
-        td = 0.5 * jnp.square(returns - baseline[:-1]) * step_mask
-        if field in FIELD_MASKS:
-            td = td * masks[FIELD_MASKS[field]]
-        td = td.mean()
-        total_critic += field_w * td
-        info[f"td/{field}"] = td
-        info[f"reward/{field}"] = reward.mean()
-        info[f"value/{field}"] = baseline.mean()
-    info["td/total"] = total_critic
+    with jax.named_scope("td"):
+        total_critic = 0.0
+        for field, field_w in cfg.baseline_weights:
+            if field not in values or field not in rewards:
+                continue
+            reward = rewards[field].astype(jnp.float32)
+            baseline = values[field]
+            returns = jax.lax.stop_gradient(
+                generalized_lambda_returns(reward, gammas[field], baseline, cfg.td_lambda)
+            )
+            td = 0.5 * jnp.square(returns - baseline[:-1]) * step_mask
+            if field in FIELD_MASKS:
+                td = td * masks[FIELD_MASKS[field]]
+            td = td.mean()
+            total_critic += field_w * td
+            info[f"td/{field}"] = td
+            info[f"reward/{field}"] = reward.mean()
+            info[f"value/{field}"] = baseline.mean()
+        info["td/total"] = total_critic
 
     # --------------------------------------------------------------- entropy
-    total_entropy_loss = 0.0
-    for head in HEADS:
-        ent = -target_prob_full[head] * target_logp_full[head]
-        if head == "selected_units":
-            # normalise by log(valid candidates + 1) and average over real steps
-            norm = jnp.log(entity_num.astype(jnp.float32) + 1.0 + 1e-9)[..., None]
-            ent = ent.sum(-1) / norm
-            ent = (ent * su_mask).sum(-1) / (su_mask.sum(-1) + 1e-9)
-        elif head == "target_unit":
-            # log(num_valid_targets + 1) (reference as_rl_utils.py:59-61);
-            # the +1 inside the log also guards entity_num == 1
-            ent = ent.sum(-1) / (jnp.log(entity_num.astype(jnp.float32) + 1.0) + 1e-9)
-        else:
-            ent = ent.sum(-1) / jnp.log(float(ent.shape[-1]))
-        ent = ent * step_mask
-        if head not in ALWAYS_ON:
-            ent = ent * masks["actions_mask"][head]
-        ent_mean = ent.mean()
-        info[f"entropy/{head}"] = ent_mean
-        total_entropy_loss += -ent_mean * head_w[head]
-    total_entropy_loss = total_entropy_loss * cfg.entropy_weight
-    info["entropy/total"] = total_entropy_loss
+    with jax.named_scope("entropy"):
+        total_entropy_loss = 0.0
+        for head in HEADS:
+            ent = -target_prob_full[head] * target_logp_full[head]
+            if head == "selected_units":
+                # normalise by log(valid candidates + 1) and average over real steps
+                norm = jnp.log(entity_num.astype(jnp.float32) + 1.0 + 1e-9)[..., None]
+                ent = ent.sum(-1) / norm
+                ent = (ent * su_mask).sum(-1) / (su_mask.sum(-1) + 1e-9)
+            elif head == "target_unit":
+                # log(num_valid_targets + 1) (reference as_rl_utils.py:59-61);
+                # the +1 inside the log also guards entity_num == 1
+                ent = ent.sum(-1) / (jnp.log(entity_num.astype(jnp.float32) + 1.0) + 1e-9)
+            else:
+                ent = ent.sum(-1) / jnp.log(float(ent.shape[-1]))
+            ent = ent * step_mask
+            if head not in ALWAYS_ON:
+                ent = ent * masks["actions_mask"][head]
+            ent_mean = ent.mean()
+            info[f"entropy/{head}"] = ent_mean
+            total_entropy_loss += -ent_mean * head_w[head]
+        total_entropy_loss = total_entropy_loss * cfg.entropy_weight
+        info["entropy/total"] = total_entropy_loss
 
     # -------------------------------------------------------------------- KL
-    def _kl_terms(ref_logit):
-        out = {}
-        for head in HEADS:
-            ref_logp = _log_softmax(ref_logit[head])
-            kl = (jnp.exp(ref_logp) * (ref_logp - target_logp_full[head])).sum(-1)
-            if head == "selected_units":
-                kl = (kl * su_mask).sum(-1)
-            kl = kl * step_mask
-            if head not in ALWAYS_ON:
-                kl = kl * masks["actions_mask"][head]
-            out[head] = kl
-        return out
+    with jax.named_scope("kl"):
+        def _kl_terms(ref_logit):
+            out = {}
+            for head in HEADS:
+                ref_logp = _log_softmax(ref_logit[head])
+                kl = (jnp.exp(ref_logp) * (ref_logp - target_logp_full[head])).sum(-1)
+                if head == "selected_units":
+                    kl = (kl * su_mask).sum(-1)
+                kl = kl * step_mask
+                if head not in ALWAYS_ON:
+                    kl = kl * masks["actions_mask"][head]
+                out[head] = kl
+            return out
 
-    kls = _kl_terms(teacher_logit)
-    total_kl = 0.0
-    for head, kl in kls.items():
-        kl_mean = kl.mean()
-        total_kl += kl_mean * head_w[head]
-        info[f"kl/{head}"] = kl_mean
-    at_kl = (
-        kls["action_type"]
-        * (steps < cfg.action_type_kl_steps)
-        * masks["cum_action_mask"]
-    ).mean()
-    total_kl = total_kl * cfg.kl_weight
-    at_kl = at_kl * cfg.action_type_kl_weight
-    info["kl/total"] = total_kl
-    info["kl/extra_at"] = at_kl
+        kls = _kl_terms(teacher_logit)
+        total_kl = 0.0
+        for head, kl in kls.items():
+            kl_mean = kl.mean()
+            total_kl += kl_mean * head_w[head]
+            info[f"kl/{head}"] = kl_mean
+        at_kl = (
+            kls["action_type"]
+            * (steps < cfg.action_type_kl_steps)
+            * masks["cum_action_mask"]
+        ).mean()
+        total_kl = total_kl * cfg.kl_weight
+        at_kl = at_kl * cfg.action_type_kl_weight
+        info["kl/total"] = total_kl
+        info["kl/extra_at"] = at_kl
 
     # ------------------------------------------------------------------ DAPO
     total_dapo = 0.0

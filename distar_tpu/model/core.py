@@ -62,13 +62,16 @@ class Encoder(nn.Module):
         entity_embeddings, embedded_entity, entity_mask = EntityEncoder(
             static_cfg(self.cfg), name="entity_encoder"
         )(entity_info, entity_num)
-        proj = FCBlock(static_cfg(self.cfg).encoder.scatter.output_dim, "relu", dtype=cdtype(self.cfg))(
-            entity_embeddings
-        )
-        proj = proj * entity_mask[..., None]
-        locations = jnp.stack(
-            [entity_info["x"].astype(jnp.int32), entity_info["y"].astype(jnp.int32)], axis=-1
-        )
+        # the projection belongs to the scatter connection's scope (the
+        # function names its own operations the same: obs.STEP_SCOPES)
+        with jax.named_scope("scatter_connection"):
+            proj = FCBlock(static_cfg(self.cfg).encoder.scatter.output_dim, "relu", dtype=cdtype(self.cfg))(
+                entity_embeddings
+            )
+            proj = proj * entity_mask[..., None]
+            locations = jnp.stack(
+                [entity_info["x"].astype(jnp.int32), entity_info["y"].astype(jnp.int32)], axis=-1
+            )
         scatter_map = scatter_connection(
             proj,
             locations,
@@ -309,24 +312,25 @@ class Model(nn.Module):
                 "False for actor-side models, mirroring the reference's "
                 "use_value_network ctor flag, model.py:23)"
             )
-        critic_input = flat_out
-        if static_cfg(self.cfg).only_update_baseline:
-            critic_input = jax.lax.stop_gradient(critic_input)
-            baseline_feature = jax.lax.stop_gradient(baseline_feature)
-        if static_cfg(self.cfg).use_value_feature:
-            if value_feature is None:
-                raise ValueError(
-                    "cfg.use_value_feature=True but the batch carries no "
-                    "value_feature — the data source (actor collect_data / "
-                    "fake_rl_batch) must include the centralized-critic "
-                    "features (lib.features.VALUE_FEATURE_INFO)"
-                )
-            vf = self.value_encoder(value_feature)
-            critic_input = jnp.concatenate([critic_input, vf, baseline_feature], axis=1)
-        values = {
-            k: v(critic_input).reshape(unroll_len + 1, batch_size)
-            for k, v in self.value_networks.items()
-        }
+        with jax.named_scope("value"):
+            critic_input = flat_out
+            if static_cfg(self.cfg).only_update_baseline:
+                critic_input = jax.lax.stop_gradient(critic_input)
+                baseline_feature = jax.lax.stop_gradient(baseline_feature)
+            if static_cfg(self.cfg).use_value_feature:
+                if value_feature is None:
+                    raise ValueError(
+                        "cfg.use_value_feature=True but the batch carries no "
+                        "value_feature — the data source (actor collect_data / "
+                        "fake_rl_batch) must include the centralized-critic "
+                        "features (lib.features.VALUE_FEATURE_INFO)"
+                    )
+                vf = self.value_encoder(value_feature)
+                critic_input = jnp.concatenate([critic_input, vf, baseline_feature], axis=1)
+            values = {
+                k: v(critic_input).reshape(unroll_len + 1, batch_size)
+                for k, v in self.value_networks.items()
+            }
         return {"target_logit": logits, "value": values}
 
     # ------------------------------------------------------------------ SL

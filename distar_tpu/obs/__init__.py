@@ -65,7 +65,7 @@ from .tracestore import (
     set_trace_buffer,
 )
 from .waterfall import build_waterfall, render_listing, render_waterfall
-from .profiler import ProfilerSession, record_step_phases
+from .profiler import STEP_SCOPES, ProfilerSession, Spans, feed_spans, loop_spans
 from .perf import (
     PerfMonitor,
     estimate_collective_bytes,
@@ -149,7 +149,10 @@ __all__ = [
     "render_listing",
     "render_waterfall",
     "ProfilerSession",
-    "record_step_phases",
+    "STEP_SCOPES",
+    "Spans",
+    "feed_spans",
+    "loop_spans",
     "PerfMonitor",
     "estimate_collective_bytes",
     "flops_of_compiled",

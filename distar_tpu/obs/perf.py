@@ -290,6 +290,15 @@ class PerfMonitor:
                         "distar_perf_hbm_peak_bytes",
                         "allocator high-water mark", device=label,
                     ).set(float(peak))
+                # on the TPU runtime a program's temporaries live in the
+                # reserved pool, apart from peak_bytes_in_use (PERF.md §3)
+                reserved = stats.get("peak_bytes_reserved")
+                if reserved is not None:
+                    self._registry.gauge(
+                        "distar_perf_hbm_reserved_peak_bytes",
+                        "reserved-pool high-water mark (program temporaries)",
+                        device=label,
+                    ).set(float(reserved))
         except Exception:
             self._c_fail.inc()
 

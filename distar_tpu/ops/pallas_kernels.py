@@ -131,6 +131,7 @@ def _masked_attention_fwd_kernel(q, k, v, mask, interpret):
         ],
         out_specs=pl.BlockSpec((1, 1, N, Dh), idx, memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="masked_attention",  # the kernel's name in a device trace
     )(q, k, v, mask2)
 
 
@@ -234,6 +235,7 @@ def _scatter_onehot_fwd_kernel(embeddings, flat_idx, hw, interpret):
         out_specs=pl.BlockSpec((1, chunk, D), lambda b, c: (b, c, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="scatter_add_onehot",  # the kernel's name in a device trace
     )(embeddings, flat_idx.astype(jnp.int32)[:, None, :])
 
 

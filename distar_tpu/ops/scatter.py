@@ -17,9 +17,11 @@ not measured (ROADMAP D2).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("scatter_connection")
 def scatter_connection(
     embeddings: jnp.ndarray,  # [B, N, D]
     locations: jnp.ndarray,  # [B, N, 2] as (x, y) int

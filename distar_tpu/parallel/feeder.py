@@ -37,7 +37,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding
 
-from ..obs import get_registry
+from ..obs import feed_spans, get_registry
 from .mesh import MeshConfigError
 
 _SENTINEL = object()
@@ -89,6 +89,11 @@ class ShardFeeder:
     and the ``distar_feeder_*`` instrumentation. ``place_fn`` receives the
     raw host batch and returns the device-placed batch — for learners that
     is ``_place_batch`` (entity cap + per-leaf ``assemble_global``).
+
+    The producer thread's phases go through ``obs.feed_spans(token)``
+    (``distar:feed/<phase>`` in a profiler trace, ``distar_feeder_phase_
+    seconds{phase,token}``): ``pull`` and ``put_wait`` here, ``cap`` and
+    ``put`` inside the learner's ``place_fn``, under the same token.
     """
 
     def __init__(self, dataloader, place_fn: Callable, depth: int = 2,
@@ -121,6 +126,11 @@ class ShardFeeder:
             "host pull + collate + device placement time per batch",
             token=token,
         )
+        self._m_leaves = reg.histogram(
+            "distar_feeder_batch_leaves",
+            "device arrays in a placed batch: one device_put each", token=token,
+        )
+        self._spans = feed_spans(token)
         self._m_occ = reg.gauge(
             "distar_feeder_occupancy",
             "placed-batch share of the double buffer (0..1)", token=token,
@@ -132,23 +142,28 @@ class ShardFeeder:
 
     # ------------------------------------------------------------- producer
     def _loop(self) -> None:
+        spans = self._spans
         try:
             while not self._stop.is_set():
                 t0 = time.monotonic()
                 try:
-                    batch = next(self._it)
+                    with spans.span("pull"):
+                        batch = next(self._it)
                 except StopIteration:
                     return
                 placed = self._place(batch)
                 dt = time.monotonic() - t0
                 self.total_place_s += dt
                 self._m_place.observe(dt)
-                while not self._stop.is_set():
-                    try:
-                        self._q.put(placed, timeout=0.2)
-                        break
-                    except queue.Full:
-                        continue
+                self._m_leaves.observe(
+                    sum(isinstance(x, jax.Array) for x in jax.tree.leaves(placed)))
+                with spans.span("put_wait"):
+                    while not self._stop.is_set():
+                        try:
+                            self._q.put(placed, timeout=0.2)
+                            break
+                        except queue.Full:
+                            continue
         except BaseException as e:  # surfaced on the consumer side
             self._err = e
         finally:

@@ -675,7 +675,7 @@ def test_legacy_shim_surfaces(tmp_path):
     assert len(problems) == 1 and "wrong_name" in problems[0]
     names = lmn.registered_names(str(pkg))
     assert "wrong_name" in names
-    assert "distar_stopwatch_seconds" in names  # DYNAMIC_ALLOW included
+    assert "distar_stopwatch_seconds" not in names  # went with utils/timing.StopWatch (PR 23)
 
 
 # ================================================================= lockwatch

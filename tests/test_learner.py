@@ -513,3 +513,32 @@ def test_rl_learner_resume_latest_with_corrupt_fallback(rl_learner, chaos):
     np.testing.assert_allclose(
         w1, np.asarray(jax.tree.leaves(learner.state["params"])[0])
     )
+
+
+@pytest.mark.parametrize("kind", ["sl", "rl"])
+def test_step_lowered_from_its_arguments_types_is_the_calls_program(kind, tmp_path):
+    """``jit.lower`` of the ``ShapeDtypeStruct``s of a call's arguments (how
+    the perf monitor and the benchmark ask for the step's cost and memory
+    analysis) gives the call's own program, so the compile cache serves it.
+    An argument the call leaves uncommitted breaks that: its struct carries
+    the device it happened to sit on, and the step compiles twice."""
+    from distar_tpu.learner import RLLearner, SLLearner
+
+    learner = {"sl": SLLearner, "rl": RLLearner}[kind]({
+        "common": {"experiment_name": "lower", "save_path": str(tmp_path)},
+        "learner": {"batch_size": 2, "unroll_len": 2, "save_freq": 100000, "log_freq": 1},
+        "model": SMALL_MODEL,
+    })
+    jitted, texts = learner._train_step, []
+
+    def tap(*args):
+        if not texts:  # before the call: it donates the state
+            specs = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=getattr(x, "sharding", None))
+                if hasattr(x, "shape") and hasattr(x, "dtype") else x, args)
+            texts.extend(jitted.lower(*a).as_text() for a in (args, specs))
+        return jitted(*args)
+
+    learner._train_step = tap
+    learner.run(max_iterations=1)
+    assert len(texts) == 2 and texts[0] == texts[1]

@@ -1,6 +1,6 @@
 """Unified telemetry layer: registry semantics, exporters, the coordinator
 /metrics route, and the meters the obs PR touched (EMAMeter debias,
-thread-safe StopWatch)."""
+the run loop's phase histograms)."""
 import json
 import os
 import threading
@@ -292,31 +292,6 @@ def test_ema_meter_converges_to_plateau():
     for _ in range(200):
         m.update(3.0)
     assert m.avg == pytest.approx(3.0)
-
-
-# -------------------------------------------------------- StopWatch report
-def test_stopwatch_thread_safe_and_reports(registry):
-    from distar_tpu.utils.timing import StopWatch
-
-    swatch = StopWatch(enabled=True)
-
-    def spin(name):
-        for _ in range(200):
-            with swatch(name):
-                pass
-
-    threads = [threading.Thread(target=spin, args=(f"r{i % 2}",)) for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    s = swatch.summary()
-    assert s["r0"]["num"] == 800 and s["r1"]["num"] == 800
-    published = swatch.report(registry=registry)
-    assert published["r0"]["num"] == 800
-    assert swatch.times == {}  # reset: repeated reports never double-count
-    assert registry.histogram("distar_stopwatch_seconds", region="r0").count == 800
-    assert swatch.report(registry=registry) == {}
 
 
 # ------------------------------------------------------------ no-print lint

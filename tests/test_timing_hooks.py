@@ -1,4 +1,4 @@
-"""Direct unit tests for StopWatch and the learner hook registry
+"""Direct unit tests for EasyTimer and the learner hook registry
 (previously exercised only through full learner runs; EasyTimer has a
 basic check in test_utils.py — here it gets the reuse semantics)."""
 import time
@@ -14,7 +14,7 @@ from distar_tpu.learner.hooks import (
     ProfilerHook,
     SaveCkptHook,
 )
-from distar_tpu.utils.timing import EasyTimer, StopWatch
+from distar_tpu.utils.timing import EasyTimer
 
 
 # ------------------------------------------------------------------ timing
@@ -27,31 +27,6 @@ def test_easy_timer_measures_block():
     with t:  # reusable; value overwritten
         pass
     assert t.value < first  # empty block must re-measure, not accumulate
-
-
-def test_stopwatch_disabled_records_nothing():
-    sw = StopWatch(enabled=False)
-    with sw("phase"):
-        time.sleep(0.005)
-    assert sw.times == {} and sw.summary() == {}
-
-
-def test_stopwatch_enabled_accumulates_and_summarises():
-    sw = StopWatch(enabled=True)
-    for _ in range(3):
-        with sw("step"):
-            time.sleep(0.003)
-
-    @sw.decorate("fn")
-    def work(x):
-        return x + 1
-
-    assert work(1) == 2
-    s = sw.summary()
-    assert s["step"]["num"] == 3
-    assert s["step"]["sum"] >= 0.009
-    assert s["step"]["avg"] == pytest.approx(s["step"]["sum"] / 3)
-    assert s["fn"]["num"] == 1
 
 
 # ------------------------------------------------------------------- hooks
