@@ -154,6 +154,45 @@ def test_scatter_connection_add():
     np.testing.assert_array_equal(out[1, 4, 5], [1, 1, 1])
 
 
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("f32", "bf16"))
+@pytest.mark.parametrize("B", (1, 5))
+@pytest.mark.parametrize("mode", ("add", "cover"))
+def test_scatter_connection_against_a_numpy_loop(mode, B, dtype):
+    """out[b, y, x] += emb[b, n], with shared cells and out-of-range
+    locations (clipped into the map); forward exactly, and in add mode the
+    gradient of a sum of squares exactly (2 * out gathered at each entity's
+    cell). Embeddings are small integers, so bf16 sums are exact too; in cover
+    mode a cell takes one writer's value and no (b, cell) is shared."""
+    N, D, H, W = 12, 3, 5, 6
+    rng = np.random.default_rng(7 + B)
+    emb = rng.integers(-4, 5, size=(B, N, D)).astype(np.float32)
+    if mode == "add":
+        loc = rng.integers(-2, 9, size=(B, N, 2))  # (x, y); 9 > W, H: clipped
+        loc[:, 1] = loc[:, 0]  # a shared cell in every frame
+    else:
+        # distinct cells, none the last one: entity 0 is clipped into that
+        cells = np.stack([rng.permutation(H * W - 1)[:N] for _ in range(B)])
+        loc = np.stack([cells % W, cells // W], axis=-1)
+        loc[:, 0] = (W + 3, H + 4)
+    xs, ys = np.clip(loc[..., 0], 0, W - 1), np.clip(loc[..., 1], 0, H - 1)
+    want = np.zeros((B, H, W, D), np.float32)
+    for b in range(B):
+        for n in range(N):
+            if mode == "add":
+                want[b, ys[b, n], xs[b, n]] += emb[b, n]
+            else:
+                want[b, ys[b, n], xs[b, n]] = emb[b, n]
+
+    fn = lambda e: scatter_connection(e, jnp.asarray(loc), (H, W), mode)
+    out = fn(jnp.asarray(emb, dtype))
+    assert out.shape == (B, H, W, D) and out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(out, np.float32), want)
+    if mode == "add":
+        grad = jax.grad(lambda e: jnp.sum(fn(e) ** 2))(jnp.asarray(emb, dtype))
+        want_grad = 2.0 * want[np.arange(B)[:, None], ys, xs]
+        np.testing.assert_array_equal(np.asarray(grad, np.float32), want_grad)
+
+
 def test_scatter_connection_cover():
     B, N, D, H, W = 1, 2, 2, 3, 3
     emb = jnp.array([[[1.0, 1.0], [5.0, 5.0]]])

@@ -1,5 +1,6 @@
 """Every Pallas kernel the repo keeps compiles for a DESCRIBED TPU v5e at
-flagship shapes, forward and grad, in both compute dtypes.
+flagship shapes, forward and grad, in both compute dtypes; and the scatter
+connection's way into the spatial encoder compiles without a relayout loop.
 
 No chip is attached: the TPU compiler installed in this image compiles for a
 topology description and raises what the chip's compiler would raise (a
@@ -29,7 +30,7 @@ DTYPES = (jnp.bfloat16, jnp.float32)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -41,9 +42,14 @@ def one_chip():
     # never be read back without the chip (it warns and recompiles): off
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
 def _attention(one_chip, B, dtype):
@@ -88,3 +94,50 @@ def test_every_kernel_in_the_module_is_covered():
     entry_points = set(re.findall(r"^def ([a-z]\w*)\(", src, flags=re.M))
     for name in KERNELS:
         assert name in entry_points
+
+
+# ------------------------------------------- the scatter connection's layout
+# Why `ops/scatter.py` indexes the map by (y, x, b): its docstring. A change
+# there or in the encoder that brings the compiler's relayout loops or the
+# `dp` all-gathers back fails here, minutes of compile and a chip run before
+# a trace would show it.
+PLANES = 24  # what the spatial encoder concatenates beside the 32-channel map
+MAP = (152, 160)
+
+
+def _scatter_into_conv(emb, loc, planes, w):
+    from distar_tpu.ops import scatter_connection
+
+    m = scatter_connection(emb, loc, MAP, "add")
+    h = jnp.concatenate([planes, m], axis=-1)
+    h = jax.nn.relu(jax.lax.conv_general_dilated(
+        h, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")))
+    h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    return jnp.sum(h.astype(jnp.float32) ** 2)
+
+
+# frames, mesh axis size, `while(` allowed: 6 x 64 SL frames are three lane
+# tiles of 128; RL's (64 + 1) x 6 = 390 are not, and keep the forward loop
+@pytest.mark.parametrize("B,dp,loops", [(384, 1, 0), (384, 4, 0), (390, 1, 1)],
+                         ids=("b384", "b384_dp4", "b390"))
+def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp, loops):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(topo.devices[:dp], ("dp",))
+    rows, repl = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+    dt = jnp.bfloat16
+    args = (
+        jax.ShapeDtypeStruct((B * dp, N, D), dt, sharding=rows),
+        jax.ShapeDtypeStruct((B * dp, N, 2), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((B * dp, *MAP, PLANES), dt, sharding=rows),
+        jax.ShapeDtypeStruct((1, 1, PLANES + D, 32), dt, sharding=repl),
+    )
+    text = jax.jit(jax.value_and_grad(_scatter_into_conv, argnums=(0, 3))).lower(
+        *args).compile().as_text()
+    assert "scatter" in text
+    assert text.count(" while(") <= loops
+    if dp > 1:
+        # frame b writes rows of frame b only: nothing but the weight's
+        # gradient and the scalar crosses chips
+        assert "all-gather" not in text
+        assert f"[{B * dp * N},{D}]" not in text  # the global batch's entities

@@ -21,7 +21,9 @@ Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
       --batch-size 4 --mesh dp=2,fsdp=2        # four chips, per-device bytes
 
 Prints one JSON line per program: trace / compile seconds and
-``memory_analysis()`` bytes (per device).
+``memory_analysis()`` bytes (per device). ``--hlo-dir DIR`` also writes each
+compiled program's text (``compiled.as_text()``: layouts, the compiler's own
+loops and copies, collectives) to ``DIR/<program>.hlo.txt``.
 """
 from __future__ import annotations
 
@@ -61,6 +63,9 @@ def _with_sharding(shapes, shardings):
         shapes, shardings)
 
 
+HLO_DIR = ""  # --hlo-dir
+
+
 def _report(name, jitted, args, extra):
     t0 = time.monotonic()
     lowered = jitted.lower(*args)
@@ -80,6 +85,10 @@ def _report(name, jitted, args, extra):
     row["total_bytes"] = (row["argument_bytes"] + row["temp_bytes"]
                           + row["output_bytes"] - row["alias_bytes"])
     print(json.dumps(row), flush=True)
+    if HLO_DIR:
+        os.makedirs(HLO_DIR, exist_ok=True)
+        with open(os.path.join(HLO_DIR, f"{name}.hlo.txt"), "w") as f:
+            f.write(compiled.as_text())
     return compiled
 
 
@@ -263,7 +272,11 @@ def main() -> None:
                         "devices (default: one device)")
     p.add_argument("--batch-size", type=int, default=0,
                    help="sl/rl only: override the config's batch")
+    p.add_argument("--hlo-dir", default="",
+                   help="write each compiled program's text here")
     args = p.parse_args()
+    global HLO_DIR
+    HLO_DIR = args.hlo_dir
 
     import jax
     from jax.experimental import topologies
