@@ -39,6 +39,9 @@ def _learner(args) -> None:
                        **({"save_path": args.save_path}
                           if args.save_path else {})},
             "learner": {
+                # the config file's learner section (learning rate, clip, ...);
+                # what the command line sets comes after it
+                **user_cfg.get("learner", {}),
                 "batch_size": args.batch_size,
                 "unroll_len": args.traj_len,
                 "log_freq": max(args.iters // 4, 1),
@@ -105,10 +108,12 @@ def _learner(args) -> None:
         # restarted SL learner processes resume from their durable pointer
         learner.resume_latest()
     _run_learner_supervised(args, learner, args.iters)
+    # the policy's learner reports action_type_acc, a token model token_acc
+    accuracy = next(k for k in ("action_type_acc", "token_acc") if k in learner.variable_record.vars())
     print(
         f"sl_train done: {learner.last_iter.val} iters, "
         f"loss={learner.variable_record.get('total_loss').avg:.4f}, "
-        f"action_type_acc={learner.variable_record.get('action_type_acc').avg:.4f}"
+        f"{accuracy}={learner.variable_record.get(accuracy).avg:.4f}"
     )
 
 

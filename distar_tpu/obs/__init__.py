@@ -65,7 +65,7 @@ from .tracestore import (
     set_trace_buffer,
 )
 from .waterfall import build_waterfall, render_listing, render_waterfall
-from .profiler import STEP_SCOPES, ProfilerSession, Spans, feed_spans, loop_spans
+from .profiler import LM_STEP_SCOPES, STEP_SCOPES, ProfilerSession, Spans, feed_spans, loop_spans
 from .perf import (
     PerfMonitor,
     estimate_collective_bytes,
@@ -150,6 +150,7 @@ __all__ = [
     "render_waterfall",
     "ProfilerSession",
     "STEP_SCOPES",
+    "LM_STEP_SCOPES",
     "Spans",
     "feed_spans",
     "loop_spans",

@@ -11,7 +11,8 @@ whenever a profiler session is active, and costs nothing when none is) and
 one observation of the seconds in the role's phase histogram. The learner
 run loop (role ``loop``) and the feeder thread (role ``feed``) use it.
 ``STEP_SCOPES`` is the fixed vocabulary of ``jax.named_scope`` / Flax module
-names under which every operation of a jitted train step is found.
+names under which every operation of a jitted train step is found;
+``LM_STEP_SCOPES`` is the token-sequence learner's.
 """
 from __future__ import annotations
 
@@ -111,6 +112,14 @@ STEP_SCOPES = (
     "scalar_encoder", "entity_encoder", "scatter_connection", "spatial_encoder",
     "core_lstm", "action_type_head", "delay_head", "queued_head",
     "selected_units_head", "target_unit_head", "location_head", "value",
+    "loss", "optimizer", "diagnostics",
+)
+
+# The token-sequence learner's step (``lm_train_step``): the model's parts
+# (``model/lfm2.py``, ``ops/moe.py``) and the three names every step has.
+LM_STEP_SCOPES = (
+    "embed", "short_conv", "attention", "dense_mlp",
+    "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head",
     "loss", "optimizer", "diagnostics",
 )
 

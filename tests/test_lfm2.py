@@ -1,0 +1,233 @@
+"""LFM2 (``model/lfm2.py``, ``ops/moe.py``, ``ops/sequence.py``) against its
+plain reference (``benchmark/references/lfm2_plain.py``, which imports none
+of them) at a tiny size on seeded weights, float32, on the CPU."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark.references import lfm2_plain  # noqa: E402
+from distar_tpu.model import LFM2, default_lfm2_config  # noqa: E402
+from distar_tpu.ops import moe  # noqa: E402
+from distar_tpu.ops.sequence import ShortConv, causal_conv  # noqa: E402
+from distar_tpu.utils import deep_merge_dicts  # noqa: E402
+
+TINY = {"hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+        "num_experts": 8, "num_experts_per_tok": 2, "experts_held": {"offset": 2, "count": 4},
+        "vocab_size": 128}
+B, S = 2, 32
+
+
+def build(seed=0, scale=5.0, **over):
+    """The tiny model with seeded weights. The program draws N(0, 0.02^2),
+    which at width 64 leaves every layer's output far below the residual
+    stream; ``scale`` widens the matrices so that each part moves the logits
+    and a fault in any of them shows."""
+    cfg = deep_merge_dicts(default_lfm2_config(), dict(TINY, **over))
+    model = LFM2(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (B, S), 0, cfg.vocab_size)
+    labels = jax.random.randint(jax.random.PRNGKey(seed + 2), (B, S), 0, cfg.vocab_size)
+    variables = model.init(jax.random.PRNGKey(seed), tokens)
+    params = jax.tree.map(lambda x: x * scale if x.ndim >= 2 else x, variables["params"])
+    return cfg, model, {"params": params, "buffers": variables["buffers"]}, tokens, labels
+
+
+def system_loss(model, variables, params, tokens, labels):
+    from distar_tpu.losses import compute_lm_loss
+
+    logits, stats = model.apply({**variables, "params": params}, tokens)
+    return compute_lm_loss(logits, labels)[0], (logits, stats)
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
+def test_logits_loss_and_every_gradient_leaf_match_the_plain_reference(remat):
+    cfg, model, variables, tokens, labels = build(remat=remat)
+    plain = lfm2_plain.plain_config(cfg)
+    (loss, (logits, stats)), grads = jax.value_and_grad(
+        lambda p: system_loss(model, variables, p, tokens, labels), has_aux=True)(variables["params"])
+    with jax.default_matmul_precision("highest"):
+        (ref_loss, (ref_logits, ref_stats)), ref_grads = jax.value_and_grad(
+            lambda p: lfm2_plain.loss(p, variables, plain, tokens, labels), has_aux=True)(variables["params"])
+    # float32 against float32 on one backend: what differs is the order of the sums (a sorted
+    # buffer and grouped products against a masked loop over experts, blocks of queries against
+    # whole rows). Logits are O(1): 2e-4 absolute is ~100 ulp of headroom over the 1e-6 seen
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-4, rtol=1e-4)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
+    # the routing is discrete: the same picks, or the comparison above means nothing
+    np.testing.assert_array_equal(stats["rows"], np.stack(ref_stats["rows"]))
+    np.testing.assert_allclose(stats["rms"], np.stack(ref_stats["rms"]), rtol=1e-5)
+    np.testing.assert_allclose(stats["ff_rms"], np.stack(ref_stats["ff_rms"]), rtol=1e-4)
+    assert int(stats["overflow"]) == 0
+    flat, ref_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (grads, ref_grads))
+    assert flat.keys() == ref_flat.keys() and len(flat) >= 40
+    for path, g in flat.items():
+        # every leaf, against its own size: a leaf is off when it parts by more than 1e-3 of its
+        # largest entry (sum order again; a wrong term is off by O(1) of it)
+        bound = 1e-3 * float(jnp.abs(ref_flat[path]).max()) + 1e-9
+        np.testing.assert_allclose(g, ref_flat[path], atol=bound, rtol=0, err_msg=jax.tree_util.keystr(path))
+        assert float(jnp.abs(ref_flat[path]).max()) > 0, jax.tree_util.keystr(path)
+
+
+def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """64 experts, top-4, tiny width: the layer as each of the eight members
+    of an expert-parallel group computes it (experts 0-7, 8-15, ...), summed,
+    is the reference's layer over all 64 experts."""
+    d, width, E, k = 32, 16, 64, 4
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, d))
+    whole = moe.ExpertsHeldMoE(E, k, width, 0, E)
+    variables = whole.init(jax.random.PRNGKey(1), x)
+    p = jax.tree.map(lambda a: a * 8.0 if a.ndim >= 2 else a, variables["params"])
+    plain_cfg = {"num_experts_per_tok": k, "experts_held": {"offset": 0, "count": E},
+                 "use_expert_bias": True, "routed_scaling_factor": 1.0}
+    u = lfm2_plain.rms_norm(x, p["norm"]["scale"], 1e-5).reshape(-1, d)
+    with jax.default_matmul_precision("highest"):
+        want, want_rows, _ = lfm2_plain.experts_held(
+            {k_: p[k_] for k_ in ("router", "w1", "w2", "w3")},
+            variables["buffers"]["expert_bias"], u, plain_cfg, None)
+    total, rows = 0.0, []
+    for member in range(8):
+        held = slice(8 * member, 8 * member + 8)
+        share = moe.ExpertsHeldMoE(E, k, width, 8 * member, 8)
+        mine = {"params": {**p, **{w: p[w][held] for w in ("w1", "w2", "w3")}},
+                "buffers": variables["buffers"]}
+        y, stats = share.apply(mine, x)
+        total = total + y
+        rows.append(stats["rows"])
+    np.testing.assert_allclose(total.reshape(-1, d), want, atol=1e-5, rtol=1e-4)
+    np.testing.assert_array_equal(np.concatenate(rows), want_rows)
+    assert int(np.concatenate(rows).sum()) == 2 * 24 * k  # every pick is somebody's
+
+
+def test_short_convolution_is_causal_and_is_the_per_position_formula():
+    z = jax.random.normal(jax.random.PRNGKey(0), (2, 10, 6))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 6))
+    c = causal_conv(z, w)
+    for t in range(10):
+        want = sum(w[k] * (z[:, t - 2 + k] if t - 2 + k >= 0 else 0.0) for k in range(3))
+        np.testing.assert_allclose(c[:, t], want, rtol=1e-6, atol=1e-6)
+    # the whole operator: what comes after position t does not reach position t
+    op = ShortConv(3)
+    u = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 8))
+    v = op.init(jax.random.PRNGKey(3), u)
+    later = u.at[:, 7:].set(jax.random.normal(jax.random.PRNGKey(4), (1, 5, 8)))
+    np.testing.assert_array_equal(op.apply(v, u)[:, :7], op.apply(v, later)[:, :7])
+    assert not np.allclose(op.apply(v, u)[:, 7:], op.apply(v, later)[:, 7:])
+
+
+@pytest.mark.parametrize("S", (16, 128), ids=("s16", "s128_flash_shaped"))
+def test_attention_is_causal_and_groups_four_query_heads_to_a_key_head(S):
+    """At 128 positions the flash kernel's tiles divide the sequence, so the
+    choice is the platform's: on the CPU that is the query-block loop."""
+    from distar_tpu.ops.sequence import CausalGQAttention
+
+    att = CausalGQAttention(heads=4, kv_heads=2, head_dim=8)
+    u = jax.random.normal(jax.random.PRNGKey(0), (1, S, 32))
+    v = att.init(jax.random.PRNGKey(1), u)
+    later = u.at[:, 9:].add(1.0)
+    np.testing.assert_allclose(att.apply(v, u)[:, :9], att.apply(v, later)[:, :9], atol=1e-6)
+    np.testing.assert_allclose(att.apply(v, u), lfm2_plain.attention(v["params"], u, 4, 2, 8, 1e-5, 1e6, None),
+                               atol=1e-5, rtol=1e-4)
+
+
+def test_no_row_is_lost_when_every_position_picks_the_same_held_expert():
+    """The router's bias sends every position to expert 5 (held) first: its
+    group is the whole batch, the others share the second pick, and the
+    layer is still the reference's."""
+    d, width, E, k, N = 16, 8, 8, 2, 40
+    layer = moe.ExpertsHeldMoE(E, k, width, 4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, N, d))
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    p = jax.tree.map(lambda a: a * 8.0 if a.ndim >= 2 else a, variables["params"])
+    bias = jnp.zeros((E,)).at[5].set(10.0)
+    y, stats = layer.apply({"params": p, "buffers": {"expert_bias": bias}}, x)
+    assert int(stats["rows"][1]) == N and int(stats["overflow"]) == 0
+    plain_cfg = {"num_experts_per_tok": k, "experts_held": {"offset": 4, "count": 4},
+                 "use_expert_bias": True, "routed_scaling_factor": 1.0}
+    u = lfm2_plain.rms_norm(x, p["norm"]["scale"], 1e-5).reshape(-1, d)
+    want, rows, _ = lfm2_plain.experts_held(p, bias, u, plain_cfg, None)
+    np.testing.assert_allclose(y.reshape(-1, d), want, atol=1e-5, rtol=1e-4)
+    np.testing.assert_array_equal(stats["rows"], rows)
+
+
+def test_dispatch_sorts_the_picks_by_expert_into_the_provable_bound():
+    sel = jnp.asarray([[0, 1], [0, 1], [0, 2], [1, 0], [3, 0]], jnp.int32)  # expert 0 five times
+    plan = moe.dispatch(sel, 0, 2)                                           # 5 positions x min(2, 2) rows
+    assert plan.rows.tolist() == [5, 3] and int(plan.overflow) == 0
+    assert plan.group_sizes.tolist() == [5, 3]
+    # each present row copies the position that picked it, sorted by expert
+    assert plan.token[:8].tolist() == [0, 1, 2, 3, 4, 0, 1, 3]
+    assert sorted(plan.row.reshape(-1).tolist()) == list(range(8)) + [10, 10]
+    # one expert held: a position can send it one row at most, and the buffer is that long
+    one = moe.dispatch(sel, 0, 1)
+    assert one.token.shape == (5,) and one.rows.tolist() == [5] and int(one.overflow) == 0
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_megablox_as_the_layer_calls_it_is_the_ragged_product(grad):
+    """The TPU branch of ``grouped_matmul`` (its tiles, its argument order) in
+    interpret mode against the branch every other platform runs, on uneven
+    groups with an empty one and an unused tail."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (128, 64))
+    w = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 32))
+    sizes = jnp.asarray([50, 0, 7, 40], jnp.int32)
+    present = (jnp.arange(128) < 97)[:, None]
+    out = lambda f: lambda x, w: jnp.where(present, f(x, w, sizes), 0.0)
+    kernel, plain = out(lambda *a: moe.megablox(*a, interpret=True)), out(jax.lax.ragged_dot)
+    if grad:
+        weight = jax.random.normal(jax.random.PRNGKey(2), (128, 32))
+        kernel, plain = (jax.grad(lambda x, w, f=f: (f(x, w) * weight).sum(), argnums=(0, 1))
+                         for f in (kernel, plain))
+    for got, want in zip(jax.tree.leaves(kernel(x, w)), jax.tree.leaves(plain(x, w))):
+        if got.shape[0] == 128:  # rows beyond the groups are undefined, values and gradients alike
+            got, want = got[:97], want[:97]
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    # off a TPU the layer's product is the plain one: nothing is interpreted
+    np.testing.assert_array_equal(moe.grouped_matmul(x, w, sizes), jax.lax.ragged_dot(x, w, sizes))
+
+
+def test_route_is_sigmoid_top_k_with_a_bias_on_the_selection_only():
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    sel, w = moe.route(logits, jnp.zeros((4,)), 2)
+    s = jax.nn.sigmoid(logits[0])
+    assert sel.tolist() == [[0, 1]]
+    np.testing.assert_allclose(w[0], s[:2] / (s[:2].sum() + 1e-6), rtol=1e-6)
+    sel, w = moe.route(logits, jnp.asarray([0.0, 0.0, 0.0, 5.0]), 2)   # the bias picks expert 3...
+    assert sorted(sel[0].tolist()) == [0, 3]
+    picked = s[jnp.asarray(sorted(sel[0].tolist()))]                   # ...and its weight is its score
+    np.testing.assert_allclose(sorted(w[0].tolist()), sorted((picked / (picked.sum() + 1e-6)).tolist()),
+                               rtol=1e-6)
+
+
+def test_take_rows_gradient_is_the_gathers_own():
+    src = jax.random.normal(jax.random.PRNGKey(0), (5, 3))
+    idx = jnp.asarray([4, 0, 0, 2, 4, 4])
+    takers = jnp.asarray([[1, 2, 6], [6, 6, 6], [3, 6, 6], [6, 6, 6], [0, 4, 5]])
+    weight = jax.random.normal(jax.random.PRNGKey(1), (6, 3))
+    got = jax.grad(lambda s: (moe.take_rows(s, idx, takers) * weight).sum())(src)
+    want = jax.grad(lambda s: (s[idx] * weight).sum())(src)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def test_the_steps_scopes_are_on_the_compiled_program(tmp_path):
+    """Every name of ``LM_STEP_SCOPES`` is on the op_name paths of the
+    lowered ``lm_train_step``: what the benchmark's trace reader looks for."""
+    import optax
+
+    from distar_tpu.learner.lm_learner import make_lm_train_step
+    from distar_tpu.obs import LM_STEP_SCOPES, tree_spec
+
+    cfg, model, variables, tokens, labels = build()
+    optimizer = optax.adam(1e-3)
+    step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
+    text = step.lower(variables, optimizer.init(variables["params"]),
+                      {"tokens": tokens, "labels": labels}).as_text(debug_info=True)
+    assert "lm_train_step" in text
+    missing = [name for name in LM_STEP_SCOPES if f"/{name}" not in text and f"({name})" not in text]
+    assert not missing, missing

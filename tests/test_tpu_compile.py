@@ -141,3 +141,41 @@ def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp,
         # gradient and the scalar crosses chips
         assert "all-gather" not in text
         assert f"[{B * dp * N},{D}]" not in text  # the global batch's entities
+
+
+# ------------------------------------------------ the token model's kernels
+# JAX's own kernels at LFM2-24B-A2B's published widths, as ops/moe.py and
+# ops/sequence.py call them (their tilings and block sizes): 8 held experts of
+# 2048 x 1536 over a buffer of 32,768 rows; 32 heads of 64 over 8,192 positions.
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_grouped_expert_products_compile_for_v5e(one_chip, grad):
+    from distar_tpu.ops import moe
+
+    # the backend here is the CPU, the target is not: the product is chosen by what is compiled for
+    rows, d, width, experts = 32768, 2048, 1536, 8
+    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+    w1 = jax.ShapeDtypeStruct((experts, d, width), jnp.bfloat16, sharding=one_chip)
+    w2 = jax.ShapeDtypeStruct((experts, width, d), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
+
+    def fn(x, w1, w3, w2, sizes):
+        h = jax.nn.silu(moe.grouped_matmul(x, w1, sizes)) * moe.grouped_matmul(x, w3, sizes)
+        return jnp.sum(moe.grouped_matmul(h, w2, sizes).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2, 3)) if grad else fn).lower(x, w1, w1, w2, sizes).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (9 if grad else 3)
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad):
+    from distar_tpu.ops.sequence import causal_attention
+
+    B, S, Hkv, G, Dh = 4, 8192, 8, 4, 64
+    q = jax.ShapeDtypeStruct((B, S, Hkv, G, Dh), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, Dh), jnp.bfloat16, sharding=one_chip)
+    fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, Dh ** -0.5).astype(jnp.float32) ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no S x S score tensor is held (8.6 GB a sequence in float32): the temporaries are the
+    # library's row statistics, which its backward pass broadcasts to the key block's width
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9

@@ -14,6 +14,8 @@ Programs (shapes, dtype and batch from the committed configs that
          by ``make_sl_train_step`` as ``SLLearner`` builds it
   rl     the RL train step (teacher KL, six baselines), ``make_rl_train_step``
   actor  ``sample_action`` and ``teacher_logits`` at the actor's env batch
+  lm     the token-sequence train step (``make_lm_train_step`` as ``LMLearner``
+         builds it) of ``configs/lfm2_24b_a2b_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -40,6 +42,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SL_CONFIG = os.path.join(REPO, "configs", "sl_flagship_v5e.yaml")
 RL_CONFIG = os.path.join(REPO, "configs", "rl_flagship_v5e.yaml")
+LM_CONFIG = os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml")
 
 
 def _specs(tree, sharding):
@@ -215,6 +218,39 @@ def check_rl(topo, cfg, batch_size, mesh_spec):
         {"batch": B, "unroll": T, "dtype": model_cfg.dtype, "mesh": dict(mesh.shape)})
 
 
+def check_lm(topo, cfg, batch_size, mesh_spec):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distar_tpu.learner.lm_learner import LM_LEARNER_DEFAULTS, make_lm_train_step
+    from distar_tpu.model import LFM2, default_lfm2_config
+    from distar_tpu.parallel.mesh import batch_sharding, fsdp_param_sharding
+    from distar_tpu.utils import deep_merge_dicts
+
+    lc, optimizer, dynamics, mesh, _ = _learner_setup(
+        topo, cfg, LM_LEARNER_DEFAULTS, batch_size, mesh_spec)
+    model_cfg = deep_merge_dicts(default_lfm2_config(), cfg.get("model", {}))
+    B, S = lc.batch_size, lc.unroll_len
+    model = LFM2(model_cfg)
+    flat = batch_sharding(mesh, batch_size=B)
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=flat)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    param_sh = fsdp_param_sharding(mesh, variables)
+    opt = jax.eval_shape(optimizer.init, variables["params"])
+    opt_sh = fsdp_param_sharding(mesh, opt)
+    step = jax.jit(make_lm_train_step(model, optimizer, dynamics=dynamics),
+                   donate_argnums=(0, 1),
+                   out_shardings=(param_sh, opt_sh, NamedSharding(mesh, P())))
+    n_params = sum(x.size for x in jax.tree.leaves(variables["params"]))
+    return _report(
+        "lm_train_step", step,
+        (_with_sharding(variables, param_sh), _with_sharding(opt, opt_sh),
+         {"tokens": tokens, "labels": tokens}),
+        {"batch": B, "seq": S, "dtype": model_cfg.dtype, "params": n_params,
+         "mesh": dict(mesh.shape)})
+
+
 def check_actor(topo, cfg):
     """The two programs ``actor.inference.BatchedInference`` jits, at the
     actor's env batch, on one device."""
@@ -271,7 +307,7 @@ def main() -> None:
                    help="sl/rl only: compile for this mesh over the topology's "
                         "devices (default: one device)")
     p.add_argument("--batch-size", type=int, default=0,
-                   help="sl/rl only: override the config's batch")
+                   help="sl/rl/lm only: override the config's batch")
     p.add_argument("--hlo-dir", default="",
                    help="write each compiled program's text here")
     args = p.parse_args()
@@ -296,8 +332,10 @@ def main() -> None:
             check_rl(topo, read_config(RL_CONFIG), args.batch_size, args.mesh)
         elif what == "actor":
             check_actor(topo, read_config(RL_CONFIG))
+        elif what == "lm":
+            check_lm(topo, read_config(LM_CONFIG), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm)")
 
 
 if __name__ == "__main__":
