@@ -4,21 +4,28 @@ Role parity with the reference encoders
 (reference: distar/agent/default/model/obs_encoder/*.py, encoder.py) with
 TPU-first reformulations:
 
-* Entity features are *not* materialised as a 997-wide one-hot concat then
-  projected (entity_encoder.py:59-78); each categorical field gets its own
-  embedding table into the transformer width and the contributions are
-  summed — mathematically identical to concat->Dense (split the kernel by
-  rows) but lowers to gathers + adds instead of a huge sparse matmul.
+* Entity features are projected as the reference projects them
+  (entity_encoder.py:59-80): each entity's 997-wide row of one-hots, position
+  bits and raw floats times one ``[997, width]`` matrix, one product forward
+  and one (``rows^T x g``) for every table's gradient. The parameters stay one
+  leaf a field (the matrix is their concatenation, made at trace time), and the
+  rows are rebuilt in the backward pass, not kept. A table a field with a
+  gather each and their sum, the form this module had until PR 26, was the
+  flagship step's largest avoidable cost on the v5e: 26 gathers forward and 26
+  scatter-adds backward that write one row at a time (2.0 ms each at 196,608
+  rows, 52.7 ms a step), where the whole product is 100 GFLOP (0.5 ms).
 * Spatial maps are NHWC (TPU conv layout); effect coordinate lists are
   scattered into planes with one fused scatter.
 * All fixed shapes: entities padded to MAX_ENTITY_NUM, map fixed 152x160.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from .config import cdtype, static_cfg
@@ -37,28 +44,131 @@ from ..ops.transformer import TransformerLayer
 from ..ops.blocks import build_activation
 
 
-def _field_sum_embed(mdl_prefix: str, fields, x: Dict[str, jnp.ndarray], width: int, dtype):
-    """Sum of per-field projections into ``width`` (== concat->Dense)."""
-    total = None
-    for key, arc, n in fields:
-        v = x[key]
+class _FieldTable(nn.Module):
+    """One field's rows of the entity projection: the leaf ``<name>/embedding``
+    that ``nn.Embed`` would hold, or ``<name>/kernel`` as ``nn.Dense`` would,
+    drawn by that module's initialiser (checkpoints and ``ref_convert`` see
+    the same tree and the same values from the same seed)."""
+
+    rows: int
+    width: int
+    arc: str
+
+    @nn.compact
+    def __call__(self):
+        if self.arc == "one_hot":
+            leaf, init = "embedding", nn.linear.default_embed_init
+        else:
+            leaf, init = "kernel", nn.linear.default_kernel_init
+        return self.param(leaf, init, (self.rows, self.width))
+
+
+_LANES = 128  # columns of one tile of `_field_rows`: the lane width of a TPU vector register
+
+
+def _field_rows(fields, values, dtype):
+    """``[..., columns]``: each entity's row of the reference's concat, in the
+    fields' order: the one-hot of the clamped id (``ops.one_hot``'s clamp), the
+    position's bits high bit first (``ops.binary_encode``), the raw float.
+
+    Written a lane tile of columns at a time, from the fields that reach into
+    the tile, and not a field at a time: XLA writes such tiles straight into the
+    matrix, where it writes 36 pieces of 1 to 327 columns apart and then copies
+    them together (value and gradient of the embedding at 196,608 rows: 6.1
+    against 10.9 ms on the v5e, PR 26).
+    """
+    spans, end = [], 0
+    for (_key, arc, n), v in zip(fields, values):
         if arc == "one_hot":
-            emb = nn.Embed(n, width, dtype=dtype, name=f"{mdl_prefix}_{key}")(
-                jnp.clip(v.astype(jnp.int32), 0, n - 1)
-            )
+            v = jnp.clip(v.astype(jnp.int32), 0, n - 1)
         elif arc == "binary":
-            emb = nn.Dense(width, use_bias=False, dtype=dtype, name=f"{mdl_prefix}_{key}")(
-                binary_encode(v, n)
-            )
+            v = v.astype(jnp.int32)
         elif arc == "float":
-            w = nn.Dense(width, use_bias=False, dtype=dtype, name=f"{mdl_prefix}_{key}")(
-                v.astype(jnp.float32)[..., None]
-            )
-            emb = w
+            v = v.astype(jnp.float32)
         else:
             raise NotImplementedError(arc)
-        total = emb if total is None else total + emb
-    return total
+        start, end = end, end + (1 if arc == "float" else n)
+        spans.append((start, end, arc, n, v[..., None]))
+    tiles = []
+    for lo in range(0, end, _LANES):
+        hi = min(lo + _LANES, end)
+        columns = jnp.arange(lo, hi)
+        hot, raw = False, 0.0  # fields share no column: at most one term is not zero
+        for start, stop, arc, n, v in spans:
+            if stop <= lo or start >= hi:
+                continue
+            k = columns - start  # the column's place in this field
+            if arc == "one_hot":
+                hot |= v == k
+            elif arc == "binary":
+                bit = (v >> jnp.clip(n - 1 - k, 0, n - 1)) & 1
+                hot |= (bit == 1) & (k >= 0) & (k < n)
+            else:
+                raw += jnp.where(k == 0, v, 0.0)
+        tiles.append(jnp.where(hot, 1.0, raw).astype(dtype))
+    return jnp.concatenate(tiles, axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _project_fields(fields, dtype, values, table):
+    """``rows(values) @ table`` in ``dtype`` with float32 accumulation.
+
+    The backward pass is the one product ``rows^T x g`` and builds the rows
+    again from ``values`` (the observations; with the table, which is a
+    parameter, the only residuals), so the ``[..., columns]`` matrix never
+    lives from the forward to the backward pass. ``values`` are data: the
+    integer fields have no gradient, a ``float`` field's is ``g`` times its one
+    row of the table, as ``nn.Dense`` gave it (a program that asks for none
+    drops that product). Reverse mode only: a ``custom_vjp`` has no jvp rule,
+    so forward-mode differentiation through the encoder raises.
+    """
+    rows = _field_rows(fields, values, dtype)
+    out = jnp.einsum("...c,cw->...w", rows, table.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype)
+
+
+def _project_fields_fwd(fields, dtype, values, table):
+    return _project_fields(fields, dtype, values, table), (values, table)
+
+
+def _project_fields_bwd(fields, dtype, residuals, g):
+    values, table = residuals
+    # the barrier ties the rebuilt rows to `g`: without it XLA finds them equal
+    # to the forward pass's and keeps those instead (392 MB at 6 x 64 frames,
+    # through the whole step), as it would under `jax.checkpoint` without its own
+    values, g = jax.lax.optimization_barrier((values, g))
+    g = g.astype(dtype)
+    rows = _field_rows(fields, values, dtype)
+    # float32 like the leaves it is split into: accumulated over every entity
+    # of the batch and never rounded to the compute dtype
+    d_table = jnp.einsum("...c,...w->cw", rows, g, preferred_element_type=jnp.float32)
+    d_values, column = [], 0
+    for (_key, arc, n), v in zip(fields, values):
+        if not jnp.issubdtype(v.dtype, jnp.floating):
+            d_values.append(np.zeros(v.shape, jax.dtypes.float0))
+        elif arc == "float":  # the column holds the observation itself
+            d = jnp.einsum("...w,w->...", g, table[column].astype(dtype),
+                           preferred_element_type=jnp.float32)
+            d_values.append(d.astype(v.dtype))
+        else:  # an id that came as a float: cast to an integer, so no gradient
+            d_values.append(jnp.zeros_like(v))
+        column += 1 if arc == "float" else n
+    return tuple(d_values), d_table
+
+
+_project_fields.defvjp(_project_fields_fwd, _project_fields_bwd)
+
+
+def _field_sum_embed(mdl_prefix: str, fields, x: Dict[str, jnp.ndarray], width: int, dtype):
+    """Sum of per-field projections into ``width``, as concat -> Dense: the
+    fields' leaves stacked along rows make the reference's one matrix."""
+    fields = tuple(tuple(f) for f in fields)
+    table = jnp.concatenate([
+        _FieldTable(n if arc != "float" else 1, width, arc, name=f"{mdl_prefix}_{key}")()
+        for key, arc, n in fields
+    ])
+    return _project_fields(fields, dtype, tuple(x[key] for key, _, _ in fields), table)
 
 
 class BeginningBuildOrderEncoder(nn.Module):
@@ -197,8 +307,8 @@ class SpatialEncoder(nn.Module):
 
 
 class EntityEncoder(nn.Module):
-    """Per-field embedding-sum -> 3-layer set transformer -> per-entity
-    embeddings + masked-mean pooled embedding
+    """997-wide field rows x one matrix -> 3-layer set transformer ->
+    per-entity embeddings + masked-mean pooled embedding
     (role of reference entity_encoder.py:20-96)."""
 
     cfg: dict
@@ -207,7 +317,8 @@ class EntityEncoder(nn.Module):
     def __call__(self, x: Dict[str, jnp.ndarray], entity_num: jnp.ndarray):
         ent = static_cfg(self.cfg).encoder.entity
         width = ent.output_dim
-        # field-sum embedding == reference's concat(one-hots) @ W_embed
+        # the reference's concat(one-hots, bits, floats) @ W_embed, W_embed held
+        # as one leaf a field
         h = _field_sum_embed("ent", ent.fields, x, width, cdtype(self.cfg))
         bias = self.param("ent_embed_bias", nn.initializers.zeros_init(), (width,))
         h = jax.nn.relu(h + bias)

@@ -146,8 +146,8 @@ def convert_entity_encoder(sd: Dict, cfg) -> Dict:
 
     The reference materialises each entity as a 997-wide one-hot/binary/raw
     concat and projects with transformer.embedding (fc 997->256,
-    entity_encoder.py:59-80); our per-field embedding-sum is the same map
-    with W^T split row-wise at each field's column offset."""
+    entity_encoder.py:59-80); ours is the same product, with W^T kept as one
+    leaf a field, split row-wise at each field's column offset."""
     ent = cfg.encoder.entity
     W = np.asarray(sd["transformer.embedding.0.weight"])  # [width, total]
     bias = np.asarray(sd["transformer.embedding.0.bias"])

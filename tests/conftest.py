@@ -154,3 +154,31 @@ SMALL_MODEL = {
     },
     "value": {"res_dim": 8, "res_num": 1},
 }
+
+
+def gather_and_add_embed(mdl_prefix, fields, x, width, dtype):
+    """The entity encoder's field embedding as a table a field, a gather each
+    and their sum: the form ``model/encoders.py::_field_sum_embed`` had until
+    PR 26, kept as the plain reference of the one-product form (value, every
+    leaf's gradient, the parameter tree and its seeded values). Call it inside
+    an ``nn.compact`` method, as the encoder calls its own."""
+    import jax.numpy as jnp
+    from flax import linen as nn
+
+    from distar_tpu.ops import binary_encode
+
+    total = None
+    for key, arc, n in fields:
+        v, name = x[key], f"{mdl_prefix}_{key}"
+        if arc == "one_hot":
+            emb = nn.Embed(n, width, dtype=dtype, name=name)(
+                jnp.clip(v.astype(jnp.int32), 0, n - 1))
+        elif arc == "binary":
+            emb = nn.Dense(width, use_bias=False, dtype=dtype, name=name)(binary_encode(v, n))
+        elif arc == "float":
+            emb = nn.Dense(width, use_bias=False, dtype=dtype, name=name)(
+                v.astype(jnp.float32)[..., None])
+        else:
+            raise NotImplementedError(arc)
+        total = emb if total is None else total + emb
+    return total

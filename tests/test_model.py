@@ -5,9 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax import linen as nn
 
+from conftest import gather_and_add_embed  # the entity embedding's plain reference
 from distar_tpu.lib import features as F
-from distar_tpu.model import Model, default_model_config
+from distar_tpu.model import Model, default_model_config, encoders, ref_convert
 
 B = 2
 
@@ -351,3 +353,183 @@ def test_remat_preserves_numerics(rng):
     assert jnp.allclose(v0, v1, rtol=1e-5), (v0, v1)
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         assert jnp.allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------------------ the entity embedding as one product
+# `encoders._field_sum_embed` multiplies each entity's 997-wide row by the
+# fields' leaves stacked into one matrix; `conftest.gather_and_add_embed` is the
+# form it replaced (a table a field, a gather each, 35 adds), kept as the plain
+# reference: same leaves, same seeded values, same function of them.
+ENT_WIDTH, ENT_SLOTS = 32, 24
+
+
+class _FieldEmbed(nn.Module):
+    form: object
+    fields: tuple
+    dtype: object
+
+    @nn.compact
+    def __call__(self, x):
+        return self.form("ent", self.fields, x, ENT_WIDTH, self.dtype)
+
+
+def _entity_fields(cfg):
+    return tuple(tuple(f) for f in cfg.encoder.entity.fields)
+
+
+def _entity_inputs(fields, ids, rng, frames=3):
+    """One array a field; ``ids``: 'in_range', 'out_of_range' (below 0 and past
+    the last class: the clamp) or 'zeros' (what the benchmark's traffic holds)."""
+    shape, x = (frames, ENT_SLOTS), {}
+    for key, arc, n in fields:
+        if ids == "zeros":
+            x[key] = np.zeros(shape, np.float32 if arc == "float" else np.int64)
+        elif arc == "float":
+            x[key] = rng.normal(size=shape).astype(np.float32)
+        elif arc == "binary":
+            x[key] = rng.integers(0, 2 ** n, size=shape)
+        elif ids == "out_of_range":
+            x[key] = rng.integers(-3, n + 4, size=shape)
+        else:
+            x[key] = rng.integers(0, n, size=shape)
+    return jax.tree.map(jnp.asarray, x)
+
+
+@pytest.mark.parametrize("ids", ("in_range", "out_of_range", "zeros"))
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("f32", "bf16"))
+def test_entity_embedding_product_matches_gather_and_add(small_cfg, dtype, ids):
+    """Value and every leaf's gradient. float32: the same numbers to 1e-5 of
+    the largest. bfloat16: both forms against the float32 reference; the product
+    rounds its 36-term sum once (the reference form 35 times) and accumulates
+    the tables' gradients in float32, so it must come no further from float32
+    than one bf16 rounding of operands and result allows (2^-7 of the largest)
+    and not further than the form it replaced."""
+    fields = _entity_fields(small_cfg)
+    assert {arc for _, arc, _ in fields} == {"one_hot", "binary", "float"}
+    rng = np.random.default_rng(26)
+    x = _entity_inputs(fields, ids, rng)
+    ref32 = _FieldEmbed(gather_and_add_embed, fields, jnp.float32)
+    params = ref32.init(jax.random.PRNGKey(1), x)
+    cot = jnp.asarray(rng.normal(size=(3, ENT_SLOTS, ENT_WIDTH)).astype(np.float32))
+
+    def value_and_grads(form, dt):
+        m = _FieldEmbed(form, fields, dt)
+        out = m.apply(params, x)
+        assert out.dtype == dt and out.shape == cot.shape
+        grads = jax.grad(lambda p: jnp.sum(m.apply(p, x).astype(jnp.float32) * cot))(params)
+        assert jax.tree.structure(grads) == jax.tree.structure(params)
+        return [np.asarray(out, np.float32)] + [
+            np.asarray(g) for g in jax.tree.leaves(grads)]
+
+    def worst(got, want):  # largest error over value and leaves, each by its own scale
+        return max(np.abs(g - w).max() / max(np.abs(w).max(), 1e-6)
+                   for g, w in zip(got, want))
+
+    want = value_and_grads(gather_and_add_embed, jnp.float32)
+    got = value_and_grads(encoders._field_sum_embed, dtype)
+    if dtype == jnp.float32:
+        assert worst(got, want) < 1e-5
+    else:
+        replaced = value_and_grads(gather_and_add_embed, dtype)
+        assert worst(got, want) < 2.0 ** -7
+        assert worst(got, want) <= worst(replaced, want) + 1e-6
+
+
+def test_entity_embedding_gradient_reaches_the_float_observations(small_cfg):
+    """The backward rule is written by hand: a ``float`` field's cotangent is
+    what ``nn.Dense`` gave it in the gather-and-add form (``g`` times the
+    field's one row), not a silent zero; the integer fields have none."""
+    fields = _entity_fields(small_cfg)
+    rng = np.random.default_rng(27)
+    x = _entity_inputs(fields, "in_range", rng)
+    floats = {key: x[key] for key, arc, _ in fields if arc == "float"}
+    assert floats
+    ref = _FieldEmbed(gather_and_add_embed, fields, jnp.float32)
+    params = ref.init(jax.random.PRNGKey(1), x)
+    cot = jnp.asarray(rng.normal(size=(3, ENT_SLOTS, ENT_WIDTH)).astype(np.float32))
+
+    def d_floats(form):
+        m = _FieldEmbed(form, fields, jnp.float32)
+        return jax.grad(lambda f: jnp.sum(m.apply(params, {**x, **f}) * cot))(floats)
+
+    got, want = d_floats(encoders._field_sum_embed), d_floats(gather_and_add_embed)
+    for key in floats:
+        assert np.abs(np.asarray(want[key])).max() > 0.1
+        np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]),
+                                   rtol=1e-5, atol=1e-6, err_msg=key)
+
+
+def _reference_entity_state_dict(cfg, rng):
+    """A state_dict with the reference EntityEncoder's keys and shapes (torch
+    Linear weights are [out, in]) for ``ref_convert.convert_entity_encoder``."""
+    ent = cfg.encoder.entity
+    cols = sum(1 if arc == "float" else n for _, arc, n in ent.fields)
+    w, hd = ent.output_dim, ent.head_num * ent.head_dim
+    sd = {}
+
+    def linear(prefix, out_dim, in_dim):
+        sd[f"{prefix}.0.weight"] = rng.normal(size=(out_dim, in_dim)).astype(np.float32)
+        sd[f"{prefix}.0.bias"] = np.zeros(out_dim, np.float32)
+
+    def layernorm(prefix):
+        sd[f"{prefix}.weight"], sd[f"{prefix}.bias"] = np.ones(w, np.float32), np.zeros(w, np.float32)
+
+    linear("transformer.embedding", w, cols)
+    for i in range(ent.layer_num):
+        p = f"transformer.layers.{i}"
+        linear(f"{p}.attention.attention_pre", 3 * hd, w)
+        linear(f"{p}.attention.project", w, hd)
+        layernorm(f"{p}.layernorm1")
+        layernorm(f"{p}.layernorm2")
+        dims = [w] + [ent.hidden_dim] * (ent.mlp_num - 1) + [w]
+        for j in range(ent.mlp_num):
+            linear(f"{p}.mlp.{j}", dims[j + 1], dims[j])
+    linear("entity_fc", w, w)
+    linear("embed_fc", w, w)
+    return sd
+
+
+def test_entity_encoder_parameter_tree_is_pinned(small_cfg):
+    """Checkpoints, `ref_convert` and the benchmark's reference process (which
+    draws its weights from the same seed) see the tree the gather form had: the
+    leaf paths, shapes and dtypes `convert_entity_encoder` writes, and in every
+    `ent_*` leaf the values `nn.Embed` / `nn.Dense` of that name draw."""
+    fields = _entity_fields(small_cfg)
+    x = _entity_inputs(fields, "in_range", np.random.default_rng(0), frames=2)
+    key = jax.random.PRNGKey(7)
+    params = encoders.EntityEncoder(small_cfg).init(
+        key, x, jnp.asarray([ENT_SLOTS, 5], jnp.int32))
+
+    converted = ref_convert.convert_entity_encoder(
+        _reference_entity_state_dict(small_cfg, np.random.default_rng(1)), small_cfg)
+    spec = lambda tree: {
+        jax.tree_util.keystr(path): (np.shape(leaf), np.asarray(leaf).dtype)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)}
+    assert spec(params) == spec(converted)
+
+    assert small_cfg.encoder.entity.output_dim == ENT_WIDTH
+    drawn = _FieldEmbed(gather_and_add_embed, fields, jnp.float32).init(key, x)["params"]
+    assert sorted(drawn) == sorted(k for k in params["params"] if k != "ent_embed_bias"
+                                   and k.startswith("ent_"))
+    for name, leaves in drawn.items():
+        for leaf, value in leaves.items():
+            np.testing.assert_array_equal(
+                np.asarray(params["params"][name][leaf]), np.asarray(value), err_msg=name)
+
+
+def test_entity_encoder_gradient_has_no_gather_and_no_scatter(small_cfg):
+    """The lowered program of the encoder's gradient, whatever the platform:
+    every table is reached through the one product, none by rows."""
+    fields = _entity_fields(small_cfg)
+    x = _entity_inputs(fields, "in_range", np.random.default_rng(0), frames=2)
+    num = jnp.asarray([ENT_SLOTS, 5], jnp.int32)
+    enc = encoders.EntityEncoder(small_cfg)
+    params = enc.init(jax.random.PRNGKey(0), x, num)
+
+    def loss(p, x, num):
+        per_entity, pooled, _mask = enc.apply(p, x, num)
+        return jnp.sum(per_entity.astype(jnp.float32) ** 2) + jnp.sum(pooled.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).lower(params, x, num).as_text()
+    assert "stablehlo.dot_general" in text
+    assert "stablehlo.gather" not in text and "stablehlo.scatter" not in text

@@ -1,6 +1,7 @@
 """Every Pallas kernel the repo keeps compiles for a DESCRIBED TPU v5e at
-flagship shapes, forward and grad, in both compute dtypes; and the scatter
-connection's way into the spatial encoder compiles without a relayout loop.
+flagship shapes, forward and grad, in both compute dtypes; the scatter
+connection's way into the spatial encoder compiles without a relayout loop; and
+the entity embedding compiles to two products with no row gathered or scattered.
 
 No chip is attached: the TPU compiler installed in this image compiles for a
 topology description and raises what the chip's compiler would raise (a
@@ -141,6 +142,65 @@ def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp,
         # gradient and the scalar crosses chips
         assert "all-gather" not in text
         assert f"[{B * dp * N},{D}]" not in text  # the global batch's entities
+
+
+# ------------------------------------------------------ the entity embedding
+# Why `model/encoders.py` multiplies a 997-wide row by one matrix: its module
+# docstring. On this chip a gather or a scatter-add over a `[n, 256]` table is a
+# `kCustom` fusion that moves one row at a time (2.0 ms at 196,608 rows, 52 of
+# them a step until PR 26); a change that brings one back, or that keeps the
+# 392 MB row matrix from the forward to the backward pass, fails here.
+ROW_MATRIX_BYTES = 384 * N * 997 * 2
+
+
+def _embedding_with_an_mlp_behind(form, one_chip):
+    """Compiled value and gradient of relu(embedding) -> 256-1024-256 MLP at
+    6 x 64 frames of 512 entities, bf16: the MLP stands for the transformer
+    between the embedding's two passes."""
+    from flax import linen as nn
+    from distar_tpu.model import default_model_config
+
+    fields = tuple(tuple(f) for f in default_model_config().encoder.entity.fields)
+
+    class Fragment(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            h = jax.nn.relu(form("ent", fields, x, 256, jnp.bfloat16))
+            h = jax.nn.relu(nn.Dense(1024, dtype=jnp.bfloat16)(h))
+            return nn.Dense(256, dtype=jnp.bfloat16)(h)
+
+    m = Fragment()
+    dtypes = {k: jnp.float32 if arc == "float" else jnp.int32 for k, arc, _ in fields}
+    params = jax.eval_shape(
+        lambda: m.init(jax.random.PRNGKey(0), {k: jnp.zeros((1, 4), d) for k, d in dtypes.items()}))
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    loss = lambda p, x: jnp.sum(m.apply(p, x).astype(jnp.float32) ** 2)
+    return jax.jit(jax.value_and_grad(loss)).lower(
+        jax.tree.map(lambda s: on_chip(s.shape, s.dtype), params),
+        {k: on_chip((384, N), d) for k, d in dtypes.items()},
+    ).compile()
+
+
+def _row_moves(compiled):
+    """`kCustom` fusions with a `[rows, 256]` result: a table's gradient
+    (`[n, 256]`) or every entity's row of one (`[196608, 256]`)."""
+    return re.findall(r"= \w+\[\d+,256\]\S* fusion\([^\n]*kind=kCustom", compiled.as_text())
+
+
+def test_entity_embedding_compiles_to_products_that_move_no_rows(one_chip):
+    from conftest import gather_and_add_embed
+    from distar_tpu.model.encoders import _field_sum_embed
+
+    gathers = _embedding_with_an_mlp_behind(gather_and_add_embed, one_chip)
+    product = _embedding_with_an_mlp_behind(_field_sum_embed, one_chip)
+    assert len(_row_moves(gathers)) >= 52  # the pattern sees 26 gathers and 26 scatter-adds
+    assert not _row_moves(product) and "kind=kCustom" not in product.as_text()
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes
+    assert temp(product) <= temp(gathers) + 100e6
+    # one row matrix at a time, built again for the backward pass: a copy kept
+    # through the MLP (XLA merges the two builds unless the backward's is tied to
+    # its cotangent) makes 1.39 GB of temporaries, rebuilt 0.61 GB
+    assert temp(product) < 2 * ROW_MATRIX_BYTES
 
 
 # ------------------------------------------------ the token model's kernels
