@@ -18,8 +18,8 @@ The contract, enforced by ``perf_gate``'s scaling gate:
     matching top-level ``host_cores``); anything else is refused exit 2.
 
 ``tools/pin.py`` is the CLI over this module (plan / pin a pid / exec a
-command pinned); ``tools/loadgen.py --mode fleet``, the ``BENCH_MODE=
-replay`` sweeps and the chaos drills call it directly.
+command pinned); ``tools/loadgen.py --mode fleet`` and the chaos drills
+call it directly.
 """
 from __future__ import annotations
 

@@ -380,10 +380,9 @@ class BaseLearner:
         live mesh — while the current step trains. Disable with
         learner.prefetch_depth=0."""
         from ..parallel.feeder import ShardFeeder
-        from .prefetch import DevicePrefetcher
 
         depth = int(self.cfg.learner.get("prefetch_depth", 2))
-        if depth <= 0 or isinstance(self._dataloader, (ShardFeeder, DevicePrefetcher)):
+        if depth <= 0 or isinstance(self._dataloader, ShardFeeder):
             return
         if type(self)._place_batch is BaseLearner._place_batch:
             return  # learner doesn't define placement

@@ -16,8 +16,7 @@ The RL trajectory batch layout (time-major, mirroring the reference's
   model_last_iter           [B]
 
 Fake dataloaders (role of the reference FakeDataloader, rl_learner.py:196)
-produce schema-complete random batches for learner job_type 'train_test' and
-for bench.py.
+produce schema-complete random batches for learner job_type 'train_test'.
 """
 from __future__ import annotations
 

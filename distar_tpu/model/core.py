@@ -173,8 +173,6 @@ class Model(nn.Module):
         self.core_lstm = StackedLSTM(
             hidden_size=core.hidden_size, num_layers=core.num_layers, norm="LN",
             dtype=cdtype(self.cfg),
-            scan_unroll=int(core.get("scan_unroll", 1)),
-            layer_major=bool(core.get("layer_major", True)),
         )
         if static_cfg(self.cfg).use_value_network:
             self.value_networks = {

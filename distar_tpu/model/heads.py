@@ -142,11 +142,6 @@ class SelectedUnitsHead(nn.Module):
             "end_embedding", nn.initializers.uniform(scale=2.0 / (32 ** 0.5)), (hc.key_dim,)
         )
 
-    def _scan_unroll(self) -> int:
-        # lax.scan unroll for the 64-step pointer decode (pure scheduling
-        # knob, same as encoder.core_lstm.scan_unroll)
-        return int(static_cfg(self.cfg).policy.selected_units_head.get("scan_unroll", 1))
-
     def _keys(self, entity_embedding, entity_num):
         """Per-entity keys with the end token written at index entity_num.
         Returns key [B, N+1, K] and validity mask [B, N+1]."""
@@ -266,7 +261,6 @@ class SelectedUnitsHead(nn.Module):
             lambda mdl, carry, x: tuple(reversed(mdl._lstm(x, carry))),
             variable_broadcast="params",
             split_rngs={"params": False},
-            unroll=self._scan_unroll(),
         )(self, states0, q_in.transpose(1, 0, 2))
         lstm_out = lstm_out.transpose(1, 0, 2)  # [B, S, K]
         logits = jnp.einsum("bsk,bnk->bsn", lstm_out, key).astype(jnp.float32)
@@ -367,7 +361,6 @@ class SelectedUnitsHead(nn.Module):
                 type(self)._su_step_train if train else type(self)._su_step_sample,
                 variable_broadcast="params",
                 split_rngs={"params": False},
-                unroll=self._scan_unroll(),
             )(self, carry0, xs)
 
         ae = final["ae"]

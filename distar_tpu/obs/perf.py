@@ -1,10 +1,9 @@
 """Live performance attribution: MFU / HBM / collective-traffic gauges.
 
-One code path for the three consumers of XLA's cost and memory
-introspection (previously bench.py, tools/memstats.py and the learner each
-did their own): ``flops_of_lowered``/``flops_of_compiled`` extract flop
-counts, ``memory_report`` normalises ``memory_analysis()``, ``peak_flops``
-looks a device kind up in the datasheet bf16 peak table — and
+One code path for XLA's cost and memory introspection:
+``flops_of_lowered``/``flops_of_compiled`` extract flop counts,
+``memory_report`` normalises ``memory_analysis()``, ``peak_flops`` looks a
+device kind up in the datasheet bf16 peak table — and
 ``PerfMonitor`` turns them into the live ``distar_perf_*`` gauges the
 BaseLearner run loop publishes every iteration, so the PR 3 telemetry
 pipeline (TSDB, shipper, health rules) sees MFU and HBM fleet-wide.

@@ -7,9 +7,9 @@ LAYER-MAJOR — per layer, the input projection for ALL timesteps is one
 big [T*B, D] x [D, 4H] matmul on the MXU (the cuDNN-style split), and
 only the small recurrent [B, H] x [H, 4H] matmul + gate pointwise stays
 inside the `lax.scan` over time. Identical parameters and numerics to the
-step-per-layer formulation (equivalence-tested); `layer_major=False`
-restores the time-major scan. State layout is a tuple of (h, c) pairs,
-one per layer, each [B, hidden].
+step-per-layer formulation (`tests/test_ops.py` holds it to a time-major
+loop over `_step`). State layout is a tuple of (h, c) pairs, one per
+layer, each [B, hidden].
 """
 from __future__ import annotations
 
@@ -94,22 +94,14 @@ class StackedLSTM(nn.Module):
     """N stacked cells over time.
 
     Input [T, B, D] -> output [T, B, H] plus final per-layer states.
-    Layer-major by default: each layer hoists its input projection out of
-    the time scan (see module docstring); `layer_major=False` scans
-    time-major with all layer states in one carry.
+    Layer-major: each layer hoists its input projection out of the time
+    scan (see module docstring).
     """
 
     hidden_size: int
     num_layers: int
     norm: str = "LN"  # 'LN' -> LayerNormLSTMCell, 'none' -> PlainLSTMCell
     dtype: Dtype = jnp.float32
-    # lax.scan unroll factor: >1 fuses that many timesteps per loop
-    # iteration — fewer loop boundaries for the 64-step unrolls whose
-    # per-step matmuls are far too small to fill the MXU at batch ~6.
-    # Measured, not assumed: bench BENCH_LSTM_UNROLL / config
-    # encoder.core_lstm.scan_unroll
-    scan_unroll: int = 1
-    layer_major: bool = True
 
     def setup(self):
         cell_cls = LayerNormLSTMCell if self.norm == "LN" else PlainLSTMCell
@@ -140,15 +132,7 @@ class StackedLSTM(nn.Module):
             final, y = self._step(states, xs[0])
             ys = jnp.broadcast_to(y[None], (xs.shape[0],) + y.shape)
             return ys, final
-        if not self.layer_major:
-            final, ys = nn.transforms.scan(
-                lambda mdl, carry, x: mdl._step(carry, x),
-                variable_broadcast="params",
-                split_rngs={"params": False},
-                unroll=self.scan_unroll,
-            )(self, states, xs)
-            return ys, final
-        # layer-major: hoist each layer's input projection out of the scan
+        # hoist each layer's input projection out of the scan
         h_seq = xs
         new_states = []
         for cell, st in zip(self.cells, states):
@@ -157,7 +141,6 @@ class StackedLSTM(nn.Module):
                 lambda mdl, carry, p: tuple(reversed(mdl.step_from_proj(p, carry))),
                 variable_broadcast="params",
                 split_rngs={"params": False},
-                unroll=self.scan_unroll,
             )(cell, st, proj)
             new_states.append(st)
         return h_seq, tuple(new_states)

@@ -5,8 +5,6 @@ This is the subsystem entry the rest of the repo drives:
 * ``__graft_entry__.dryrun_multichip`` is a thin wrapper over
   ``run_sharded_training`` (the "dryrun" IS the production path now — same
   learner, same feeder, same shardings);
-* ``bench.py``'s MULTICHIP case calls it at dp=1/2/4 for the
-  scaling-efficiency report;
 * ``tools/chaos.py multichip-drill`` runs it as kill/resume children with
   sharded checkpoints across DIFFERENT mesh shapes;
 * ``tests/test_parallel_exec.py`` runs it as the tier-1 smoke.
@@ -245,8 +243,8 @@ def run_sharded_training(
 
 def main_cli(argv=None) -> int:
     """``python -m distar_tpu.parallel.executor --mesh dp=4,fsdp=2 ...`` —
-    the child-process surface the chaos multichip drill and bench MULTICHIP
-    case drive. Prints one ``REPORT {json}`` line."""
+    the child-process surface the chaos multichip drill drives. Prints one
+    ``REPORT {json}`` line."""
     import argparse
     import json
 

@@ -83,10 +83,10 @@ def _check_divisible(x, sharding) -> None:
 class ShardFeeder:
     """Wraps a host-batch iterator; yields placed (sharded) batches.
 
-    Supersedes ``learner.prefetch.DevicePrefetcher`` on the learner path:
-    same double-buffer semantics (bounded queue, error propagation through
-    ``__next__``, sentinel shutdown) plus the mesh-aware placement contract
-    and the ``distar_feeder_*`` instrumentation. ``place_fn`` receives the
+    The learners' one prefetcher: a double buffer (bounded queue, error
+    propagation through ``__next__``, sentinel shutdown) with the
+    mesh-aware placement contract and the ``distar_feeder_*``
+    instrumentation. ``place_fn`` receives the
     raw host batch and returns the device-placed batch — for learners that
     is ``_place_batch`` (entity cap + per-leaf ``assemble_global``).
 
@@ -193,10 +193,12 @@ class ShardFeeder:
 
     def stats(self) -> dict:
         """Host-side totals for smoke assertions (the prefetch-overlap
-        contract: mean wait << mean step time when the host keeps up)."""
+        contract: the feeder is ahead of the steps, so placed batches wait
+        in the buffer and mean wait << mean step time)."""
         n = max(self.batches, 1)
         return {
             "batches": self.batches,
+            "occupancy": self.occupancy(),
             "wait_s_mean": self.total_wait_s / n,
             "place_s_mean": self.total_place_s / n,
             "wait_s_total": self.total_wait_s,

@@ -18,7 +18,7 @@ the surface the batcher needs:
 ``BatchedInferenceEngine`` adapts ``actor.inference.BatchedInference`` — the
 serving path reuses the actor fleet's compiled ``sample_action`` verbatim.
 ``MockModelEngine`` is a CPU stand-in with observable per-slot dynamics for
-tests, ``tools/loadgen.py`` and ``BENCH_MODE=rollout``.
+tests and ``tools/loadgen.py``.
 """
 from __future__ import annotations
 

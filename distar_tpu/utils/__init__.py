@@ -1,6 +1,5 @@
 from .config import Config, deep_merge_dicts, read_config, save_config
 from .log import TextLogger, VariableRecord, AverageMeter, EMAMeter, build_logger
-from .timing import EasyTimer
 
 __all__ = [
     "Config",
@@ -11,6 +10,5 @@ __all__ = [
     "VariableRecord",
     "AverageMeter",
     "EMAMeter",
-    "EasyTimer",
     "build_logger",
 ]

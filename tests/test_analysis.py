@@ -898,18 +898,6 @@ def test_scalar_sink_close_releases_file(tmp_path):
     sink.close()  # idempotent
 
 
-def test_device_prefetcher_close_joins_producer():
-    import itertools
-
-    from distar_tpu.learner.prefetch import DevicePrefetcher
-
-    pf = DevicePrefetcher(itertools.count(), place_fn=lambda b: b, depth=2)
-    assert next(pf) == 0
-    thread = pf._thread
-    pf.close()
-    assert not thread.is_alive(), "close() must reap the producer thread"
-
-
 def test_shm_peer_close_joins_beat_thread():
     pytest.importorskip("multiprocessing.shared_memory")
     from distar_tpu.comm import shm_ring
@@ -931,7 +919,7 @@ def test_analysis_repo_clean():
     idiom. Stale baseline entries fail too (shrink-only)."""
     baseline = load_baseline(os.path.join(REPO, "tools", "analysis_baseline.json"))
     analyzer = Analyzer(repo_root=REPO)
-    files = collect_files(["distar_tpu", "tools", "bench.py"], repo_root=REPO)
+    files = collect_files(["distar_tpu", "tools"], repo_root=REPO)
     result = analyzer.run(files, baseline=baseline)
     msg = "\n".join(str(f) for f in result.findings) or "<none>"
     stale = "\n".join(str(e) for e in result.stale_baseline) or "<none>"

@@ -68,7 +68,7 @@ def test_no_python_file_outside_compile_cache_names_a_cache_path():
     option / environment variable that sets one) spelled anywhere else is a
     second rule."""
     pattern = re.compile(
-        r"jax_cache|jax_compilation_cache_dir|JAX_COMPILATION_CACHE_DIR|BENCH_COMPILE_CACHE")
+        r"jax_cache|jax_compilation_cache_dir|JAX_COMPILATION_CACHE_DIR")
     allowed = {
         os.path.join("distar_tpu", "utils", "compile_cache.py"),
         os.path.join("tests", "test_compile_cache.py"),

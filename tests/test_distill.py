@@ -520,18 +520,3 @@ def test_opsctl_distill_digest_renders(capsys, monkeypatch):
     assert "action_type=0.11" in out
     assert "step-cost ratio: 0.31x teacher" in out
     assert "canary split: 25.0% -> 10.0.0.1:1 (version s2)" in out
-
-
-@pytest.mark.slow
-def test_bench_distill_smoke(monkeypatch, tmp_path):
-    """BENCH_MODE=distill machinery on smoke dims: ratio computed from both
-    lowered train steps, toy-run curve monotone, smoke flagged in-band."""
-    import bench
-
-    monkeypatch.setenv("BENCH_DISTILL_SMOKE", "1")
-    monkeypatch.setenv("BENCH_DISTILL_ITERS", "4")
-    monkeypatch.setenv("DISTAR_EXPERIMENTS_ROOT", str(tmp_path))
-    out = bench.bench_distill()
-    assert out["smoke_model"] is True and out["meets_target"] is False
-    assert out["value"] and out["value"] > 0
-    assert out["distill"]["toy_run"]["monotone_decrease"] is True

@@ -407,27 +407,6 @@ def test_admin_profile_route_e2e(tmp_path):
         learner.request_profile(steps=1, timeout_s=0.5)
 
 
-def test_device_prefetcher_order_and_errors():
-    from distar_tpu.learner.prefetch import DevicePrefetcher
-
-    batches = [{"i": i} for i in range(5)]
-    pf = DevicePrefetcher(iter(batches), lambda b: {**b, "placed": True}, depth=2)
-    out = list(pf)
-    assert [b["i"] for b in out] == list(range(5))
-    assert all(b["placed"] for b in out)
-
-    def boom():
-        yield {"i": 0}
-        raise RuntimeError("producer failed")
-
-    pf = DevicePrefetcher(boom(), lambda b: b, depth=2)
-    assert next(pf)["i"] == 0
-    import pytest as _pytest
-
-    with _pytest.raises(RuntimeError, match="producer failed"):
-        next(pf)
-
-
 def test_rl_cap_entities_exact_below_cap(tmp_path):
     """cap_entities_rl (learner.max_entities on the RL learner) is
     numerically exact within the cap: same batch trained at the 512 pad and

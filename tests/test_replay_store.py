@@ -415,38 +415,3 @@ def test_admin_surface_serves_stats_and_metrics():
         assert "distar_replay_inserts_total" in text
     finally:
         admin.stop()
-
-
-def test_bench_replay_emits_standard_json(monkeypatch, capsys):
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
-
-    monkeypatch.setenv("BENCH_REPLAY_SECONDS", "0.4")
-    monkeypatch.setenv("BENCH_REPLAY_PAYLOAD_KB", "4")
-    monkeypatch.setenv("BENCH_REPLAY_WRITERS", "1")
-    monkeypatch.setenv("BENCH_REPLAY_READERS", "1")
-    monkeypatch.setenv("BENCH_REPLAY_SHARDS", "1,2")
-    point = bench.bench_replay()
-    assert {"metric", "value", "unit", "vs_baseline"} <= set(point)
-    assert point["replay"]["insert_items_per_s"] > 0
-    # in-band honesty flags + the r09 cases: sharded sweep over real shard
-    # subprocesses, negotiated-compression A/B, zero-copy fast path
-    assert point["cpu_derived"] is True and point["device"] == "cpu"
-    assert isinstance(point["scaling_valid"], bool) and point["host_cores"] >= 1
-    # pinning provenance (tools/pin.py harness): the scaling_valid flag must
-    # agree with it — perf_gate's scaling gate enforces the same contract
-    assert point["pinning"]["pinned"] in (True, False)
-    assert point["scaling_valid"] == (
-        point["pinning"]["pinned"] and point["pinning"]["host_cores"] >= 3)
-    assert [r["shards"] for r in point["replay_shard_sweep"]] == [1, 2]
-    assert all(r["aggregate_items_per_s"] > 0 for r in point["replay_shard_sweep"])
-    comp = point["replay_compression"]
-    assert comp["on"]["wire_ratio"] < 0.9 < comp["off"]["wire_ratio"]
-    assert point["replay_fast_path"]["vs_tcp_loopback"] > 1.0
-    out = capsys.readouterr().out.strip().splitlines()
-    import json
-
-    parsed = json.loads(out[-1])
-    assert parsed["unit"] == "items/s"

@@ -470,4 +470,4 @@ def test_loadgen_soak_closed_loop_with_swap(tmp_path):
     import json as _json
 
     parsed = [_json.loads(l) for l in lines]
-    assert parsed[-1]["metric"] == "serve_throughput"  # bench.py tail convention
+    assert parsed[-1]["metric"] == "serve_throughput"  # the last line is the summary

@@ -1,7 +1,5 @@
-"""Direct unit tests for EasyTimer and the learner hook registry
-(previously exercised only through full learner runs; EasyTimer has a
-basic check in test_utils.py — here it gets the reuse semantics)."""
-import time
+"""Direct unit tests for the learner hook registry (previously exercised
+only through full learner runs)."""
 import types
 
 import pytest
@@ -14,19 +12,6 @@ from distar_tpu.learner.hooks import (
     ProfilerHook,
     SaveCkptHook,
 )
-from distar_tpu.utils.timing import EasyTimer
-
-
-# ------------------------------------------------------------------ timing
-def test_easy_timer_measures_block():
-    t = EasyTimer()
-    with t:
-        time.sleep(0.02)
-    first = t.value
-    assert first > 0.015
-    with t:  # reusable; value overwritten
-        pass
-    assert t.value < first  # empty block must re-measure, not accumulate
 
 
 # ------------------------------------------------------------------- hooks

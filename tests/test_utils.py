@@ -6,7 +6,6 @@ from distar_tpu.utils import (
     AverageMeter,
     Config,
     EMAMeter,
-    EasyTimer,
     VariableRecord,
     deep_merge_dicts,
     read_config,
@@ -60,13 +59,6 @@ def test_variable_record():
     rec.update_var({"loss": 3.0})
     assert rec.get("loss").avg == pytest.approx(2.0)
     assert "loss" in rec.get_vars_text()
-
-
-def test_timer():
-    t = EasyTimer()
-    with t:
-        pass
-    assert t.value >= 0.0
 
 
 def test_downloader_resumes_with_range(tmp_path):

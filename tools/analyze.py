@@ -8,7 +8,7 @@ Usage:
     python tools/analyze.py --json out.json      # machine-readable report
     python tools/analyze.py --write-baseline     # regenerate the baseline
 
-Default paths: ``distar_tpu tools bench.py``. Exit codes: 0 = clean,
+Default paths: ``distar_tpu tools``. Exit codes: 0 = clean,
 1 = baselined-only (grandfathered debt, nothing new), 2 = new findings or
 stale baseline entries (the baseline may only shrink). Tier-1 runs this via
 tests/test_analysis.py::test_analysis_repo_clean; ``--changed`` is the fast
@@ -30,7 +30,7 @@ from distar_tpu.analysis import (  # noqa: E402
     Analyzer, collect_files, load_baseline, render_markdown, save_baseline,
 )
 
-DEFAULT_PATHS = ("distar_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("distar_tpu", "tools")
 DEFAULT_BASELINE = os.path.join(_REPO, "tools", "analysis_baseline.json")
 
 
@@ -51,7 +51,7 @@ def _changed_files() -> list:
         # docs change constantly and are not the analyzer's subject
         if not line.endswith(".py") or not os.path.exists(os.path.join(_REPO, line)):
             continue
-        if not (line == "bench.py" or line.startswith(("distar_tpu/", "tools/"))):
+        if not line.startswith(("distar_tpu/", "tools/")):
             continue
         files.append(os.path.join(_REPO, line))
     return sorted(set(files))

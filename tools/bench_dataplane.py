@@ -116,7 +116,7 @@ def main():
     from distar_tpu.comm.serializer import dumps
     from distar_tpu.learner.data import fake_rl_batch
 
-    traj_len = int(os.environ.get("DP_BENCH_TRAJ", 16))
+    traj_len = 16
     payload = fake_rl_batch(1, traj_len, rng=np.random.default_rng(0))
     raw = dumps(payload, compress=False)
     print(f"payload: 1 actor trajectory window (traj_len={traj_len}), "

@@ -37,7 +37,7 @@ Modes (the canonical load-test shapes):
     shed-rate curve as levels sweep past fleet slot capacity — the
     numbers a 10k+ session deployment is sized against.
 
-Output: bench.py-style JSON result lines on stdout (the LAST line is the
+Output: JSON result lines on stdout (the LAST line is the
 summary), optionally mirrored to ``--artifact <path>``. A mid-run hot swap
 (``--swap-at <frac>``) exercises the registry under load and reports swap
 duration + any in-flight disruption (there must be none).

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """perf_gate: performance regression gate + bench-trajectory aggregator.
 
-Two commands, both consuming the JSON artifacts bench.py / obs.traceview
-already emit (nothing here measures — this is the layer that finally READS
+Two commands, both consuming the JSON artifacts the CPU-era harness and
+obs.traceview emitted (nothing here measures — this is the layer that READS
 the `BENCH_*`/`MULTICHIP_*` files every round produces):
 
   check       compare a fresh artifact against a committed baseline with a
@@ -15,7 +15,7 @@ the `BENCH_*`/`MULTICHIP_*` files every round produces):
               Exit 0 pass / 1 regression / 2 precondition failed.
 
   trajectory  aggregate the round-over-round artifacts (BENCH_r*.json,
-              BENCH_LOCAL_*.json, MULTICHIP_r*.json, ROLLOUT_r*.json,
+              MULTICHIP_r*.json, ROLLOUT_r*.json,
               artifacts/*_r*.json) into a markdown table, optionally
               rewritten in place between the PERF.md trajectory markers.
 
@@ -103,7 +103,7 @@ def _points(artifact: dict) -> Dict[Tuple, dict]:
 
 # --------------------------------------------------------- the physics check
 def impossible_timing(artifact: dict) -> List[str]:
-    """Re-run bench.py's impossible-timing recheck over an artifact: any
+    """The impossible-timing recheck over an artifact: any
     point whose max(flops_unoptimized, flops_optimized)/step_time exceeds
     1.1x the named device's datasheet peak is physically impossible. Points
     already flagged in-band (suspect / suspect_timing) count too. Returns
@@ -327,7 +327,6 @@ def _multichip_row(path: str, doc: dict) -> Optional[dict]:
 def collect_trajectory(repo: str = _REPO) -> List[dict]:
     rows: List[dict] = []
     for path in sorted(glob.glob(os.path.join(repo, "BENCH_r*.json"))
-                       + glob.glob(os.path.join(repo, "BENCH_LOCAL_r*.json"))
                        + glob.glob(os.path.join(repo, "ROLLOUT_r*.json"))
                        + glob.glob(os.path.join(repo, "REPLAY_SHARD_r*.json"))
                        + glob.glob(os.path.join(repo, "FLEET_r*.json"))

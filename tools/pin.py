@@ -7,8 +7,7 @@ core pinning, and prints the PROVENANCE BLOCK bench artifacts must embed to
 claim ``scaling_valid: true`` (tools/perf_gate.py refuses the claim without
 it, or with ``host_cores < 2``). On a host without enough cores the plan
 REFUSES — the artifact then keeps ``scaling_valid: false`` with the reason
-in-band. Wired into ``tools/loadgen.py --mode fleet``, the ``BENCH_MODE=
-replay`` sweeps and the chaos drills.
+in-band. Wired into ``tools/loadgen.py --mode fleet`` and the chaos drills.
 
   python tools/pin.py plan --procs 3 [--reserve-client 1] [--require]
         print the assignment plan (JSON); --require exits 3 when refused
