@@ -123,6 +123,8 @@ def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
                         for j, row in enumerate(v) for e, x in enumerate(row)})
         elif k == "overflow":
             log["moe_overflow_rows"] = float(v)
+        elif k == "buffer_rows":
+            log["moe_buffer_rows"] = float(v)
         else:
             log[k] = float(v)
     return log
@@ -179,6 +181,9 @@ class LMLearner(BaseLearner):
         self._moe_load = self.metrics.histogram(
             "distar_moe_load_max_over_mean",
             "largest over the expert layers of (most loaded held expert / mean held expert), per step")
+        self._moe_buffer_rows = self.metrics.histogram(
+            "distar_moe_buffer_rows",
+            "rows of the expert buffers walked (chunks of one row a position that held rows), all layers, per step")
         self._moe_overflow = self.metrics.counter(
             "distar_moe_overflow_rows_total", "rows routed here that an expert buffer could not take")
 
@@ -225,6 +230,7 @@ class LMLearner(BaseLearner):
             log = _flat_log(jax.device_get(info), self._moe_layers)
             self._moe_rows.observe(log["moe_rows_here"])
             self._moe_load.observe(log["moe_load_max_over_mean"])
+            self._moe_buffer_rows.observe(log["moe_buffer_rows"])
             self._moe_overflow.inc(log["moe_overflow_rows"])
             # the layer's buffer is its provable bound: a row that found no place is a fault of ops/moe
             if log["moe_overflow_rows"]:

@@ -100,7 +100,8 @@ class LFM2(nn.Module):
     """``__call__(tokens [B, S] int32) -> (logits [B, S, vocab_size] float32,
     stats)``. ``stats``: ``rms`` [layers] of the residual stream after each
     layer, ``ff_rms`` [layers] of each layer's feed-forward output, ``rows`` [expert layers, experts held] routed to each held expert,
-    ``overflow`` [] rows the expert buffers did not take (0 by construction)."""
+    ``overflow`` [] rows the expert buffers did not take (0 by construction),
+    ``buffer_rows`` [] rows of the expert buffers that were walked, all layers."""
 
     cfg: Dict
 
@@ -126,4 +127,5 @@ class LFM2(nn.Module):
             "ff_rms": jnp.stack([s["ff_rms"] for s in per_layer]),
             "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
             "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
+            "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
         }

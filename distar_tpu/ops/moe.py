@@ -10,15 +10,22 @@ nothing that stands in for them.
 
 No row is dropped. The rows routed here are sorted by expert into a static
 buffer of ``min(top_k, count)`` rows a position, which is the provable bound
-(a position picks distinct experts), and the grouped products run over the
-rows that are really there (``group_sizes``), so an uneven load costs what it
-holds and the empty tail costs no product. ``overflow`` counts the rows routed
-here that the buffer did not take: 0 by construction, reported so that the
-learner and the benchmark can hold the layer to it. The grouped product is
-chosen by the platform the program is being compiled for
-(``jax.lax.platform_dependent``): JAX's megablox kernel on a TPU
-(``jax.experimental.pallas.ops.tpu.megablox``: tiles over the rows of each
-group, grid as long as the rows present), ``jax.lax.ragged_dot`` anywhere else.
+(a position picks distinct experts). The buffer is walked in chunks of one
+row a position, twice the expected load of ``top_k * count / num_experts``:
+up to the end of the last chunk that holds rows (``walk``: one program for
+each length, chosen by a count the step computes from its own picks, forward
+and backward). So the gather into the buffer, the grouped products
+(``group_sizes``: they skip the empty tail anyway), the masks and their
+backward passes cost what the load holds, and a router that sends the bound
+gets the whole buffer at the whole buffer's cost; the weighted sum gathers by
+pick (``N * top_k`` indices whatever the length). ``buffer_rows`` reports the
+rows walked. ``overflow`` counts the rows routed here that the buffer did not
+take: 0 by construction, reported so that the learner and the benchmark can
+hold the layer to it. The grouped product is chosen by the platform the
+program is being compiled for (``jax.lax.platform_dependent``): JAX's
+megablox kernel on a TPU (``jax.experimental.pallas.ops.tpu.megablox``: tiles
+over the rows of each group, grid as long as the rows present),
+``jax.lax.ragged_dot`` anywhere else.
 
 Moving rows to the sorted buffer and back is a gather in both directions,
 forward and backward (``take_rows``): the transpose of a row gather is a
@@ -27,6 +34,7 @@ are known.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -54,13 +62,14 @@ def route(logits, bias, top_k: int, scaling: float = 1.0) -> Tuple[jnp.ndarray, 
 
 
 class Dispatch(NamedTuple):
-    """Where the rows of the experts held here go. ``R`` rows in the buffer,
-    ``N`` positions, ``k`` picks a position."""
+    """Where the rows of the experts held here go. ``N`` positions, ``k``
+    picks a position, a buffer of ``R = N * min(k, count)`` rows."""
 
     token: jnp.ndarray       # [R] the position each buffer row copies (0 beyond the rows present)
     slot: jnp.ndarray        # [R] the flat pick n*k+j each buffer row serves (N*k beyond)
     row: jnp.ndarray         # [N, k] the buffer row of each pick, R where its expert is not here
     group_sizes: jnp.ndarray  # [count] rows of each held expert in the buffer
+    chunks: jnp.ndarray      # [] chunks of N rows that hold rows, counting the first, which always runs
     rows: jnp.ndarray        # [count] rows routed to each held expert
     overflow: jnp.ndarray    # [] rows routed here that the buffer did not take: 0 by construction
 
@@ -69,7 +78,8 @@ def dispatch(sel, offset: int, count: int) -> Dispatch:
     """Sort the picks of held experts by expert into ``N * min(k, count)``
     rows: a position picks distinct experts, so no more can be routed here."""
     N, k = sel.shape
-    capacity = N * min(k, count)
+    chunks = min(k, count)
+    capacity = N * chunks
     local = sel - offset
     held = (local >= 0) & (local < count)
     key = jnp.where(held, local, count).reshape(-1)                 # absent experts sort last
@@ -84,7 +94,8 @@ def dispatch(sel, offset: int, count: int) -> Dispatch:
     valid = jnp.arange(capacity) < present
     slot = jnp.where(valid, order[:capacity], N * k)
     return Dispatch(token=jnp.where(valid, order[:capacity] // k, 0), slot=slot, row=row,
-                    group_sizes=group_sizes, rows=rows, overflow=rows.sum() - present)
+                    group_sizes=group_sizes, chunks=jnp.clip((present + N - 1) // N, 1, chunks),
+                    rows=rows, overflow=rows.sum() - present)
 
 
 @jax.custom_vjp
@@ -125,6 +136,63 @@ def grouped_matmul(x, w, group_sizes):
     return jax.lax.platform_dependent(x, w, group_sizes, tpu=megablox, default=jax.lax.ragged_dot)
 
 
+def _buffer(length: int, u, w, w1, w3, w2, plan: Dispatch):
+    """``FF`` [N, d] float32 from the first ``length`` rows of the buffer,
+    which hold every row present: the rows of ``u`` gathered, the three
+    grouped products, and the weighted sum over the picks served."""
+    N, k = w.shape
+    with jax.named_scope("moe_dispatch"):
+        xs = take_rows(u, plan.token[:length], plan.row)
+    with jax.named_scope("moe_experts"):
+        gate = nn.silu(grouped_matmul(xs, w1, plan.group_sizes))
+        up = grouped_matmul(xs, w3, plan.group_sizes)
+        out = grouped_matmul(gate * up, w2, plan.group_sizes)
+    with jax.named_scope("moe_combine"):
+        slot = plan.slot[:length]
+        out = jnp.where((slot < N * k)[:, None], out, 0)
+        # a pick whose expert is not here reads row 0 with weight 0
+        here = plan.row < length
+        picked = take_rows(out, jnp.where(here, plan.row, 0).reshape(-1), slot[:, None])
+        return (picked.reshape(N, k, -1).astype(jnp.float32) * jnp.where(here, w, 0.0)[..., None]).sum(1)
+
+
+def _lengths(plan: Dispatch):
+    N = plan.row.shape[0]
+    return range(N, plan.token.shape[0] + 1, N)
+
+
+@jax.custom_vjp
+def walk(u, w, w1, w3, w2, plan: Dispatch):
+    """``FF`` [N, d] float32 from the buffer up to the end of the last chunk
+    that holds rows (``plan.chunks``, which the step computes from its own
+    picks): one program a length, of which the one the load asks for runs.
+    ``u`` [N, d] rows, ``w`` [N, k] float32 weights of the picks. The backward
+    rule makes the same choice and computes the chosen length's forward again
+    (a choice that JAX differentiates itself returns every length's residuals
+    from whichever ran, zeros for the others). Under a decoder layer's remat
+    that forward takes the place of the replay's, which is then dead code;
+    without remat it is one forward more than a buffer of one length costs."""
+    return jax.lax.switch(plan.chunks - 1, [functools.partial(_buffer, n) for n in _lengths(plan)],
+                          u, w, w1, w3, w2, plan)
+
+
+def _walk_fwd(*args):
+    return walk(*args), args
+
+
+def _walk_bwd(args, g):
+    *rest, plan = args
+
+    def pull(length):
+        # ``checkpoint``: the forward computed here is a recompute, and reads as one in a trace
+        return lambda g, *rest: jax.vjp(jax.checkpoint(lambda *a: _buffer(length, *a, plan)), *rest)[1](g)
+
+    return (*jax.lax.switch(plan.chunks - 1, [pull(n) for n in _lengths(plan)], g, *rest), None)
+
+
+walk.defvjp(_walk_fwd, _walk_bwd)
+
+
 class ExpertsHeldMoE(nn.Module):
     """``FF(u)`` over the experts held here, and what the step reports of it.
 
@@ -132,7 +200,8 @@ class ExpertsHeldMoE(nn.Module):
     [d, num_experts], ``w1``/``w3`` [count, d, width], ``w2`` [count, width, d].
     ``expert_bias`` [num_experts] is a buffer (collection ``buffers``): drawn
     at init, never trained. Returns ``(FF(RMSNorm(u)), stats)`` with ``stats``
-    the rows routed to each held expert and the overflow."""
+    the ``rows`` routed to each held expert, the ``overflow`` and the
+    ``buffer_rows`` walked."""
 
     num_experts: int
     top_k: int
@@ -164,18 +233,9 @@ class ExpertsHeldMoE(nn.Module):
             sel, w = route(logits, bias.value if self.use_bias else 0.0, self.top_k, self.scaling)
         with jax.named_scope("moe_dispatch"):
             plan = dispatch(sel, self.offset, self.count)
-            xs = take_rows(u, plan.token, plan.row)
         with jax.named_scope("moe_experts"):
-            cast = lambda p: p.astype(self.dtype)
-            gate = nn.silu(grouped_matmul(xs, cast(w1), plan.group_sizes))
-            up = grouped_matmul(xs, cast(w3), plan.group_sizes)
-            out = grouped_matmul(gate * up, cast(w2), plan.group_sizes)
+            w1, w3, w2 = (p.astype(self.dtype) for p in (w1, w3, w2))
+        y = walk(u, w, w1, w3, w2, plan)
         with jax.named_scope("moe_combine"):
-            R = out.shape[0]
-            out = jnp.where((plan.slot < N * self.top_k)[:, None], out, 0)
-            # a pick whose expert is not here reads row 0 with weight 0
-            here = plan.row < R
-            picked = take_rows(out, jnp.where(here, plan.row, 0).reshape(-1), plan.slot[:, None])
-            w = jnp.where(here, w, 0.0).astype(jnp.float32)
-            y = (picked.reshape(N, self.top_k, d).astype(jnp.float32) * w[..., None]).sum(1)
-        return y.astype(x.dtype).reshape(B, S, d), {"rows": plan.rows, "overflow": plan.overflow}
+            y = y.astype(x.dtype).reshape(B, S, d)
+        return y, {"rows": plan.rows, "overflow": plan.overflow, "buffer_rows": N * plan.chunks}

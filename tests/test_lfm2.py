@@ -160,13 +160,114 @@ def test_dispatch_sorts_the_picks_by_expert_into_the_provable_bound():
     sel = jnp.asarray([[0, 1], [0, 1], [0, 2], [1, 0], [3, 0]], jnp.int32)  # expert 0 five times
     plan = moe.dispatch(sel, 0, 2)                                           # 5 positions x min(2, 2) rows
     assert plan.rows.tolist() == [5, 3] and int(plan.overflow) == 0
-    assert plan.group_sizes.tolist() == [5, 3]
+    # two chunks of five rows: expert 0 fills the first, expert 1 starts the second
+    assert plan.group_sizes.tolist() == [5, 3] and int(plan.chunks) == 2
     # each present row copies the position that picked it, sorted by expert
     assert plan.token[:8].tolist() == [0, 1, 2, 3, 4, 0, 1, 3]
+    assert plan.slot[:8].tolist() == [0, 2, 4, 7, 9, 1, 3, 6] and plan.slot[8:].tolist() == [10, 10]
     assert sorted(plan.row.reshape(-1).tolist()) == list(range(8)) + [10, 10]
+    # each pick's row serves it
+    flat = plan.row.reshape(-1)
+    assert all(int(plan.slot[r]) == i for i, r in enumerate(flat.tolist()) if r < 10)
     # one expert held: a position can send it one row at most, and the buffer is that long
     one = moe.dispatch(sel, 0, 1)
     assert one.token.shape == (5,) and one.rows.tolist() == [5] and int(one.overflow) == 0
+    assert one.group_sizes.tolist() == [5] and int(one.chunks) == 1
+    # no row here: the first chunk is still walked
+    assert int(moe.dispatch(sel, 4, 2).chunks) == 1
+
+
+# 40 positions, top-3 of 8, experts 4-7 held: three chunks of 40 rows
+LOADS = {
+    "first_chunk": ({6: -10.0, 7: -10.0}, 1),          # experts 6 and 7 are never picked: under 40 rows
+    "second_chunk": ({5: 10.0}, 2),                    # every position picks expert 5, some the others
+    "whole_bound": ({4: 10.0, 5: 10.0, 6: 10.0}, 3),   # every pick of every position is held: 120 rows
+}
+
+
+@pytest.mark.parametrize("load", LOADS)
+def test_the_layer_is_the_reference_at_every_load_and_walks_the_chunks_that_hold_rows(load):
+    """Value and every gradient leaf (parameters and input) against
+    ``lfm2_plain.experts_held``, with the rows in the first chunk only, spilling
+    into the second, and filling the provable bound; ``buffer_rows`` says how
+    many chunks ran."""
+    d, width, E, k, N = 16, 8, 8, 3, 40
+    push, chunks = LOADS[load]
+    layer = moe.ExpertsHeldMoE(E, k, width, 4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, N, d))
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    p = jax.tree.map(lambda a: a * 8.0 if a.ndim >= 2 else a, variables["params"])
+    bias = jnp.zeros((E,)).at[jnp.asarray(list(push))].set(jnp.asarray(list(push.values())))
+    weight = jax.random.normal(jax.random.PRNGKey(2), (N, d))
+    plain_cfg = {"num_experts_per_tok": k, "experts_held": {"offset": 4, "count": 4},
+                 "use_expert_bias": True, "routed_scaling_factor": 1.0}
+
+    def system(p, x):
+        y, stats = layer.apply({"params": p, "buffers": {"expert_bias": bias}}, x)
+        return (y.reshape(N, d) * weight).sum(), (y, stats)
+
+    def reference(p, x):
+        u = lfm2_plain.rms_norm(x, p["norm"]["scale"], 1e-5).reshape(-1, d)
+        y, rows, _ = lfm2_plain.experts_held(p, bias, u, plain_cfg, None)
+        return (y * weight).sum(), (y, rows)
+
+    (_, (y, stats)), grads = jax.value_and_grad(system, argnums=(0, 1), has_aux=True)(p, x)
+    with jax.default_matmul_precision("highest"):
+        (_, (want, rows)), ref_grads = jax.value_and_grad(reference, argnums=(0, 1), has_aux=True)(p, x)
+    np.testing.assert_array_equal(stats["rows"], rows)
+    held = int(rows.sum())
+    assert (chunks - 1) * N < held <= chunks * N and (load != "whole_bound" or held == N * k)
+    assert int(stats["buffer_rows"]) == chunks * N and int(stats["overflow"]) == 0
+    np.testing.assert_allclose(y.reshape(N, d), want, atol=1e-5, rtol=1e-4)
+    flat, ref_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (grads, ref_grads))
+    assert flat.keys() == ref_flat.keys() and len(flat) == 6   # norm, router, w1, w2, w3 and the input
+    for path, g in flat.items():
+        bound = 1e-3 * float(jnp.abs(ref_flat[path]).max()) + 1e-9
+        np.testing.assert_allclose(g, ref_flat[path], atol=bound, rtol=0, err_msg=jax.tree_util.keystr(path))
+        assert float(jnp.abs(ref_flat[path]).max()) > 0, jax.tree_util.keystr(path)
+
+
+def test_every_row_move_belongs_to_the_length_the_load_chose():
+    """The lowered value-and-gradient of the layer (CPU, 24 positions, top-3,
+    2 held: a buffer of 2 x 24 rows for 72 picks): outside the choice between
+    the buffer's lengths no row of ``d`` or ``width`` numbers is gathered, and
+    the program for the first chunk moves ``N`` rows (by buffer row) or
+    ``N * k`` (by pick), never the buffer's ``2 * N``."""
+    import re
+
+    d, width, E, k, N = 16, 8, 8, 3, 24
+    layer = moe.ExpertsHeldMoE(E, k, width, 0, 2)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, N, d))
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    f = lambda p, x: layer.apply({"params": p, "buffers": variables["buffers"]}, x)[0].sum()
+    text = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(variables["params"], x).as_text()
+
+    def choices(text):
+        """The text outside every ``stablehlo.case`` and, for each outermost
+        one, the text of its branches (``platform_dependent``'s are nested)."""
+        outside, cases, depth, top = [], [], 0, None
+        for line in text.splitlines():
+            if top is None and "stablehlo.case" in line:
+                top = depth
+                cases.append([[]])
+            elif top is not None and depth == top + 1 and line.strip().startswith("}, {"):
+                cases[-1].append([])
+            elif top is not None:
+                cases[-1][-1].append(line)
+            else:
+                outside.append(line)
+            depth += line.count("{") - line.count("}")
+            if top is not None and depth <= top:
+                top = None
+        return "\n".join(outside), [["\n".join(b) for b in c] for c in cases]
+
+    moved = lambda t: sorted({int(np.prod([int(n) for n in dims.split("x")[:-1]]))
+                              for dims in re.findall(r'"stablehlo\.gather".*-> tensor<((?:\d+x)+\d+)xf32>', t)
+                              if dims.endswith(f"x{d}") or dims.endswith(f"x{width}")})
+    outside, cases = choices(text)
+    assert moved(outside) == [] and [len(c) for c in cases] == [2, 2]   # forward and backward, two lengths each
+    for first, whole in cases:
+        assert moved(first) == [N, N * k] and moved(whole) == [2 * N, N * k]
 
 
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))

@@ -130,6 +130,26 @@ def test_overflow_is_counted_as_zero_and_a_row_not_computed_stops_the_run(tmp_pa
     assert counted() == [3]
 
 
+def test_buffer_rows_walked_are_observed_once_a_step(tmp_path):
+    """``distar_moe_buffer_rows``: one observation a step, the sum over the
+    expert layers of (chunks that held rows) x ``N``: a layer walks the first
+    chunk of ``N`` = 64 rows always and the second when more than 64 rows are
+    routed to its experts (top-2 of 8 with 4 held: 64 expected)."""
+    lrn = learner(tmp_path)
+    hist = lambda: [inst for fam in lrn.metrics.collect() if fam["name"] == "distar_moe_buffer_rows"
+                    for _, inst in fam["series"]][0]
+    N, walked = 2 * 32, []
+    count, total = hist().count, hist().sum  # the registry is the process's: other tests' steps are in it
+    for step in range(3):
+        log = lrn._train(fake_token_batch(2, 32, 128, np.random.default_rng(step)))
+        per_layer = [sum(v for k, v in log.items() if k.startswith(f"moe_rows/layer_{i}/")) for i in range(1, 5)]
+        assert sum(per_layer) == log["moe_rows_here"]
+        walked.append(sum(N * max(1, -(-int(rows) // N)) for rows in per_layer))
+        assert log["moe_buffer_rows"] == walked[-1] and 4 * N <= walked[-1] <= 8 * N
+        assert hist().count == count + step + 1 and hist().sum == total + sum(walked)
+    assert any(w > 4 * N for w in walked)  # some layer of some step took its second chunk
+
+
 def test_sl_train_reaches_it_through_the_plugin_registry(tmp_path, monkeypatch, capsys):
     from distar_tpu import plugins
     from distar_tpu.bin import sl_train
