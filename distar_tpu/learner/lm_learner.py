@@ -1,4 +1,6 @@
-"""Token-sequence learner: next-token training of ``model.LFM2`` on
+"""Token-sequence learner: next-token training of a token model
+(``model.TOKEN_MODELS``: ``LFM2``, ``NemotronH``; the config's
+``model.model_type`` says which, ``lfm2_moe`` when it says nothing) on
 ``BaseLearner``'s run loop, feeder, optimizer, dynamics tree and checkpoints.
 
 A batch is two int32 leaves, ``tokens`` and ``labels`` ``[B, S]``, with
@@ -13,7 +15,7 @@ Started through the ordinary launcher, which resolves a learner by pipeline
 (``plugins.load_component``); this module is such a pipeline:
 
   python -m distar_tpu.bin.sl_train --pipeline distar_tpu.learner.lm_learner \\
-      --config configs/lfm2_24b_a2b_v5e.yaml --iters N
+      --config configs/lfm2_24b_a2b_v5e.yaml --iters N      (or configs/nemotron_twotower_30b_a3b_v5e.yaml)
 
 With no ``set_dataloader`` it trains on ``FakeTokenDataloader`` (Zipf ids).
 """
@@ -28,7 +30,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import compute_lm_loss
-from ..model import LFM2, default_lfm2_config
+from ..model import TOKEN_MODELS
 from ..parallel import MeshSpec, make_mesh
 from ..utils import deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
@@ -39,7 +41,7 @@ LM_LEARNER_DEFAULTS = deep_merge_dicts(
         "learner": {
             "batch_size": 2,
             "unroll_len": 64,          # the sequence length
-            # AdamW; what configs/lfm2_24b_a2b_v5e.yaml trains with (docs/token_models.md)
+            # AdamW; what the published-width files under configs/ train with (docs/token_models.md)
             "learning_rate": 1e-5,
             "betas": [0.9, 0.95],
             "eps": 1e-8,
@@ -72,7 +74,7 @@ class FakeTokenDataloader:
         return fake_token_batch(*self._shape, rng=self._rng)
 
 
-def forward_loss(model: LFM2, variables, params, batch):
+def forward_loss(model, variables, params, batch):
     """The loss of ``params`` on a batch and the step's log: what the train
     step differentiates and ``evaluate`` reports."""
     logits, stats = model.apply({**variables, "params": params}, batch["tokens"])
@@ -85,7 +87,7 @@ def forward_loss(model: LFM2, variables, params, batch):
     return total, info
 
 
-def make_lm_train_step(model: LFM2, optimizer, dynamics=None):
+def make_lm_train_step(model, optimizer, dynamics=None):
     # the function's name is the compiled program's name and heads its
     # compile-cache key (see make_sl_train_step)
     def lm_train_step(variables, opt_state, batch):
@@ -110,17 +112,27 @@ def make_lm_train_step(model: LFM2, optimizer, dynamics=None):
 
 def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
     """The step's fetched outputs as named scalars: per-layer vectors become
-    ``residual_rms/layer_<i>``, ``ff_rms/layer_<i>`` and
-    ``moe_rows/layer_<i>/expert_<e>`` (``e`` counts the experts held)."""
+    ``residual_rms/layer_<i>``, ``ff_rms/layer_<i>`` or ``mixer_rms/layer_<i>``
+    and ``moe_rows/layer_<i>/expert_<e>`` (``e`` counts the experts held) with
+    ``moe_rows_sum/layer_<i>`` and ``moe_rows_max/layer_<i>`` over them; a
+    statistic that only some layers have comes as a dict by layer
+    (``ssm_state_rms/layer_<i>``)."""
     log = {}
     for k, v in info.items():
+        if isinstance(v, dict):
+            log.update({f"{k}/{name}": float(x) for name, x in v.items()})
+            continue
         v = np.asarray(v)
-        if k in ("rms", "ff_rms"):
+        if k in ("rms", "ff_rms", "mixer_rms"):
             name = "residual_rms" if k == "rms" else k
             log.update({f"{name}/layer_{i}": float(x) for i, x in enumerate(v)})
         elif k == "rows":
             log.update({f"moe_rows/layer_{moe_layers[j]}/expert_{e}": float(x)
                         for j, row in enumerate(v) for e, x in enumerate(row)})
+            # a layer's rows here and its busiest held expert's: thousands of rows, so a pick in fifty
+            # that falls the other way in bfloat16 moves them by half a percent, a held expert of 26 rows by 8
+            log.update({f"moe_rows_sum/layer_{moe_layers[j]}": float(row.sum()) for j, row in enumerate(v)})
+            log.update({f"moe_rows_max/layer_{moe_layers[j]}": float(row.max()) for j, row in enumerate(v)})
         elif k == "overflow":
             log["moe_overflow_rows"] = float(v)
         elif k == "buffer_rows":
@@ -134,8 +146,13 @@ class LMLearner(BaseLearner):
     def __init__(self, cfg: Optional[dict] = None, mesh=None):
         cfg = deep_merge_dicts(LM_LEARNER_DEFAULTS, cfg or {})
         self.mesh = mesh if mesh is not None else make_mesh(MeshSpec())
-        self.model_cfg = deep_merge_dicts(default_lfm2_config(), cfg.get("model", {}))
-        self.model = LFM2(self.model_cfg)
+        model_type = cfg.get("model", {}).get("model_type", "lfm2_moe")
+        if model_type not in TOKEN_MODELS:
+            raise ValueError(f"model.model_type {model_type!r}: one of {sorted(TOKEN_MODELS)}")
+        model_cls, defaults = TOKEN_MODELS[model_type]
+        self.model_cfg = deep_merge_dicts(defaults(), cfg.get("model", {}))
+        self.model = model_cls(self.model_cfg)
+        self._moe_layers = model_cls.moe_layers(self.model_cfg)
         super().__init__(cfg)
 
     def _setup_dataloader(self) -> None:
@@ -170,7 +187,6 @@ class LMLearner(BaseLearner):
         repl = NamedSharding(self.mesh, P())
         self._shardings = dict(repl=repl, param=param_sh, opt=opt_sh,
                                flat=batch_sharding(self.mesh, batch_size=B))
-        self._moe_layers = list(range(self.model_cfg.num_dense_layers, len(self.model_cfg.layer_types)))
         self._train_step = jax.jit(
             make_lm_train_step(self.model, self.optimizer, dynamics=self._dynamics_spec()),
             donate_argnums=(0, 1), out_shardings=(param_sh, opt_sh, repl))

@@ -24,7 +24,7 @@ experts, 8,192 of the 65,536 vocabulary rows, every width as published
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +104,11 @@ class LFM2(nn.Module):
     ``buffer_rows`` [] rows of the expert buffers that were walked, all layers."""
 
     cfg: Dict
+
+    @staticmethod
+    def moe_layers(cfg) -> List[int]:
+        """The layers that report ``rows``: those after the leading dense ones."""
+        return list(range(cfg["num_dense_layers"], len(cfg["layer_types"])))
 
     @nn.compact
     def __call__(self, tokens):

@@ -115,12 +115,14 @@ STEP_SCOPES = (
     "loss", "optimizer", "diagnostics",
 )
 
-# The token-sequence learner's step (``lm_train_step``): the model's parts
-# (``model/lfm2.py``, ``ops/moe.py``) and the three names every step has.
+# The token-sequence learner's step (``lm_train_step``): the models' parts
+# (``model/lfm2.py``, ``model/nemotron_h.py``, ``ops/moe.py``, ``ops/ssm.py``;
+# a model has the parts its layers have) and the three names every step has.
 LM_STEP_SCOPES = (
     "embed", "short_conv", "attention", "dense_mlp",
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head",
     "loss", "optimizer", "diagnostics",
+    "ssm_proj", "ssm_scan", "moe_shared",
 )
 
 SPAN_PREFIX = "distar:"

@@ -6,15 +6,21 @@ one member's part: the router scores ALL ``num_experts`` and picks
 ones whose expert lives here (``experts_held``: ``offset`` and ``count``),
 ``FF(u) = sum_{e in sel, e held} w_e E_e(u)``. What the absent experts would
 add is left out; there is no exchange with other chips in this module and
-nothing that stands in for them.
+nothing that stands in for them. What an expert computes is the layer's
+``body`` (``EXPERT_BODIES``: ``swiglu``, three matrices, ``W_2 (silu(W_1 u) *
+W_3 u)``, LFM2's; ``relu2``, two, ``W_2 relu(W_1 u)^2``, ``nemotron_h``'s). A
+shared expert (``shared_width``) is the same body as one dense product over
+every position, added to the routed part: every member of the group computes
+it alike, so over the group it counts once.
 
 No row is dropped. The rows routed here are sorted by expert into a static
 buffer of ``min(top_k, count)`` rows a position, which is the provable bound
 (a position picks distinct experts). The buffer is walked in chunks of one
-row a position, twice the expected load of ``top_k * count / num_experts``:
-up to the end of the last chunk that holds rows (``walk``: one program for
-each length, chosen by a count the step computes from its own picks, forward
-and backward). So the gather into the buffer, the grouped products
+row a position, twice the expected load of ``top_k * count / num_experts``
+and more: up to the end of the last chunk that holds rows, rounded up to a
+length that has a program (``walk``: a program for one chunk, for two and
+for the whole buffer, chosen by a count the step computes from its own
+picks, forward and backward). So the gather into the buffer, the grouped products
 (``group_sizes``: they skip the empty tail anyway), the masks and their
 backward passes cost what the load holds, and a router that sends the bound
 gets the whole buffer at the whole buffer's cost; the weighted sum gathers by
@@ -35,6 +41,7 @@ are known.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -125,7 +132,8 @@ def megablox(x, w, group_sizes, interpret: bool = False):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     R, a, b = x.shape[0], x.shape[1], w.shape[2]
-    tiling = (min(GMM_TILING[0], R), min(GMM_TILING[1], a), min(GMM_TILING[2], b))
+    # the kernel wants whole row tiles: a buffer of 3 x 256 rows gets tiles of 256
+    tiling = (math.gcd(GMM_TILING[0], R), min(GMM_TILING[1], a), min(GMM_TILING[2], b))
     return gmm(x, w, group_sizes, x.dtype, tiling, None, None, False, interpret)
 
 
@@ -136,17 +144,36 @@ def grouped_matmul(x, w, group_sizes):
     return jax.lax.platform_dependent(x, w, group_sizes, tpu=megablox, default=jax.lax.ragged_dot)
 
 
-def _buffer(length: int, u, w, w1, w3, w2, plan: Dispatch):
+def _swiglu(product, x, w1, w3, w2):
+    return product(nn.silu(product(x, w1)) * product(x, w3), w2)
+
+
+def _relu2(product, x, w1, w2):
+    return product(jnp.square(nn.relu(product(x, w1))), w2)
+
+
+# what one expert computes: ``body(product, rows, *matrices)`` with ``product(x, w)``
+# the grouped product over the buffer, or a dense one for a shared expert.
+# ``w2`` [width, d] is the way back; the others are [d, width].
+EXPERT_BODIES = {"swiglu": (_swiglu, ("w1", "w3", "w2")), "relu2": (_relu2, ("w1", "w2"))}
+
+
+def shared_expert(body: str, u, ws):
+    """The body over every row of ``u`` [N, d] as dense products: the expert
+    that every position takes and every member of the group computes alike."""
+    with jax.named_scope("moe_shared"):
+        return EXPERT_BODIES[body][0](jnp.dot, u, *ws)
+
+
+def _buffer(body: str, length: int, u, w, ws, plan: Dispatch):
     """``FF`` [N, d] float32 from the first ``length`` rows of the buffer,
-    which hold every row present: the rows of ``u`` gathered, the three
+    which hold every row present: the rows of ``u`` gathered, the body's
     grouped products, and the weighted sum over the picks served."""
     N, k = w.shape
     with jax.named_scope("moe_dispatch"):
         xs = take_rows(u, plan.token[:length], plan.row)
     with jax.named_scope("moe_experts"):
-        gate = nn.silu(grouped_matmul(xs, w1, plan.group_sizes))
-        up = grouped_matmul(xs, w3, plan.group_sizes)
-        out = grouped_matmul(gate * up, w2, plan.group_sizes)
+        out = EXPERT_BODIES[body][0](lambda x, m: grouped_matmul(x, m, plan.group_sizes), xs, *ws)
     with jax.named_scope("moe_combine"):
         slot = plan.slot[:length]
         out = jnp.where((slot < N * k)[:, None], out, 0)
@@ -157,37 +184,48 @@ def _buffer(length: int, u, w, w1, w3, w2, plan: Dispatch):
 
 
 def _lengths(plan: Dispatch):
+    """The buffer lengths that have a program: one chunk of ``N`` rows (twice
+    the expected load and more), two, and the whole buffer. A program a chunk
+    is a compile a chunk (each holds the grouped product's kernels, forward
+    and backward), and a load beyond two chunks is rare enough to round up."""
+    N, chunks = plan.row.shape[0], plan.token.shape[0] // plan.row.shape[0]
+    return [N * c for c in sorted({1, min(2, chunks), chunks})]
+
+
+def _program(plan: Dispatch):
+    """The first of ``_lengths`` that takes ``plan.chunks`` chunks."""
     N = plan.row.shape[0]
-    return range(N, plan.token.shape[0] + 1, N)
+    return sum((plan.chunks * N > n).astype(jnp.int32) for n in _lengths(plan)[:-1])
 
 
-@jax.custom_vjp
-def walk(u, w, w1, w3, w2, plan: Dispatch):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def walk(body: str, u, w, ws, plan: Dispatch):
     """``FF`` [N, d] float32 from the buffer up to the end of the last chunk
     that holds rows (``plan.chunks``, which the step computes from its own
     picks): one program a length, of which the one the load asks for runs.
-    ``u`` [N, d] rows, ``w`` [N, k] float32 weights of the picks. The backward
+    ``u`` [N, d] rows, ``w`` [N, k] float32 weights of the picks, ``ws`` the
+    body's matrices, each [count, ., .]. The backward
     rule makes the same choice and computes the chosen length's forward again
     (a choice that JAX differentiates itself returns every length's residuals
     from whichever ran, zeros for the others). Under a decoder layer's remat
     that forward takes the place of the replay's, which is then dead code;
     without remat it is one forward more than a buffer of one length costs."""
-    return jax.lax.switch(plan.chunks - 1, [functools.partial(_buffer, n) for n in _lengths(plan)],
-                          u, w, w1, w3, w2, plan)
+    return jax.lax.switch(_program(plan), [functools.partial(_buffer, body, n) for n in _lengths(plan)],
+                          u, w, ws, plan)
 
 
-def _walk_fwd(*args):
-    return walk(*args), args
+def _walk_fwd(body, *args):
+    return walk(body, *args), args
 
 
-def _walk_bwd(args, g):
+def _walk_bwd(body, args, g):
     *rest, plan = args
 
     def pull(length):
         # ``checkpoint``: the forward computed here is a recompute, and reads as one in a trace
-        return lambda g, *rest: jax.vjp(jax.checkpoint(lambda *a: _buffer(length, *a, plan)), *rest)[1](g)
+        return lambda g, *rest: jax.vjp(jax.checkpoint(lambda *a: _buffer(body, length, *a, plan)), *rest)[1](g)
 
-    return (*jax.lax.switch(plan.chunks - 1, [pull(n) for n in _lengths(plan)], g, *rest), None)
+    return (*jax.lax.switch(_program(plan), [pull(n) for n in _lengths(plan)], g, *rest), None)
 
 
 walk.defvjp(_walk_fwd, _walk_bwd)
@@ -197,7 +235,9 @@ class ExpertsHeldMoE(nn.Module):
     """``FF(u)`` over the experts held here, and what the step reports of it.
 
     Parameters: ``norm`` (the layer's feed-forward RMSNorm), ``router``
-    [d, num_experts], ``w1``/``w3`` [count, d, width], ``w2`` [count, width, d].
+    [d, num_experts], the body's matrices (``w1``/``w3`` [count, d, width],
+    ``w2`` [count, width, d]; ``relu2`` has no ``w3``) and, with
+    ``shared_width``, ``shared`` (the same names, [d, shared_width] and back).
     ``expert_bias`` [num_experts] is a buffer (collection ``buffers``): drawn
     at init, never trained. Returns ``(FF(RMSNorm(u)), stats)`` with ``stats``
     the ``rows`` routed to each held expert, the ``overflow`` and the
@@ -212,16 +252,18 @@ class ExpertsHeldMoE(nn.Module):
     use_bias: bool = True
     eps: float = 1e-5
     dtype: Dtype = jnp.float32
+    body: str = "swiglu"
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         B, S, d = x.shape
         N = B * S
         init = nn.initializers.normal(0.02)
+        names = EXPERT_BODIES[self.body][1]
+        shape = lambda name, width: (width, d) if name == "w2" else (d, width)
         w_router = self.param("router", init, (d, self.num_experts), jnp.float32)
-        w1 = self.param("w1", init, (self.count, d, self.width), jnp.float32)
-        w3 = self.param("w3", init, (self.count, d, self.width), jnp.float32)
-        w2 = self.param("w2", init, (self.count, self.width, d), jnp.float32)
+        ws = [self.param(n, init, (self.count, *shape(n, self.width)), jnp.float32) for n in names]
         bias = self.variable(
             "buffers", "expert_bias",
             lambda: 0.01 * jax.random.normal(self.make_rng("params"), (self.num_experts,)))
@@ -234,8 +276,13 @@ class ExpertsHeldMoE(nn.Module):
         with jax.named_scope("moe_dispatch"):
             plan = dispatch(sel, self.offset, self.count)
         with jax.named_scope("moe_experts"):
-            w1, w3, w2 = (p.astype(self.dtype) for p in (w1, w3, w2))
-        y = walk(u, w, w1, w3, w2, plan)
+            ws = tuple(p.astype(self.dtype) for p in ws)
+        y = walk(self.body, u, w, ws, plan)
+        if self.shared_width:
+            shared = [self.param(f"shared_{n}", init, shape(n, self.shared_width), jnp.float32) for n in names]
+            with jax.named_scope("moe_shared"):
+                shared = [p.astype(self.dtype) for p in shared]
+            y = y + shared_expert(self.body, u, shared).astype(jnp.float32)
         with jax.named_scope("moe_combine"):
             y = y.astype(x.dtype).reshape(B, S, d)
         return y, {"rows": plan.rows, "overflow": plan.overflow, "buffer_rows": N * plan.chunks}

@@ -1,9 +1,11 @@
 """Layers of a causal token-sequence model: RMSNorm, rotary positions, the
-gated short convolution and causal grouped-query attention.
+depthwise causal convolution, the gated short convolution and causal
+grouped-query attention.
 
-These are the operators of the LFM2 family (``model/lfm2.py``). Parameters
-are float32; ``dtype`` is the compute dtype of the matrix products. Nothing
-here has a bias. Sequences are ``[B, S, d]``, position 0 first.
+These are the operators the token models share (``model/lfm2.py``,
+``model/nemotron_h.py``; the state-space mixer is ``ops/ssm.py``). Parameters
+are float32; ``dtype`` is the compute dtype of the matrix products. No
+projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
 
 Attention over ``S`` positions never holds an ``S x S`` score tensor per
 head. Which code computes it follows from the platform the program is being
@@ -66,13 +68,21 @@ def rope(x, theta: float):
     return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
 
 
-def causal_conv(z, kernel):
+def causal_conv(z, kernel, bias=None):
     """Depthwise causal convolution over time: ``c_t = sum_k kernel[k] *
-    z[t - (L-1) + k]`` with zeros before position 0. ``z`` is ``[B, S, d]``,
-    ``kernel`` ``[L, d]``; L shifted multiply-adds, no convolution primitive."""
+    z[t - (L-1) + k] (+ bias)`` with zeros before position 0. ``z`` is
+    ``[B, S, d]``, ``kernel`` ``[L, d]``, ``bias`` ``[d]``; L shifted
+    multiply-adds, no convolution primitive."""
     L, S = kernel.shape[0], z.shape[1]
     padded = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
-    return sum(padded[:, k:k + S] * kernel[k].astype(z.dtype) for k in range(L))
+    c = sum(padded[:, k:k + S] * kernel[k].astype(z.dtype) for k in range(L))
+    return c if bias is None else c + bias.astype(z.dtype)
+
+
+def conv_kernel_init(L: int):
+    """A depthwise kernel's fan-in is its ``L`` taps, as torch's Conv1d draws it: U(+-1/sqrt(L))."""
+    bound = L ** -0.5
+    return lambda key, shape: jax.random.uniform(key, shape, jnp.float32, -bound, bound)
 
 
 class ShortConv(nn.Module):
@@ -86,10 +96,7 @@ class ShortConv(nn.Module):
     def __call__(self, u):
         d = u.shape[-1]
         gate_b, gate_c, x = jnp.split(dense(3 * d, self.dtype, "in_proj")(u), 3, axis=-1)
-        bound = self.L ** -0.5  # a depthwise kernel's fan-in is L, as torch's Conv1d draws it
-        kernel = self.param(
-            "conv_kernel", lambda key, shape: jax.random.uniform(key, shape, jnp.float32, -bound, bound),
-            (self.L, d))
+        kernel = self.param("conv_kernel", conv_kernel_init(self.L), (self.L, d))
         return dense(d, self.dtype, "out_proj")(gate_c * causal_conv(gate_b * x, kernel))
 
 
@@ -142,8 +149,11 @@ def causal_attention(q, k, v, scale: float):
 
 class CausalGQAttention(nn.Module):
     """Causal grouped-query attention: ``heads`` query heads over ``kv_heads``
-    key/value heads, RMSNorm over each head of q and k (one learned scale of
-    ``head_dim``), rotary positions, ``softmax(q k^T / sqrt(head_dim)) v``."""
+    key/value heads, ``softmax(q k^T / sqrt(head_dim)) v``. With ``positions``
+    (LFM2): RMSNorm over each head of q and k (one learned scale of
+    ``head_dim``), then rotary positions. Without (``nemotron_h``: the
+    state-space layers carry the order): q and k as projected, and
+    ``rope_theta`` is read by nothing."""
 
     heads: int
     kv_heads: int
@@ -151,6 +161,7 @@ class CausalGQAttention(nn.Module):
     rope_theta: float = 1e6
     eps: float = 1e-5
     dtype: Dtype = jnp.float32
+    positions: bool = True
 
     @nn.compact
     def __call__(self, u):
@@ -159,8 +170,9 @@ class CausalGQAttention(nn.Module):
         q = dense(H * D, self.dtype, "q_proj")(u).reshape(B, S, H, D)
         k = dense(Hkv * D, self.dtype, "k_proj")(u).reshape(B, S, Hkv, D)
         v = dense(Hkv * D, self.dtype, "v_proj")(u).reshape(B, S, Hkv, D)
-        q = rope(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta)
-        k = rope(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta)
+        if self.positions:
+            q = rope(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta)
+            k = rope(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta)
         out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5)
         return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
 

@@ -12,7 +12,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from benchmark.references import lfm2_plain  # noqa: E402
+from benchmark.references import lfm2_plain, nemotron_h_plain  # noqa: E402
 from distar_tpu.model import LFM2, default_lfm2_config  # noqa: E402
 from distar_tpu.ops import moe  # noqa: E402
 from distar_tpu.ops.sequence import ShortConv, causal_conv  # noqa: E402
@@ -185,30 +185,37 @@ LOADS = {
 }
 
 
+# the layer as each token model builds it, and the plain reference of each
+BODIES = {"swiglu": (dict(), lfm2_plain, 6),                                  # norm, router, w1, w2, w3, input
+          "relu2_shared": (dict(body="relu2", shared_width=12, scaling=2.5), nemotron_h_plain, 7)}  # no w3, 2 shared
+
+
+@pytest.mark.parametrize("body", BODIES)
 @pytest.mark.parametrize("load", LOADS)
-def test_the_layer_is_the_reference_at_every_load_and_walks_the_chunks_that_hold_rows(load):
-    """Value and every gradient leaf (parameters and input) against
-    ``lfm2_plain.experts_held``, with the rows in the first chunk only, spilling
-    into the second, and filling the provable bound; ``buffer_rows`` says how
-    many chunks ran."""
+def test_the_layer_is_the_reference_at_every_load_and_walks_the_chunks_that_hold_rows(load, body):
+    """Value and every gradient leaf (parameters and input) against the plain
+    ``experts_held`` of the model the body belongs to, with the rows in the
+    first chunk only, spilling into the second, and filling the provable
+    bound; ``buffer_rows`` says how many chunks ran."""
     d, width, E, k, N = 16, 8, 8, 3, 40
     push, chunks = LOADS[load]
-    layer = moe.ExpertsHeldMoE(E, k, width, 4, 4)
+    options, plain, n_leaves = BODIES[body]
+    layer = moe.ExpertsHeldMoE(E, k, width, 4, 4, **options)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, N, d))
     variables = layer.init(jax.random.PRNGKey(1), x)
     p = jax.tree.map(lambda a: a * 8.0 if a.ndim >= 2 else a, variables["params"])
     bias = jnp.zeros((E,)).at[jnp.asarray(list(push))].set(jnp.asarray(list(push.values())))
     weight = jax.random.normal(jax.random.PRNGKey(2), (N, d))
     plain_cfg = {"num_experts_per_tok": k, "experts_held": {"offset": 4, "count": 4},
-                 "use_expert_bias": True, "routed_scaling_factor": 1.0}
+                 "use_expert_bias": True, "routed_scaling_factor": options.get("scaling", 1.0)}
 
     def system(p, x):
         y, stats = layer.apply({"params": p, "buffers": {"expert_bias": bias}}, x)
         return (y.reshape(N, d) * weight).sum(), (y, stats)
 
     def reference(p, x):
-        u = lfm2_plain.rms_norm(x, p["norm"]["scale"], 1e-5).reshape(-1, d)
-        y, rows, _ = lfm2_plain.experts_held(p, bias, u, plain_cfg, None)
+        u = plain.rms_norm(x, p["norm"]["scale"], 1e-5).reshape(-1, d)
+        y, rows, _ = plain.experts_held(p, bias, u, plain_cfg, None)
         return (y * weight).sum(), (y, rows)
 
     (_, (y, stats)), grads = jax.value_and_grad(system, argnums=(0, 1), has_aux=True)(p, x)
@@ -220,7 +227,7 @@ def test_the_layer_is_the_reference_at_every_load_and_walks_the_chunks_that_hold
     assert int(stats["buffer_rows"]) == chunks * N and int(stats["overflow"]) == 0
     np.testing.assert_allclose(y.reshape(N, d), want, atol=1e-5, rtol=1e-4)
     flat, ref_flat = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (grads, ref_grads))
-    assert flat.keys() == ref_flat.keys() and len(flat) == 6   # norm, router, w1, w2, w3 and the input
+    assert flat.keys() == ref_flat.keys() and len(flat) == n_leaves
     for path, g in flat.items():
         bound = 1e-3 * float(jnp.abs(ref_flat[path]).max()) + 1e-9
         np.testing.assert_allclose(g, ref_flat[path], atol=bound, rtol=0, err_msg=jax.tree_util.keystr(path))
@@ -316,19 +323,42 @@ def test_take_rows_gradient_is_the_gathers_own():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-def test_the_steps_scopes_are_on_the_compiled_program(tmp_path):
-    """Every name of ``LM_STEP_SCOPES`` is on the op_name paths of the
-    lowered ``lm_train_step``: what the benchmark's trace reader looks for."""
+def _tiny_nemotron_h():
+    from distar_tpu.model import NemotronH, default_nemotron_h_config
+
+    cfg = deep_merge_dicts(default_nemotron_h_config(), {
+        "hidden_size": 64, "hybrid_override_pattern": "M*E", "mamba_num_heads": 8, "mamba_head_dim": 8, "n_groups": 2,
+        "ssm_state_size": 16, "chunk_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+        "n_routed_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 24,
+        "moe_shared_expert_intermediate_size": 48, "experts_held": {"offset": 2, "count": 4}, "vocab_size": 128})
+    model = NemotronH(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 128)
+    return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
+
+
+# the scopes of ``LM_STEP_SCOPES`` that belong to one model's layers only
+ONLY = {"lfm2": {"short_conv", "dense_mlp"}, "nemotron_h": {"ssm_proj", "ssm_scan", "moe_shared"}}
+
+
+@pytest.mark.parametrize("which", ONLY)
+def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
+    """Every name of ``LM_STEP_SCOPES`` that the model has a part for is on
+    the op_name paths of the lowered ``lm_train_step``, and no name of the
+    other model's parts: what the benchmark's trace reader looks for."""
     import optax
 
     from distar_tpu.learner.lm_learner import make_lm_train_step
     from distar_tpu.obs import LM_STEP_SCOPES, tree_spec
 
-    cfg, model, variables, tokens, labels = build()
+    if which == "lfm2":
+        _, model, variables, tokens, labels = build()
+    else:
+        model, variables, tokens, labels = _tiny_nemotron_h()
     optimizer = optax.adam(1e-3)
     step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
     text = step.lower(variables, optimizer.init(variables["params"]),
                       {"tokens": tokens, "labels": labels}).as_text(debug_info=True)
     assert "lm_train_step" in text
-    missing = [name for name in LM_STEP_SCOPES if f"/{name}" not in text and f"({name})" not in text]
-    assert not missing, missing
+    there = {name for name in LM_STEP_SCOPES if f"/{name}" in text or f"({name})" in text}
+    others = set().union(*(names for model_name, names in ONLY.items() if model_name != which))
+    assert there == set(LM_STEP_SCOPES) - others, (set(LM_STEP_SCOPES) - others) ^ there

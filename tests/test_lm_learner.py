@@ -16,13 +16,21 @@ TINY = {"hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 32,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "num_experts": 8, "num_experts_per_tok": 2, "experts_held": {"offset": 2, "count": 4},
         "vocab_size": 128}
+# the second token model, picked by ``model_type``: state-space, attention and expert layers
+TINY_NH = {"model_type": "nemotron_h", "hidden_size": 64, "hybrid_override_pattern": "MEM*E",
+           "mamba_num_heads": 8, "mamba_head_dim": 8, "n_groups": 2, "ssm_state_size": 16, "chunk_size": 8,
+           "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "n_routed_experts": 8,
+           "num_experts_per_tok": 2, "moe_intermediate_size": 24, "moe_shared_expert_intermediate_size": 48,
+           "experts_held": {"offset": 2, "count": 4}, "vocab_size": 128}
+MODELS = {"lfm2": TINY, "nemotron_h": TINY_NH}
+both_models = pytest.mark.parametrize("model", list(MODELS))
 
 
-def learner(tmp_path, name="lm", model=None, **lc):
+def learner(tmp_path, name="lm", model="lfm2", **lc):
     return LMLearner({
         "common": {"experiment_name": name, "save_path": str(tmp_path / name)},
         "learner": {"batch_size": 2, "unroll_len": 32, "save_freq": 100000, "log_freq": 1, **lc},
-        "model": dict(TINY, **(model or {})),
+        "model": MODELS[model],
     })
 
 
@@ -31,8 +39,9 @@ def repeated(batch):
         yield dict(batch)
 
 
-def test_runs_through_the_run_loop_and_the_loss_falls_on_a_repeated_batch(tmp_path):
-    lrn = learner(tmp_path, learning_rate=1e-3)  # the default is a warm-up's first steps
+@both_models
+def test_runs_through_the_run_loop_and_the_loss_falls_on_a_repeated_batch(tmp_path, model):
+    lrn = learner(tmp_path, model=model, learning_rate=1e-3)  # the default is a warm-up's first steps
     lrn.set_dataloader(repeated(fake_token_batch(2, 32, 128)))
     losses = []
     from distar_tpu.learner.hooks import LambdaHook
@@ -48,25 +57,34 @@ def test_runs_through_the_run_loop_and_the_loss_falls_on_a_repeated_batch(tmp_pa
     assert leaves and any(inst.count for _, inst in leaves[0]["series"])
     log = lrn.variable_record.vars()
     assert {"moe_rows_here", "moe_load_max_over_mean", "moe_overflow_rows", "token_acc",
-            "residual_rms/layer_4", "ff_rms/layer_0", "moe_rows/layer_1/expert_3",
-            "dyn/grad_norm/layer_2"} <= set(log)
-    assert "moe_rows/layer_0/expert_0" not in log  # layer 0 is the dense one
+            "residual_rms/layer_4", "moe_rows/layer_1/expert_3", "dyn/grad_norm/layer_2"} <= set(log)
+    assert "moe_rows/layer_0/expert_0" not in log  # layer 0 is the dense one, or a state-space one
+    if model == "lfm2":
+        assert "ff_rms/layer_0" in log and "mixer_rms/layer_0" not in log
+    else:  # a layer is one mixer; the state-space layers (0 and 2 of MEM*E) report their last state
+        assert {"mixer_rms/layer_3", "ssm_state_rms/layer_0", "ssm_state_rms/layer_2"} <= set(log)
+        assert "ssm_state_rms/layer_1" not in log and "ff_rms/layer_0" not in log
+        assert "moe_rows/layer_4/expert_0" in log and "moe_rows/layer_3/expert_0" not in log
     # the expert bias is a buffer: twelve AdamW steps with weight decay left it as drawn
-    fresh = learner(tmp_path, "fresh")
+    fresh = learner(tmp_path, "fresh", model=model)
     for a, b in zip(jax.tree.leaves(lrn.state["params"]["buffers"]),
                     jax.tree.leaves(fresh.state["params"]["buffers"])):
         np.testing.assert_array_equal(a, b)
     assert "buffers" not in str(jax.tree_util.tree_structure(lrn.state["opt_state"]))
 
 
-def test_saves_and_restores(tmp_path):
-    a = learner(tmp_path, "a")
+@both_models
+def test_saves_and_restores(tmp_path, model):
+    # leaf by leaf for the model whose published-width file asks for it (its state is 8 GB on the host)
+    lc = {"sharded_ckpt": True} if model == "nemotron_h" else {}
+    a = learner(tmp_path, "a", model=model, **lc)
     a.set_dataloader(repeated(fake_token_batch(2, 32, 128)))
     a.run(max_iterations=3)
     a._dataloader.close()
     path = a.checkpoint_path()
     a.save(path, sync=True)
-    b = learner(tmp_path, "b")
+    assert os.path.isdir(path) == bool(lc)
+    b = learner(tmp_path, "b", model=model, **lc)
     b.restore(path)
     assert b.last_iter.val == 3
     for x, y in zip(jax.tree.leaves(a.state), jax.tree.leaves(b.state)):
@@ -78,7 +96,8 @@ def test_saves_and_restores(tmp_path):
     assert b.last_iter.val == 5 and np.isfinite(b.log_buffer.get("total_loss", 0.0))
 
 
-def test_step_lowered_from_its_arguments_types_is_the_calls_program(tmp_path):
+@both_models
+def test_step_lowered_from_its_arguments_types_is_the_calls_program(tmp_path, model):
     """As ``tests/test_learner.py`` asks of the SL and RL steps: the
     benchmark's traced run lowers the step from the types of its first
     call's arguments and must get that call's program, not a second one."""
@@ -113,21 +132,22 @@ def test_evaluate_is_the_first_steps_forward_pass_without_an_update(tmp_path):
     assert "grad_norm" not in held_out and "grad_norm" in first
 
 
-def test_overflow_is_counted_as_zero_and_a_row_not_computed_stops_the_run(tmp_path):
+@both_models
+def test_overflow_is_counted_as_zero_and_a_row_not_computed_stops_the_run(tmp_path, model):
     """The expert buffer is the provable bound, so the counter stays at 0;
     the learner does not train on if the step ever reports otherwise."""
-    lrn = learner(tmp_path)
+    lrn = learner(tmp_path, model=model)
     counted = lambda: [inst.value for fam in lrn.metrics.collect()
                        if fam["name"] == "distar_moe_overflow_rows_total" for _, inst in fam["series"]]
     for _ in range(2):
         assert lrn._train(fake_token_batch(2, 32, 128))["moe_overflow_rows"] == 0.0
-    assert counted() == [0]
+    before = counted()  # the registry is the process's: the other model's case counted into it
     step = lrn._train_step
     lrn._train_step = lambda *a: (lambda v, o, info: (v, o, dict(info, overflow=info["overflow"] + 3)))(*step(*a))
     lrn._perf_note_step_args = lambda *a: None
     with pytest.raises(RuntimeError, match="3 rows routed to the experts held here were not computed"):
         lrn._train(fake_token_batch(2, 32, 128))
-    assert counted() == [3]
+    assert counted() == [before[0] + 3]
 
 
 def test_buffer_rows_walked_are_observed_once_a_step(tmp_path):

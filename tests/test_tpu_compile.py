@@ -203,34 +203,43 @@ def test_entity_embedding_compiles_to_products_that_move_no_rows(one_chip):
     assert temp(product) < 2 * ROW_MATRIX_BYTES
 
 
-# ------------------------------------------------ the token model's kernels
-# JAX's own kernels at LFM2-24B-A2B's published widths, as ops/moe.py and
-# ops/sequence.py call them (their tilings and block sizes): 8 held experts of
-# 2048 x 1536 over a buffer of 32,768 rows; 32 heads of 64 over 8,192 positions.
+# ------------------------------------------------ the token models' kernels
+# JAX's own kernels at the published widths of the two token models, as ops/moe.py and
+# ops/sequence.py call them (their tilings and block sizes). LFM2-24B-A2B: 8 held experts of
+# 2048 x 1536, three matrices, over a buffer of 32,768 rows; 32 heads of 64 over 4 x 8,192
+# positions. nemotron_h: 8 held experts of 2688 x 1856, two matrices (neither width a
+# multiple of the tiles), over a buffer of 16,384 rows; 32 heads of 128 over 2 x 8,192.
+EXPERTS = {"lfm2_swiglu": ("swiglu", 32768, 2048, 1536), "nemotron_h_relu2": ("relu2", 16384, 2688, 1856)}
+
+
+@pytest.mark.parametrize("which", EXPERTS)
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
-def test_grouped_expert_products_compile_for_v5e(one_chip, grad):
+def test_grouped_expert_products_compile_for_v5e(one_chip, grad, which):
     from distar_tpu.ops import moe
 
     # the backend here is the CPU, the target is not: the product is chosen by what is compiled for
-    rows, d, width, experts = 32768, 2048, 1536, 8
+    body, rows, d, width = EXPERTS[which]
+    experts, (fn_body, names) = 8, moe.EXPERT_BODIES[body]
     x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
-    w1 = jax.ShapeDtypeStruct((experts, d, width), jnp.bfloat16, sharding=one_chip)
-    w2 = jax.ShapeDtypeStruct((experts, width, d), jnp.bfloat16, sharding=one_chip)
+    ws = [jax.ShapeDtypeStruct((experts, width, d) if n == "w2" else (experts, d, width), jnp.bfloat16,
+                               sharding=one_chip) for n in names]
     sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
 
-    def fn(x, w1, w3, w2, sizes):
-        h = jax.nn.silu(moe.grouped_matmul(x, w1, sizes)) * moe.grouped_matmul(x, w3, sizes)
-        return jnp.sum(moe.grouped_matmul(h, w2, sizes).astype(jnp.float32) ** 2)
+    def fn(x, sizes, *ws):
+        out = fn_body(lambda a, m: moe.grouped_matmul(a, m, sizes), x, *ws)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2, 3)) if grad else fn).lower(x, w1, w1, w2, sizes).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= (9 if grad else 3)
+    n = len(names)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, *range(2, 2 + n))) if grad else fn).lower(x, sizes, *ws).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (3 * n if grad else n)
 
 
+@pytest.mark.parametrize("shape", [(4, 8, 4, 64), (2, 2, 16, 128)], ids=("lfm2_32x64", "nemotron_h_32x128_over_2"))
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
-def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad):
+def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad, shape):
     from distar_tpu.ops.sequence import causal_attention
 
-    B, S, Hkv, G, Dh = 4, 8192, 8, 4, 64
+    (B, Hkv, G, Dh), S = shape, 8192
     q = jax.ShapeDtypeStruct((B, S, Hkv, G, Dh), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((B, S, Hkv, Dh), jnp.bfloat16, sharding=one_chip)
     fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, Dh ** -0.5).astype(jnp.float32) ** 2)
@@ -239,3 +248,20 @@ def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad):
     # no S x S score tensor is held (8.6 GB a sequence in float32): the temporaries are the
     # library's row statistics, which its backward pass broadcasts to the key block's width
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_chunked_scan_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(one_chip, grad):
+    """``ops.ssm.chunked_scan`` at nemotron_h's widths over 2 x 8,192 positions: the
+    ``128 x 128`` decay matrices of all 64 heads at once are 0.54 GB in float32 and as
+    much again for each product and gradient that reads them; a group of 8 at a time,
+    recomputed in its backward pass, the whole scan's temporaries stay under a gigabyte."""
+    from distar_tpu.ops.ssm import chunked_scan
+
+    b, S, H, P, G, N = 2, 8192, 64, 64, 8, 128
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((b, S, H, P)), spec((b, S, H), jnp.float32), spec((H,), jnp.float32),
+            spec((b, S, G, N)), spec((b, S, G, N)))
+    fn = lambda *a: jnp.sum(chunked_scan(*a, 128, jnp.bfloat16)[0] ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2, 3, 4)) if grad else fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9

@@ -16,6 +16,7 @@ Programs (shapes, dtype and batch from the committed configs that
   actor  ``sample_action`` and ``teacher_logits`` at the actor's env batch
   lm     the token-sequence train step (``make_lm_train_step`` as ``LMLearner``
          builds it) of ``configs/lfm2_24b_a2b_v5e.yaml``
+  nh     the same step of ``configs/nemotron_twotower_30b_a3b_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -43,6 +44,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SL_CONFIG = os.path.join(REPO, "configs", "sl_flagship_v5e.yaml")
 RL_CONFIG = os.path.join(REPO, "configs", "rl_flagship_v5e.yaml")
 LM_CONFIG = os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml")
+NH_CONFIG = os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml")
 
 
 def _specs(tree, sharding):
@@ -224,15 +226,16 @@ def check_lm(topo, cfg, batch_size, mesh_spec):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distar_tpu.learner.lm_learner import LM_LEARNER_DEFAULTS, make_lm_train_step
-    from distar_tpu.model import LFM2, default_lfm2_config
+    from distar_tpu.model import TOKEN_MODELS
     from distar_tpu.parallel.mesh import batch_sharding, fsdp_param_sharding
     from distar_tpu.utils import deep_merge_dicts
 
     lc, optimizer, dynamics, mesh, _ = _learner_setup(
         topo, cfg, LM_LEARNER_DEFAULTS, batch_size, mesh_spec)
-    model_cfg = deep_merge_dicts(default_lfm2_config(), cfg.get("model", {}))
+    model_cls, defaults = TOKEN_MODELS[cfg.get("model", {}).get("model_type", "lfm2_moe")]
+    model_cfg = deep_merge_dicts(defaults(), cfg.get("model", {}))
     B, S = lc.batch_size, lc.unroll_len
-    model = LFM2(model_cfg)
+    model = model_cls(model_cfg)
     flat = batch_sharding(mesh, batch_size=B)
     tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=flat)
     variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
@@ -332,10 +335,10 @@ def main() -> None:
             check_rl(topo, read_config(RL_CONFIG), args.batch_size, args.mesh)
         elif what == "actor":
             check_actor(topo, read_config(RL_CONFIG))
-        elif what == "lm":
-            check_lm(topo, read_config(LM_CONFIG), args.batch_size, args.mesh)
+        elif what in ("lm", "nh"):
+            check_lm(topo, read_config(LM_CONFIG if what == "lm" else NH_CONFIG), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh)")
 
 
 if __name__ == "__main__":
