@@ -374,7 +374,9 @@ class AnakinDataLoader:
     ``model.init``) and switches to ``params_provider`` (the learner's live
     train state) as soon as it returns one — on-policy after the first
     window. Batches stay on device end to end: the learner's ``shard_batch``
-    is ``jnp.asarray`` and passes jnp arrays through.
+    hands device arrays to ``device_put`` as they are (no host round-trip;
+    a leaf in another sharding than the step's is re-laid on the device and
+    counted in ``distar_feeder_relaid_leaves_total``).
 
     With an ``opponent_seat`` runner, ``opponent_provider`` supplies the
     frozen away-team parameters each window (a league snapshot published

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import compute_lm_loss
 from ..model import TOKEN_MODELS
-from ..parallel import MeshSpec, make_mesh
+from ..parallel import MeshSpec, assemble_global, make_mesh
 from ..utils import deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
 
@@ -204,10 +204,8 @@ class LMLearner(BaseLearner):
             "distar_moe_overflow_rows_total", "rows routed here that an expert buffer could not take")
 
     def _put(self, data) -> Dict[str, jax.Array]:
-        from ..parallel.feeder import assemble_global
-
-        return {k: assemble_global(jnp.asarray(data[k], jnp.int32), self._shardings["flat"])
-                for k in ("tokens", "labels")}
+        return assemble_global({k: np.asarray(data[k], np.int32) for k in ("tokens", "labels")},
+                               self._shardings["flat"], token=self.name)
 
     def _place_batch(self, data):
         with self._feed_spans.span("put"):

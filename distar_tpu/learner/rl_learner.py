@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import ReinforcementLossConfig, compute_rl_loss
 from ..model import Model, default_model_config
-from ..parallel import MeshSpec, make_mesh
+from ..parallel import MeshSpec, assemble_global, make_mesh
 from ..parallel.grad_clip import leaf_norms
 from ..utils import Config, deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
@@ -296,34 +296,28 @@ class RLLearner(BaseLearner):
         the batch mixes T+1 (obs/values) and T (reward/mask) leading dims
         and only sp-divisible ones can shard.
 
-        Placement goes through ``parallel.feeder.assemble_global``: on one
-        host that is an async ``device_put``; on a pod every host
-        contributes its own batch shard and jax assembles the global
-        array (``make_array_from_process_local_data``)."""
-        from ..parallel.feeder import assemble_global
-
-        hidden = batch.pop("hidden_state")
+        Placement goes through ``parallel.feeder.assemble_global``, once
+        for the batch's host tree under the tree of these shardings: on one
+        host that is one async ``device_put``, each shard from host memory
+        to its own chip; on a pod every host contributes its own batch
+        shard and jax assembles the global array
+        (``make_array_from_process_local_data``)."""
         sp = self.mesh.shape["sp"]
         dp_prod = self.mesh.shape["dp"] * self.mesh.shape["fsdp"]
 
-        def put(x):
-            x = jnp.asarray(x)
-            if x.ndim >= 2:
-                sh = self._shardings["batch"]
-                if sp > 1 and x.shape[0] % sp:
-                    sh = self._shardings["batch_nosp"]
-            elif x.ndim == 1 and x.shape[0] % dp_prod == 0:
-                sh = self._shardings["flat"]
-            else:
-                sh = self._shardings["repl"]
-            return assemble_global(x, sh)
+        def sharding_of(x):
+            shape = np.shape(x)
+            if len(shape) >= 2:
+                if sp > 1 and shape[0] % sp:
+                    return self._shardings["batch_nosp"]
+                return self._shardings["batch"]
+            if len(shape) == 1 and shape[0] % dp_prod == 0:
+                return self._shardings["flat"]
+            return self._shardings["repl"]
 
-        out = jax.tree.map(put, batch)
-        out["hidden_state"] = jax.tree.map(
-            lambda x: assemble_global(jnp.asarray(x), self._shardings["flat"]), hidden
-        )
-        batch["hidden_state"] = hidden
-        return out
+        shardings = jax.tree.map(sharding_of, {k: v for k, v in batch.items() if k != "hidden_state"})
+        shardings["hidden_state"] = jax.tree.map(lambda _: self._shardings["flat"], batch["hidden_state"])
+        return assemble_global(batch, shardings, token=self.name)
 
     def _place_batch(self, batch):
         """Prefetch placement: everything device-put ahead of time except the

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import SupervisedLossConfig, compute_sl_loss
 from ..model import Model, default_model_config
-from ..parallel import MeshSpec, make_mesh
+from ..parallel import MeshSpec, assemble_global, make_mesh
 from ..parallel.grad_clip import leaf_norms
 from ..utils import deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
@@ -253,15 +253,11 @@ class SLLearner(BaseLearner):
         """Prefetch placement: placed (mesh-sharded) ahead of time, host
         fields kept. Routes through ``assemble_global`` so per-host shards
         assemble into global arrays on a pod."""
-        from ..parallel.feeder import assemble_global
-
         with self._feed_spans.span("cap"):
             data = self._cap(dict(data))
             host = {k: np.asarray(data.pop(k)) for k in ("new_episodes", "traj_lens") if k in data}
         with self._feed_spans.span("put"):
-            out = jax.tree.map(
-                lambda x: assemble_global(jnp.asarray(x), self._shardings["flat"]), data
-            )
+            out = assemble_global(data, self._shardings["flat"], token=self.name)
         out.update(host)
         out["_on_device"] = True
         return out
@@ -289,9 +285,7 @@ class SLLearner(BaseLearner):
                 keep = jnp.asarray(~new_episodes, jnp.float32)[:, None]
                 self._hidden = tuple((h * keep, c * keep) for h, c in self._hidden)
             if not on_device:
-                data = jax.tree.map(
-                    lambda x: jax.device_put(jnp.asarray(x), self._shardings["flat"]), data
-                )
+                data = assemble_global(data, self._shardings["flat"], token=self.name)
             debug_on = self.cfg.learner.get("debug_loss_spike", False)
             if debug_on:
                 # the step's exact inputs: batch + post-reset hidden (params are

@@ -6,15 +6,23 @@ pulls its own batch and copies it to its own GPU): under GSPMD there is ONE
 logical batch sharded over the mesh, so the feeder owns the two halves of
 that contract:
 
-* ``assemble_global`` — turn a process-local host array into a global
-  ``jax.Array`` with the requested ``NamedSharding``. Single-process runs
-  (one host owns every mesh device) take the ``device_put`` fast path — the
-  runtime slices the batch onto the addressable devices and streams H2D
-  asynchronously. Multi-process runs (a pod: each host's dataloader pulled
-  only its own batch shard) go through
-  ``jax.make_array_from_process_local_data``, which assembles the global
-  array from per-host locals without ever materialising the full batch on
-  any single host.
+* ``assemble_global`` — turn a process-local host batch (a tree of numpy
+  arrays, or one) into global ``jax.Array``s under the requested
+  ``NamedSharding``s. Single-process runs (one host owns every mesh
+  device) make ONE ``jax.device_put(tree, shardings)`` of the HOST arrays:
+  the runtime slices each leaf on the host (a view where the batch axis
+  leads) and streams every shard from host memory to the chip that trains
+  on it, asynchronously. The leaves must not be turned into device arrays
+  first (``jnp.asarray``): that lands the whole global leaf on the default
+  device, where re-laying it is a slicing program queued behind the running
+  step and device-to-device copies to the other chips. A leaf that does
+  arrive as a ``jax.Array`` in another sharding is still re-laid, and
+  counted in ``distar_feeder_relaid_leaves_total{token}`` (0 when the feed
+  hands over host arrays, as the learners do). Multi-process runs (a pod:
+  each host's dataloader pulled only its own batch shard) go through
+  ``jax.make_array_from_process_local_data`` leaf by leaf, which assembles
+  the global array from per-host locals without ever materialising the
+  full batch on any single host.
 
 * ``ShardFeeder`` — the double-buffer: a background thread pulls the next
   host batch from the dataloader (collate happens there), places it via
@@ -35,7 +43,7 @@ from typing import Callable, Iterator, Optional
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import Sharding
 
 from ..obs import feed_spans, get_registry
 from .mesh import MeshConfigError
@@ -43,20 +51,43 @@ from .mesh import MeshConfigError
 _SENTINEL = object()
 
 
-def assemble_global(x, sharding: NamedSharding):
-    """Host array -> global device array under ``sharding``.
+def assemble_global(tree, shardings, token: str = "feeder"):
+    """Host batch -> global device arrays: ``tree`` is one array or a tree
+    of them, ``shardings`` one ``NamedSharding`` for every leaf or a tree
+    of them shaped like ``tree``.
 
-    Raises ``MeshConfigError`` when a sharded dimension doesn't divide its
-    mesh extent — at the call site with shapes in the message, instead of an
-    opaque XLA error from inside the jitted step.
+    Every shard goes from host memory to its own device in one
+    ``jax.device_put`` of the whole tree. Raises ``MeshConfigError`` when a
+    sharded dimension doesn't divide its mesh extent — at the call site
+    with shapes in the message, instead of an opaque XLA error from inside
+    the jitted step. Leaves that arrive as device arrays in another
+    sharding are re-laid by the runtime (through the device they sit on)
+    and counted in ``distar_feeder_relaid_leaves_total{token}``.
     """
-    x = np.asarray(x) if not hasattr(x, "dtype") else x
-    _check_divisible(x, sharding)
+    leaves, treedef = jax.tree.flatten(tree)
+    if isinstance(shardings, Sharding):
+        shardings = [shardings] * len(leaves)
+    else:
+        shardings = treedef.flatten_up_to(shardings)
+    leaves = [x if hasattr(x, "dtype") else np.asarray(x) for x in leaves]
+    relaid = 0
+    for x, sh in zip(leaves, shardings):
+        _check_divisible(x, sh)
+        relaid += isinstance(x, jax.Array) and not x.sharding.is_equivalent_to(sh, x.ndim)
+    get_registry().counter(
+        "distar_feeder_relaid_leaves_total",
+        "batch leaves that reached placement as device arrays in another "
+        "sharding and were re-laid through the device holding them",
+        token=token,
+    ).inc(relaid)
     if jax.process_count() == 1:
-        return jax.device_put(x, sharding)
-    # pod path: ``x`` is this host's batch shard; every process contributes
-    # its local rows and jax glues them into one global Array
-    return jax.make_array_from_process_local_data(sharding, np.asarray(x))
+        placed = jax.device_put(leaves, shardings)
+    else:
+        # pod path: each leaf is this host's batch shard; every process
+        # contributes its local rows and jax glues them into one global Array
+        placed = [jax.make_array_from_process_local_data(sh, np.asarray(x))
+                  for x, sh in zip(leaves, shardings)]
+    return treedef.unflatten(placed)
 
 
 def _check_divisible(x, sharding) -> None:
@@ -88,7 +119,8 @@ class ShardFeeder:
     mesh-aware placement contract and the ``distar_feeder_*``
     instrumentation. ``place_fn`` receives the
     raw host batch and returns the device-placed batch — for learners that
-    is ``_place_batch`` (entity cap + per-leaf ``assemble_global``).
+    is ``_place_batch`` (entity cap, then one ``assemble_global`` of the
+    batch's host tree).
 
     The producer thread's phases go through ``obs.feed_spans(token)``
     (``distar:feed/<phase>`` in a profiler trace, ``distar_feeder_phase_
@@ -128,7 +160,7 @@ class ShardFeeder:
         )
         self._m_leaves = reg.histogram(
             "distar_feeder_batch_leaves",
-            "device arrays in a placed batch: one device_put each", token=token,
+            "device arrays in a placed batch (one device_put places them all)", token=token,
         )
         self._spans = feed_spans(token)
         self._m_occ = reg.gauge(

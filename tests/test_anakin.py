@@ -80,7 +80,7 @@ def test_batch_layout_matches_collate_contract(batch):
            for (p, g), f in zip(flat_got, flat_fake) if g != f]
     assert not bad, f"shape mismatches vs collate contract: {bad[:8]}"
     # every leaf already lives on device — the learner's shard_batch
-    # (jnp.asarray) must not trigger a host round-trip
+    # (device_put of the arrays as they are) must not trigger a host round-trip
     assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(batch))
     # time-major windows: done/step are [T, B], obs leaves [T+1, B, ...]
     assert batch["done"].shape == (TINY_T, TINY_B)
