@@ -1,5 +1,5 @@
 """Token-sequence learner: next-token training of a token model
-(``model.TOKEN_MODELS``: ``LFM2``, ``NemotronH``; the config's
+(``model.TOKEN_MODELS``: ``LFM2``, ``NemotronH``, ``DeepseekV3``; the config's
 ``model.model_type`` says which, ``lfm2_moe`` when it says nothing) on
 ``BaseLearner``'s run loop, feeder, optimizer, dynamics tree and checkpoints.
 
@@ -15,7 +15,8 @@ Started through the ordinary launcher, which resolves a learner by pipeline
 (``plugins.load_component``); this module is such a pipeline:
 
   python -m distar_tpu.bin.sl_train --pipeline distar_tpu.learner.lm_learner \\
-      --config configs/lfm2_24b_a2b_v5e.yaml --iters N      (or configs/nemotron_twotower_30b_a3b_v5e.yaml)
+      --config configs/lfm2_24b_a2b_v5e.yaml --iters N
+      (or configs/nemotron_twotower_30b_a3b_v5e.yaml, configs/kimi_vl_a3b_v5e.yaml)
 
 With no ``set_dataloader`` it trains on ``FakeTokenDataloader`` (Zipf ids).
 """
@@ -112,7 +113,7 @@ def make_lm_train_step(model, optimizer, dynamics=None):
 
 def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
     """The step's fetched outputs as named scalars: per-layer vectors become
-    ``residual_rms/layer_<i>``, ``ff_rms/layer_<i>`` or ``mixer_rms/layer_<i>``
+    ``residual_rms/layer_<i>``, ``ff_rms/layer_<i>``, ``attn_rms/layer_<i>`` or ``mixer_rms/layer_<i>``
     and ``moe_rows/layer_<i>/expert_<e>`` (``e`` counts the experts held) with
     ``moe_rows_sum/layer_<i>`` and ``moe_rows_max/layer_<i>`` over them; a
     statistic that only some layers have comes as a dict by layer
@@ -123,7 +124,7 @@ def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
             log.update({f"{k}/{name}": float(x) for name, x in v.items()})
             continue
         v = np.asarray(v)
-        if k in ("rms", "ff_rms", "mixer_rms"):
+        if k in ("rms", "ff_rms", "mixer_rms", "attn_rms"):
             name = "residual_rms" if k == "rms" else k
             log.update({f"{name}/layer_{i}": float(x) for i, x in enumerate(v)})
         elif k == "rows":

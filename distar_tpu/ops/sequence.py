@@ -1,21 +1,25 @@
 """Layers of a causal token-sequence model: RMSNorm, rotary positions, the
-depthwise causal convolution, the gated short convolution and causal
-grouped-query attention.
+depthwise causal convolution, the gated short convolution, causal
+grouped-query attention and multi-head latent attention.
 
 These are the operators the token models share (``model/lfm2.py``,
-``model/nemotron_h.py``; the state-space mixer is ``ops/ssm.py``). Parameters
+``model/nemotron_h.py``, ``model/deepseek_v3.py``; the state-space mixer is
+``ops/ssm.py``). Parameters
 are float32; ``dtype`` is the compute dtype of the matrix products. No
 projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
 
 Attention over ``S`` positions never holds an ``S x S`` score tensor per
 head. Which code computes it follows from the platform the program is being
 compiled for (``jax.lax.platform_dependent``) and the shapes: on a TPU, at a
-sequence length its tiles divide (``FLASH_MIN_BLOCK``), JAX's own flash
-attention (``jax.experimental.pallas.ops.tpu.flash_attention``: online
-softmax, blocks above the diagonal skipped, its own backward kernels; it has
-no interpret mode); anywhere else a loop over query blocks under
-``jax.checkpoint`` (every block multiplies against all keys and masks, so it
-does twice the causal work).
+sequence length its tiles divide (``FLASH_MIN_BLOCK``), one of JAX's own
+kernels, each with an online softmax, the blocks above the diagonal skipped
+and backward kernels of its own: where keys and values have one head size,
+flash attention (``jax.experimental.pallas.ops.tpu.flash_attention``; it
+refuses a value head of another size and has no interpret mode); where the
+value head has a size of its own (latent attention: 192 against 128), splash
+attention (``...tpu.splash_attention``), which carries one. Anywhere else a
+loop over query blocks under ``jax.checkpoint`` (every block multiplies
+against all keys and masks, so it does twice the causal work).
 """
 from __future__ import annotations
 
@@ -32,7 +36,11 @@ XLA_QUERY_BLOCK = 512
 # MXU fed; the library's default of 128 is a placeholder ("select better
 # parameters", its own TODO)
 FLASH_BLOCK = 512
-FLASH_MIN_BLOCK = 128  # the kernel's tiles are multiples of this many positions
+FLASH_MIN_BLOCK = 128  # the kernels' tiles are multiples of this many positions
+# splash attention's tiles at 8k positions and head sizes 192/128, with its fused backward kernel (dQ
+# beside dK and dV in one pass over the scores): 26.7 ms a layer forward and backward against 34.2 at
+# 512 with a dQ kernel of its own; 2,048 does not fit the chip's vector memory (PERF.md section 6, PR 31)
+SPLASH_BLOCK = 1024
 
 
 class RMSNorm(nn.Module):
@@ -68,6 +76,23 @@ def rope(x, theta: float):
     return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
 
 
+def rope_interleaved(x, theta: float):
+    """Rotary positions over interleaved pairs: ``(x_2j, x_2j+1)`` is turned
+    by the angle ``t * theta^(-2j/R)``, as a complex number ``x_2j + i x_2j+1``
+    times ``e^(i angle)``. ``x`` is ``[B, S, H, R]``: the rotary part of a
+    head, not the whole of it."""
+    S, R = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]      # [S, R/2]
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[None, :, None, :]
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    even = jnp.arange(R) % 2 == 0
+    # the other member of each pair, with the sign it takes: (-x_2j+1, x_2j)
+    other = jnp.where(even, -jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + other * sin).astype(x.dtype)
+
+
 def causal_conv(z, kernel, bias=None):
     """Depthwise causal convolution over time: ``c_t = sum_k kernel[k] *
     z[t - (L-1) + k] (+ bias)`` with zeros before position 0. ``z`` is
@@ -101,9 +126,9 @@ class ShortConv(nn.Module):
 
 
 def _attention_xla(q, k, v, scale: float):
-    """``q`` [B, S, Hkv, G, D], ``k``/``v`` [B, S, Hkv, D] -> [B, S, Hkv, G, D].
-    One query block at a time against all keys, float32 softmax."""
-    B, S, Hkv, G, D = q.shape
+    """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
+    [B, S, Hkv, G, Dv]. One query block at a time against all keys, float32 softmax."""
+    B, S, Hkv, G, _ = q.shape
     block = min(S, XLA_QUERY_BLOCK)
     if S % block:
         raise ValueError(f"sequence length {S} is not a multiple of the query block {block}")
@@ -118,7 +143,7 @@ def _attention_xla(q, k, v, scale: float):
         return jnp.einsum("bhgqk,bkhd->bqhgd", prob.astype(v.dtype), v)
 
     out = jax.lax.map(one_block, jnp.arange(0, S, block))          # [S/block, B, block, ...]
-    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, Hkv, G, D)
+    return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, Hkv, G, v.shape[-1])
 
 
 def _attention_flash(q, k, v, scale: float):
@@ -138,12 +163,32 @@ def _attention_flash(q, k, v, scale: float):
     return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
 
 
+def _attention_splash(q, k, v, scale: float):
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    B, S, Hkv, G, D = q.shape
+    b = min(S, SPLASH_BLOCK)
+    heads = lambda t: t.reshape(B, S, -1, t.shape[-1]).transpose(0, 2, 1, 3)
+    kernel = splash.make_splash_mha(
+        masks.MultiHeadMask([masks.CausalMask((S, S))] * (Hkv * G)), head_shards=1, q_seq_shards=1,
+        block_sizes=splash.BlockSizes(
+            block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b, block_kv_dkv=b,
+            block_kv_dkv_compute=b, use_fused_bwd_kernel=True))
+    # the kernel has no scale of its own, takes one sequence ([heads, S, .]) and shares a
+    # key/value head among the query heads of its group itself
+    out = jax.vmap(kernel)(heads(q * jnp.asarray(scale, q.dtype)), heads(k), heads(v))
+    return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, v.shape[-1])
+
+
 def causal_attention(q, k, v, scale: float):
-    """``q`` [B, S, Hkv, G, D], ``k``/``v`` [B, S, Hkv, D] -> [B, S, Hkv, G, D]."""
+    """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
+    [B, S, Hkv, G, Dv]: the value head has a size of its own."""
     if q.shape[1] % FLASH_MIN_BLOCK:
         return _attention_xla(q, k, v, scale)
+    kernel = _attention_flash if v.shape[-1] == q.shape[-1] else _attention_splash
     return jax.lax.platform_dependent(
-        q, k, v, tpu=lambda *qkv: _attention_flash(*qkv, scale),
+        q, k, v, tpu=lambda *qkv: kernel(*qkv, scale),
         default=lambda *qkv: _attention_xla(*qkv, scale))
 
 
@@ -175,6 +220,49 @@ class CausalGQAttention(nn.Module):
             k = rope(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta)
         out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5)
         return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention with a decoupled rotary part, as trained
+    (keys and values expanded from the latent; the absorbed form that a
+    decoder caches the latent for is another program, docs/token_models.md):
+
+      q          = W_q u                        [heads x (nope_dim | rope_dim)]
+      [c | k_pe] = W_kva u                      [kv_rank | rope_dim]
+      [k_nope_h | v_h] = W_kvb RMSNorm(c)       [heads x (nope_dim | v_dim)]
+      q_pe, k_pe <- ``rope_interleaved``; k_h = [k_nope_h | k_pe]: ONE rotary
+      key for all heads; ``softmax(q_h . k_h / sqrt(nope_dim + rope_dim)) v_h``;
+      W_o over the heads' ``v_dim`` outputs.
+
+    The projections, the latent norm, the rotation and the broadcast of
+    ``k_pe`` run under the scope ``mla_proj``, the attention itself under
+    ``mla_core``."""
+
+    heads: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 1e4
+    eps: float = 1e-5
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        B, S, d = u.shape
+        H, N, R, V = self.heads, self.nope_dim, self.rope_dim, self.v_dim
+        with jax.named_scope("mla_proj"):
+            q = dense(H * (N + R), self.dtype, "q_proj")(u).reshape(B, S, H, N + R)
+            c, k_pe = jnp.split(dense(self.kv_rank + R, self.dtype, "kv_a_proj")(u), [self.kv_rank], axis=-1)
+            kv = dense(H * (N + V), self.dtype, "kv_b_proj")(RMSNorm(self.eps, name="kv_norm")(c))
+            k_nope, v = jnp.split(kv.reshape(B, S, H, N + V), [N], axis=-1)
+            q = jnp.concatenate([q[..., :N], rope_interleaved(q[..., N:], self.rope_theta)], axis=-1)
+            k_pe = rope_interleaved(k_pe[:, :, None, :], self.rope_theta)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, S, H, R))], axis=-1)
+        with jax.named_scope("mla_core"):
+            out = causal_attention(q[:, :, :, None, :], k, v, (N + R) ** -0.5)
+        with jax.named_scope("mla_proj"):
+            return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * V))
 
 
 class SwiGLU(nn.Module):

@@ -336,15 +336,31 @@ def _tiny_nemotron_h():
     return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
 
 
-# the scopes of ``LM_STEP_SCOPES`` that belong to one model's layers only
-ONLY = {"lfm2": {"short_conv", "dense_mlp"}, "nemotron_h": {"ssm_proj", "ssm_scan", "moe_shared"}}
+def _tiny_deepseek_v3(B=2, S=32):
+    from distar_tpu.model import DeepseekV3, default_deepseek_v3_config
+    from distar_tpu.utils import deep_merge_dicts
+
+    cfg = deep_merge_dicts(default_deepseek_v3_config(), {
+        "hidden_size": 64, "num_hidden_layers": 2, "intermediate_size": 96, "moe_intermediate_size": 24,
+        "num_attention_heads": 4, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+        "v_head_dim": 16, "n_routed_experts": 8, "num_experts_per_tok": 2,
+        "experts_held": {"offset": 2, "count": 4}, "vocab_size": 128})
+    model = DeepseekV3(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 128)
+    return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
 
 
-@pytest.mark.parametrize("which", ONLY)
+# the names of ``LM_STEP_SCOPES`` that each model's layers have, beside those every token model has
+HAS = {"lfm2": {"short_conv", "attention", "dense_mlp"},
+       "nemotron_h": {"ssm_proj", "ssm_scan", "attention", "moe_shared"},
+       "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"}}
+
+
+@pytest.mark.parametrize("which", HAS)
 def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
     """Every name of ``LM_STEP_SCOPES`` that the model has a part for is on
     the op_name paths of the lowered ``lm_train_step``, and no name of the
-    other model's parts: what the benchmark's trace reader looks for."""
+    parts only other models have: what the benchmark's trace reader looks for."""
     import optax
 
     from distar_tpu.learner.lm_learner import make_lm_train_step
@@ -353,12 +369,12 @@ def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
     if which == "lfm2":
         _, model, variables, tokens, labels = build()
     else:
-        model, variables, tokens, labels = _tiny_nemotron_h()
+        model, variables, tokens, labels = {"nemotron_h": _tiny_nemotron_h, "deepseek_v3": _tiny_deepseek_v3}[which]()
     optimizer = optax.adam(1e-3)
     step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
     text = step.lower(variables, optimizer.init(variables["params"]),
                       {"tokens": tokens, "labels": labels}).as_text(debug_info=True)
     assert "lm_train_step" in text
     there = {name for name in LM_STEP_SCOPES if f"/{name}" in text or f"({name})" in text}
-    others = set().union(*(names for model_name, names in ONLY.items() if model_name != which))
+    others = set().union(*HAS.values()) - HAS[which]
     assert there == set(LM_STEP_SCOPES) - others, (set(LM_STEP_SCOPES) - others) ^ there

@@ -251,6 +251,26 @@ def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad, shape)
 
 
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_attention_with_a_value_head_of_its_own_compiles_for_v5e_at_8k_positions(one_chip, grad):
+    """Latent attention's core at the published head sizes, 16 heads of 192 (score) and 128
+    (value) over 2 x 8,192 positions: ONE kernel at those sizes (splash attention), nothing
+    padded to reach the flash kernel, which refuses unequal head sizes."""
+    from distar_tpu.ops.sequence import causal_attention
+
+    B, S, H = 2, 8192, 16
+    q = jax.ShapeDtypeStruct((B, S, H, 1, 192), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, S, H, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, S, H, 128), jnp.bfloat16, sharding=one_chip)
+    fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 192 ** -0.5).astype(jnp.float32) ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, k, v).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "splash" in text
+    assert not [ln for ln in text.split("\n") if "pad(" in ln and ("8192,256" in ln or "8192,192]" in ln and ",128]" in ln)]
+    # no S x S score tensor is held; the fused backward kernel's unreduced dQ (a float32 copy a key block) is the largest
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
 def test_chunked_scan_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(one_chip, grad):
     """``ops.ssm.chunked_scan`` at nemotron_h's widths over 2 x 8,192 positions: the
     ``128 x 128`` decay matrices of all 64 heads at once are 0.54 GB in float32 and as

@@ -17,6 +17,7 @@ Programs (shapes, dtype and batch from the committed configs that
   lm     the token-sequence train step (``make_lm_train_step`` as ``LMLearner``
          builds it) of ``configs/lfm2_24b_a2b_v5e.yaml``
   nh     the same step of ``configs/nemotron_twotower_30b_a3b_v5e.yaml``
+  kimi   the same step of ``configs/kimi_vl_a3b_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -43,8 +44,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SL_CONFIG = os.path.join(REPO, "configs", "sl_flagship_v5e.yaml")
 RL_CONFIG = os.path.join(REPO, "configs", "rl_flagship_v5e.yaml")
-LM_CONFIG = os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml")
-NH_CONFIG = os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml")
+# the token-sequence steps (``check_lm``), by ``--what``
+LM_CONFIGS = {"lm": os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml"),
+              "nh": os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml"),
+              "kimi": os.path.join(REPO, "configs", "kimi_vl_a3b_v5e.yaml")}
 
 
 def _specs(tree, sharding):
@@ -335,10 +338,10 @@ def main() -> None:
             check_rl(topo, read_config(RL_CONFIG), args.batch_size, args.mesh)
         elif what == "actor":
             check_actor(topo, read_config(RL_CONFIG))
-        elif what in ("lm", "nh"):
-            check_lm(topo, read_config(LM_CONFIG if what == "lm" else NH_CONFIG), args.batch_size, args.mesh)
+        elif what in LM_CONFIGS:
+            check_lm(topo, read_config(LM_CONFIGS[what]), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi)")
 
 
 if __name__ == "__main__":
