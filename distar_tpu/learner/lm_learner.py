@@ -138,6 +138,8 @@ def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
             log["moe_overflow_rows"] = float(v)
         elif k == "buffer_rows":
             log["moe_buffer_rows"] = float(v)
+        elif k == "row_indexed":
+            log["moe_row_indexed_layers"] = float(v)
         else:
             log[k] = float(v)
     return log
@@ -201,6 +203,9 @@ class LMLearner(BaseLearner):
         self._moe_buffer_rows = self.metrics.histogram(
             "distar_moe_buffer_rows",
             "rows of the expert buffers walked (chunks of one row a position that held rows), all layers, per step")
+        self._moe_row_indexed = self.metrics.histogram(
+            "distar_moe_row_indexed_layers",
+            "expert layers whose program moved its rows by buffer row, not by pick, per step")
         self._moe_overflow = self.metrics.counter(
             "distar_moe_overflow_rows_total", "rows routed here that an expert buffer could not take")
 
@@ -246,6 +251,7 @@ class LMLearner(BaseLearner):
             self._moe_rows.observe(log["moe_rows_here"])
             self._moe_load.observe(log["moe_load_max_over_mean"])
             self._moe_buffer_rows.observe(log["moe_buffer_rows"])
+            self._moe_row_indexed.observe(log["moe_row_indexed_layers"])
             self._moe_overflow.inc(log["moe_overflow_rows"])
             # the layer's buffer is its provable bound: a row that found no place is a fault of ops/moe
             if log["moe_overflow_rows"]:

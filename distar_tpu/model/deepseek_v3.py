@@ -115,7 +115,7 @@ class DeepseekV3(nn.Module):
     stats)``. ``stats``: ``rms`` [layers] of the residual stream after each
     layer, ``attn_rms`` and ``ff_rms`` [layers] of each layer's attention and
     feed-forward outputs, ``rows`` [expert layers, experts held], ``overflow``
-    [] and ``buffer_rows`` [] as ``LFM2`` reports them."""
+    [], ``buffer_rows`` [] and ``row_indexed`` [] as ``LFM2`` reports them."""
 
     cfg: Dict
 
@@ -147,4 +147,5 @@ class DeepseekV3(nn.Module):
             "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
             "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
             "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
+            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
         }

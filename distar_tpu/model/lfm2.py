@@ -101,7 +101,8 @@ class LFM2(nn.Module):
     stats)``. ``stats``: ``rms`` [layers] of the residual stream after each
     layer, ``ff_rms`` [layers] of each layer's feed-forward output, ``rows`` [expert layers, experts held] routed to each held expert,
     ``overflow`` [] rows the expert buffers did not take (0 by construction),
-    ``buffer_rows`` [] rows of the expert buffers that were walked, all layers."""
+    ``buffer_rows`` [] rows of the expert buffers that were walked, all layers,
+    ``row_indexed`` [] the expert layers whose program moved rows by buffer row."""
 
     cfg: Dict
 
@@ -133,4 +134,5 @@ class LFM2(nn.Module):
             "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
             "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
             "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
+            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
         }

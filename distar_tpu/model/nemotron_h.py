@@ -122,8 +122,8 @@ class NemotronH(nn.Module):
     stats)``. ``stats``: ``rms`` [layers] of the residual stream after each
     layer, ``mixer_rms`` [layers] of each layer's mixer output,
     ``ssm_state_rms`` {``layer_<i>``: []}, ``ops.ssm.state_rms`` of each ``M`` layer's state after the last position,
-    ``rows`` [``E`` layers, experts held], ``overflow`` [] and ``buffer_rows``
-    [] as ``LFM2`` reports them."""
+    ``rows`` [``E`` layers, experts held], ``overflow`` [], ``buffer_rows``
+    [] and ``row_indexed`` [] as ``LFM2`` reports them."""
 
     cfg: Dict
 
@@ -158,4 +158,5 @@ class NemotronH(nn.Module):
             "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
             "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
             "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
+            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
         }

@@ -20,23 +20,33 @@ row a position, twice the expected load of ``top_k * count / num_experts``
 and more: up to the end of the last chunk that holds rows, rounded up to a
 length that has a program (``walk``: a program for one chunk, for two and
 for the whole buffer, chosen by a count the step computes from its own
-picks, forward and backward). So the gather into the buffer, the grouped products
-(``group_sizes``: they skip the empty tail anyway), the masks and their
-backward passes cost what the load holds, and a router that sends the bound
-gets the whole buffer at the whole buffer's cost; the weighted sum gathers by
-pick (``N * top_k`` indices whatever the length). ``buffer_rows`` reports the
-rows walked. ``overflow`` counts the rows routed here that the buffer did not
-take: 0 by construction, reported so that the learner and the benchmark can
-hold the layer to it. The grouped product is chosen by the platform the
-program is being compiled for (``jax.lax.platform_dependent``): JAX's
-megablox kernel on a TPU (``jax.experimental.pallas.ops.tpu.megablox``: tiles
-over the rows of each group, grid as long as the rows present),
-``jax.lax.ragged_dot`` anywhere else.
+picks, forward and backward). Every row of ``d`` or ``width`` numbers that
+the layer moves is indexed by buffer row, over the ``length`` rows of the
+program that ran, forward and backward: the rows of ``u`` copied into the
+buffer (``copy_rows``), the grouped products (``group_sizes``: they skip the
+empty tail anyway), the masks, and the weighted sum, in which each buffer row
+carries the weight of the pick it serves and is added to its position
+(``add_rows``). So all of it costs what the load holds, and a router that
+sends the bound gets the whole buffer at the whole buffer's cost. Only
+scalars are indexed by pick (a weight a pick and its gradient, through
+``Dispatch.slot``). ``buffer_rows`` reports the rows walked. ``overflow``
+counts the rows routed here that the buffer did not take: 0 by construction,
+reported so that the learner and the benchmark can hold the layer to it. The
+grouped product is chosen by the platform the program is being compiled for
+(``jax.lax.platform_dependent``): JAX's megablox kernel on a TPU
+(``jax.experimental.pallas.ops.tpu.megablox``: tiles over the rows of each
+group, grid as long as the rows present), ``jax.lax.ragged_dot`` anywhere
+else.
 
-Moving rows to the sorted buffer and back is a gather in both directions,
-forward and backward (``take_rows``): the transpose of a row gather is a
-scatter-add, which the TPU serialises, and here every source row's takers
-are known.
+The way back from the buffer is a row add by position (a scatter-add), and so
+is the backward pass of the way in. Until PR 32 both were gathers by pick
+(``N * top_k`` indices whatever the length), because the TPU takes a
+scatter-add row by row; it sorts the indices and still does, at 100-180 ns a
+row, but a buffer holds a quarter or a sixth of the picks at the loads the
+cells send, and on the chip the row adds were ahead at every length, the
+whole buffer included (PERF.md section 5, "PR 32": 42 ms a layer for 67 at
+one chunk of LFM2's shape, 128 for 133 at the whole buffer). So there is one
+form, and no threshold between two.
 """
 from __future__ import annotations
 
@@ -74,7 +84,6 @@ class Dispatch(NamedTuple):
 
     token: jnp.ndarray       # [R] the position each buffer row copies (0 beyond the rows present)
     slot: jnp.ndarray        # [R] the flat pick n*k+j each buffer row serves (N*k beyond)
-    row: jnp.ndarray         # [N, k] the buffer row of each pick, R where its expert is not here
     group_sizes: jnp.ndarray  # [count] rows of each held expert in the buffer
     chunks: jnp.ndarray      # [] chunks of N rows that hold rows, counting the first, which always runs
     rows: jnp.ndarray        # [count] rows routed to each held expert
@@ -95,35 +104,44 @@ def dispatch(sel, offset: int, count: int) -> Dispatch:
     ends = jnp.minimum(jnp.cumsum(rows), capacity)
     group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
     present = ends[-1]
-    # the buffer row of every flat pick: the inverse of ``order``
-    where = jnp.argsort(order).astype(jnp.int32)
-    row = jnp.where(held.reshape(-1) & (where < present), where, capacity).reshape(N, k)
     valid = jnp.arange(capacity) < present
-    slot = jnp.where(valid, order[:capacity], N * k)
-    return Dispatch(token=jnp.where(valid, order[:capacity] // k, 0), slot=slot, row=row,
+    return Dispatch(token=jnp.where(valid, order[:capacity] // k, 0), slot=jnp.where(valid, order[:capacity], N * k),
                     group_sizes=group_sizes, chunks=jnp.clip((present + N - 1) // N, 1, chunks),
                     rows=rows, overflow=rows.sum() - present)
 
 
 @jax.custom_vjp
-def take_rows(src, idx, takers):
-    """``src[idx]``. ``takers`` [len(src), m] names, for each source row, the
-    output rows that took it (``len(idx)`` = none), so the backward pass is a
-    gather too: ``d_src[r] = sum_j d_out[takers[r, j]]``."""
+def copy_rows(src, idx, present):
+    """``src[idx]``, of which the rows ``present`` count. The backward pass
+    adds their cotangents back by ``idx``, ``len(idx)`` row adds (the other
+    rows' are whatever the grouped product left there): in float32, as the
+    sum over a row's takers is made, and rounded once."""
     return src[idx]
 
 
-def _take_rows_fwd(src, idx, takers):
-    return src[idx], (idx, takers)
+def _copy_rows_fwd(src, idx, present):
+    return src[idx], (src, idx, present)   # ``src`` for its shape and dtype
 
 
-def _take_rows_bwd(res, g):
-    idx, takers = res
-    taken = (takers < g.shape[0])[..., None]
-    return jnp.where(taken, g[jnp.minimum(takers, g.shape[0] - 1)], 0).sum(axis=1), None, None
+def _copy_rows_bwd(res, g):
+    src, idx, present = res
+    g = jnp.where(present[:, None], g, 0).astype(jnp.float32)
+    return jnp.zeros(src.shape, jnp.float32).at[idx].add(g).astype(src.dtype), None, None
 
 
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+copy_rows.defvjp(_copy_rows_fwd, _copy_rows_bwd)
+
+
+def add_rows(out, w, token, slot):
+    """``FF[n] = sum over the buffer rows r of position n of w[slot[r]] *
+    out[r]`` [N, d] float32: each row carries the weight of the pick it
+    serves (a gather of scalars) and is added to its position, ``len(out)``
+    row adds. A row beyond those present is zero in ``out``, and adds that
+    to position 0. Backward (plain autodiff): the cotangent gathered by
+    ``token``, a row-wise product for the weights' gradient, scalars back by ``slot``."""
+    N, k = w.shape
+    w_row = w.reshape(-1)[jnp.minimum(slot, N * k - 1)]
+    return jnp.zeros((N, out.shape[1]), jnp.float32).at[token].add(out.astype(jnp.float32) * w_row[:, None])
 
 
 def megablox(x, w, group_sizes, interpret: bool = False):
@@ -167,35 +185,31 @@ def shared_expert(body: str, u, ws):
 
 def _buffer(body: str, length: int, u, w, ws, plan: Dispatch):
     """``FF`` [N, d] float32 from the first ``length`` rows of the buffer,
-    which hold every row present: the rows of ``u`` gathered, the body's
-    grouped products, and the weighted sum over the picks served."""
-    N, k = w.shape
+    which hold every row present: the rows of ``u`` copied in, the body's
+    grouped products, and each row times its pick's weight added to its
+    position."""
+    token, slot = plan.token[:length], plan.slot[:length]
+    present = slot < w.size
     with jax.named_scope("moe_dispatch"):
-        xs = take_rows(u, plan.token[:length], plan.row)
+        xs = copy_rows(u, token, present)
     with jax.named_scope("moe_experts"):
         out = EXPERT_BODIES[body][0](lambda x, m: grouped_matmul(x, m, plan.group_sizes), xs, *ws)
     with jax.named_scope("moe_combine"):
-        slot = plan.slot[:length]
-        out = jnp.where((slot < N * k)[:, None], out, 0)
-        # a pick whose expert is not here reads row 0 with weight 0
-        here = plan.row < length
-        picked = take_rows(out, jnp.where(here, plan.row, 0).reshape(-1), slot[:, None])
-        return (picked.reshape(N, k, -1).astype(jnp.float32) * jnp.where(here, w, 0.0)[..., None]).sum(1)
+        return add_rows(jnp.where(present[:, None], out, 0), w, token, slot)
 
 
-def _lengths(plan: Dispatch):
+def _lengths(N: int, plan: Dispatch):
     """The buffer lengths that have a program: one chunk of ``N`` rows (twice
     the expected load and more), two, and the whole buffer. A program a chunk
     is a compile a chunk (each holds the grouped product's kernels, forward
     and backward), and a load beyond two chunks is rare enough to round up."""
-    N, chunks = plan.row.shape[0], plan.token.shape[0] // plan.row.shape[0]
+    chunks = plan.token.shape[0] // N
     return [N * c for c in sorted({1, min(2, chunks), chunks})]
 
 
-def _program(plan: Dispatch):
+def _program(N: int, plan: Dispatch):
     """The first of ``_lengths`` that takes ``plan.chunks`` chunks."""
-    N = plan.row.shape[0]
-    return sum((plan.chunks * N > n).astype(jnp.int32) for n in _lengths(plan)[:-1])
+    return sum((plan.chunks * N > n).astype(jnp.int32) for n in _lengths(N, plan)[:-1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -210,7 +224,8 @@ def walk(body: str, u, w, ws, plan: Dispatch):
     from whichever ran, zeros for the others). Under a decoder layer's remat
     that forward takes the place of the replay's, which is then dead code;
     without remat it is one forward more than a buffer of one length costs."""
-    return jax.lax.switch(_program(plan), [functools.partial(_buffer, body, n) for n in _lengths(plan)],
+    N = u.shape[0]
+    return jax.lax.switch(_program(N, plan), [functools.partial(_buffer, body, n) for n in _lengths(N, plan)],
                           u, w, ws, plan)
 
 
@@ -220,12 +235,13 @@ def _walk_fwd(body, *args):
 
 def _walk_bwd(body, args, g):
     *rest, plan = args
+    N = rest[0].shape[0]
 
     def pull(length):
         # ``checkpoint``: the forward computed here is a recompute, and reads as one in a trace
         return lambda g, *rest: jax.vjp(jax.checkpoint(lambda *a: _buffer(body, length, *a, plan)), *rest)[1](g)
 
-    return (*jax.lax.switch(_program(plan), [pull(n) for n in _lengths(plan)], g, *rest), None)
+    return (*jax.lax.switch(_program(N, plan), [pull(n) for n in _lengths(N, plan)], g, *rest), None)
 
 
 walk.defvjp(_walk_fwd, _walk_bwd)
@@ -240,8 +256,9 @@ class ExpertsHeldMoE(nn.Module):
     ``shared_width``, ``shared`` (the same names, [d, shared_width] and back).
     ``expert_bias`` [num_experts] is a buffer (collection ``buffers``): drawn
     at init, never trained. Returns ``(FF(RMSNorm(u)), stats)`` with ``stats``
-    the ``rows`` routed to each held expert, the ``overflow`` and the
-    ``buffer_rows`` walked."""
+    the ``rows`` routed to each held expert, the ``overflow``, the
+    ``buffer_rows`` walked and ``row_indexed`` (1: this layer's program moved
+    its rows by buffer row, as every length's does)."""
 
     num_experts: int
     top_k: int
@@ -285,4 +302,5 @@ class ExpertsHeldMoE(nn.Module):
             y = y + shared_expert(self.body, u, shared).astype(jnp.float32)
         with jax.named_scope("moe_combine"):
             y = y.astype(x.dtype).reshape(B, S, d)
-        return y, {"rows": plan.rows, "overflow": plan.overflow, "buffer_rows": N * plan.chunks}
+        return y, {"rows": plan.rows, "overflow": plan.overflow, "buffer_rows": N * plan.chunks,
+                   "row_indexed": jnp.ones((), jnp.int32)}

@@ -165,10 +165,9 @@ def test_dispatch_sorts_the_picks_by_expert_into_the_provable_bound():
     # each present row copies the position that picked it, sorted by expert
     assert plan.token[:8].tolist() == [0, 1, 2, 3, 4, 0, 1, 3]
     assert plan.slot[:8].tolist() == [0, 2, 4, 7, 9, 1, 3, 6] and plan.slot[8:].tolist() == [10, 10]
-    assert sorted(plan.row.reshape(-1).tolist()) == list(range(8)) + [10, 10]
-    # each pick's row serves it
-    flat = plan.row.reshape(-1)
-    assert all(int(plan.slot[r]) == i for i, r in enumerate(flat.tolist()) if r < 10)
+    # each row serves a pick of the position it copies, and that pick is of its expert
+    assert all(s // 2 == t and int(sel.reshape(-1)[s]) == e for s, t, e in
+               zip(plan.slot[:8].tolist(), plan.token[:8].tolist(), [0] * 5 + [1] * 3))
     # one expert held: a position can send it one row at most, and the buffer is that long
     one = moe.dispatch(sel, 0, 1)
     assert one.token.shape == (5,) and one.rows.tolist() == [5] and int(one.overflow) == 0
@@ -236,14 +235,14 @@ def test_the_layer_is_the_reference_at_every_load_and_walks_the_chunks_that_hold
 
 def test_every_row_move_belongs_to_the_length_the_load_chose():
     """The lowered value-and-gradient of the layer (CPU, 24 positions, top-3,
-    2 held: a buffer of 2 x 24 rows for 72 picks): outside the choice between
-    the buffer's lengths no row of ``d`` or ``width`` numbers is gathered, and
-    the program for the first chunk moves ``N`` rows (by buffer row) or
-    ``N * k`` (by pick), never the buffer's ``2 * N``."""
+    4 held: a buffer of 3 x 24 rows for 72 picks): outside the choice between
+    the buffer's lengths no row of ``d`` or ``width`` numbers is gathered or
+    scattered, and each length's program moves ``length`` of them at a time,
+    by buffer row, forward and backward: one chunk never ``N * k``."""
     import re
 
-    d, width, E, k, N = 16, 8, 8, 3, 24
-    layer = moe.ExpertsHeldMoE(E, k, width, 0, 2)
+    d, width, E, k, N = 16, 12, 8, 3, 24
+    layer = moe.ExpertsHeldMoE(E, k, width, 0, 4)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, N, d))
     variables = layer.init(jax.random.PRNGKey(1), x)
     f = lambda p, x: layer.apply({"params": p, "buffers": variables["buffers"]}, x)[0].sum()
@@ -268,13 +267,24 @@ def test_every_row_move_belongs_to_the_length_the_load_chose():
                 top = None
         return "\n".join(outside), [["\n".join(b) for b in c] for c in cases]
 
-    moved = lambda t: sorted({int(np.prod([int(n) for n in dims.split("x")[:-1]]))
-                              for dims in re.findall(r'"stablehlo\.gather".*-> tensor<((?:\d+x)+\d+)xf32>', t)
-                              if dims.endswith(f"x{d}") or dims.endswith(f"x{width}")})
+    def rows(found):
+        """Rows moved at a time by the operations whose moved type is ``found``,
+        of those that move rows of ``d`` or ``width`` numbers."""
+        return sorted({int(np.prod([int(n) for n in dims.split("x")[:-1]])) for dims in found
+                       if dims.endswith(f"x{d}") or dims.endswith(f"x{width}")})
+
+    dims = r"((?:\d+x)+\d+)xf32>"
+    gathered = lambda t: rows(re.findall(r'"stablehlo\.gather".*-> tensor<' + dims, t))
+    # a scatter's types close its region: (operand, indices, updates) -> result
+    scattered = lambda t: rows(re.findall(r"^\s*\}\) : \(tensor<[^>]*>, tensor<[^>]*>, tensor<" + dims + r"\) ->", t, re.M))
     outside, cases = choices(text)
-    assert moved(outside) == [] and [len(c) for c in cases] == [2, 2]   # forward and backward, two lengths each
-    for first, whole in cases:
-        assert moved(first) == [N, N * k] and moved(whole) == [2 * N, N * k]
+    assert gathered(outside) == [] and scattered(outside) == []
+    assert [len(c) for c in cases] == [3, 3]                       # forward and backward, three lengths each
+    for programs in cases:
+        for chunks, program in enumerate(programs, start=1):
+            assert (gathered(program), scattered(program)) == ([chunks * N], [chunks * N])
+    # backward, one chunk: the rows of u again and the cotangent by position; then rows added to d_u
+    assert len(re.findall(r'"stablehlo\.gather".*-> tensor<' + f"{N}x{d}xf32>", cases[1][0])) == 2
 
 
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
@@ -313,14 +323,92 @@ def test_route_is_sigmoid_top_k_with_a_bias_on_the_selection_only():
                                rtol=1e-6)
 
 
-def test_take_rows_gradient_is_the_gathers_own():
-    src = jax.random.normal(jax.random.PRNGKey(0), (5, 3))
-    idx = jnp.asarray([4, 0, 0, 2, 4, 4])
-    takers = jnp.asarray([[1, 2, 6], [6, 6, 6], [3, 6, 6], [6, 6, 6], [0, 4, 5]])
-    weight = jax.random.normal(jax.random.PRNGKey(1), (6, 3))
-    got = jax.grad(lambda s: (moe.take_rows(s, idx, takers) * weight).sum())(src)
-    want = jax.grad(lambda s: (s[idx] * weight).sum())(src)
-    np.testing.assert_allclose(got, want, rtol=1e-6)
+# five positions, top-3, eight buffer rows: position 4 is copied three times, 0
+# twice, 2 once, positions 1 and 3 have no pick held; the last two rows are
+# beyond the rows present (they copy position 0 and serve no pick)
+ROW_TOKEN = jnp.asarray([4, 0, 0, 2, 4, 4, 0, 0])
+ROW_SLOT = jnp.asarray([12, 0, 2, 7, 13, 14, 15, 15])   # the pick n*k+j each row serves, 15 = none
+
+
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("float32", "bfloat16"))
+def test_copy_rows_gradient_is_the_row_adds_of_plain_indexing(dtype):
+    """``copy_rows`` against autodiff of ``src[idx]`` masked to the rows
+    present: duplicate positions add up (in float32, rounded once), a row
+    beyond those present adds nothing whatever its cotangent holds, and a
+    position no row copies gets zero."""
+    src = jax.random.normal(jax.random.PRNGKey(0), (5, 3)).astype(dtype)
+    present = ROW_SLOT < 15
+    weight = jax.random.normal(jax.random.PRNGKey(1), (8, 3)).astype(dtype)
+    np.testing.assert_array_equal(moe.copy_rows(src, ROW_TOKEN, present), src[ROW_TOKEN])
+    # the grouped product leaves anything in the rows it does not own
+    junk = jnp.where(present[:, None], 1.0, jnp.nan).astype(dtype)
+    got = jax.grad(lambda s: (moe.copy_rows(s, ROW_TOKEN, present).astype(jnp.float32) * weight * junk).sum())(src)
+    plain = lambda s: (jnp.where(present[:, None], s[ROW_TOKEN], 0) * weight).sum()
+    want = jax.grad(plain)(src.astype(jnp.float32))
+    assert got.dtype == dtype and bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got.astype(jnp.float32), want.astype(dtype).astype(jnp.float32), rtol=1e-6)
+    assert not got[1].any() and not got[3].any() and bool(got[4].any())
+
+
+def test_add_rows_is_the_weighted_sum_over_the_picks_held_and_so_are_its_gradients():
+    """``add_rows`` (rows added by position, each times the weight of the pick
+    it serves) against the sum written pick by pick, value and both
+    gradients: the weights travel by ``slot`` both ways, a pick that no row
+    serves gets no gradient, a position without rows gets a zero row."""
+    N, k, d = 5, 3, 4
+    out = jax.random.normal(jax.random.PRNGKey(0), (8, d))
+    # as ``_buffer`` calls it: the mask zeroes the rows beyond those present, and their cotangents
+    by_row = lambda out, w: moe.add_rows(jnp.where((ROW_SLOT < N * k)[:, None], out, 0), w, ROW_TOKEN, ROW_SLOT)
+    w = jax.random.uniform(jax.random.PRNGKey(1), (N, k)) + 0.5
+    weight = jax.random.normal(jax.random.PRNGKey(2), (N, d))
+    # the buffer row of each pick, 8 where none serves it
+    row = jnp.full((N * k,), 8).at[ROW_SLOT[:6]].set(jnp.arange(6)).reshape(N, k)
+
+    def by_pick(out, w):
+        padded = jnp.concatenate([out[:6], jnp.zeros((3, d))])
+        return (padded[row] * w[..., None]).sum(1)
+
+    got = by_row(out, w)
+    assert got.dtype == jnp.float32 and not got[1].any() and not got[3].any()
+    np.testing.assert_allclose(got, by_pick(out, w), rtol=1e-6)
+    grads = [jax.grad(lambda out, w, f=f: (f(out, w) * weight).sum(), argnums=(0, 1))(out, w)
+             for f in (by_row, by_pick)]
+    for got, want in zip(*grads):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+    # positions 1 and 3, and the picks of the others that no row serves
+    assert np.flatnonzero(np.asarray(grads[0][1]).reshape(-1)).tolist() == sorted(ROW_SLOT[:6].tolist())
+
+
+# (N, top_k, experts held) of the three token cells
+CELL_LAYERS = {"lfm2_train_b4s8k": (32768, 4, 8), "nemotron_twotower_train_b2s8k": (16384, 6, 8),
+               "kimi_vl_train_b2s8k": (16384, 6, 8)}
+
+
+@pytest.mark.parametrize("cell", CELL_LAYERS)
+def test_each_program_of_a_cells_layer_moves_rows_by_buffer_row(cell):
+    """One chunk, two and the whole buffer at the cell's ``(N, k, count)``:
+    the chip measured the moves by buffer row ahead of those by pick at all
+    three (PERF.md section 5, "PR 32"), so there is one form and no constant:
+    each program of the lowered layer adds its ``length`` rows to their
+    positions, and ``row_indexed`` in ``stats`` says so at every load
+    (the layer at the cell's ``k`` and ``count``, 32 positions)."""
+    import re
+
+    N, k, count = CELL_LAYERS[cell]
+    plan = jax.eval_shape(lambda sel: moe.dispatch(sel, 0, count), jax.ShapeDtypeStruct((N, k), jnp.int32))
+    assert moe._lengths(N, plan) == [N, 2 * N, N * min(k, count)]
+    n, d, E = 32, 8, 16
+    layer = moe.ExpertsHeldMoE(E, k, 4, 0, count)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, n, d))
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    apply = jax.jit(lambda bias: layer.apply({"params": variables["params"], "buffers": {"expert_bias": bias}}, x))
+    text = apply.lower(jnp.zeros((E,))).as_text()
+    moved = re.findall(r"^\s*\}\) : \(tensor<[^>]*>, tensor<[^>]*>, tensor<(\d+)x" + f"{d}xf32>" + r"\) ->", text, re.M)
+    assert sorted(int(m) for m in moved) == [n, 2 * n, n * k]
+    for pushed in (0, 1, k):   # the first ``pushed`` held experts take every position
+        bias = jnp.where(jnp.arange(E) < pushed, 10.0, jnp.where(jnp.arange(E) < count, -10.0, 0.0))
+        stats = apply(bias)[1]
+        assert int(stats["buffer_rows"]) == n * (pushed or 1) and int(stats["row_indexed"]) == 1
 
 
 def _tiny_nemotron_h():

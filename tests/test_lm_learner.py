@@ -170,6 +170,18 @@ def test_buffer_rows_walked_are_observed_once_a_step(tmp_path):
     assert any(w > 4 * N for w in walked)  # some layer of some step took its second chunk
 
 
+def test_the_layers_that_moved_rows_by_buffer_row_are_logged_and_observed(tmp_path):
+    """``moe_row_indexed_layers`` / ``distar_moe_row_indexed_layers``: every
+    expert layer of a step (four here), whatever length its buffer was walked to."""
+    lrn = learner(tmp_path)
+    hist = lambda: [inst for fam in lrn.metrics.collect() if fam["name"] == "distar_moe_row_indexed_layers"
+                    for _, inst in fam["series"]][0]
+    count, total = hist().count, hist().sum
+    log = lrn._train(fake_token_batch(2, 32, 128, np.random.default_rng(0)))
+    assert log["moe_row_indexed_layers"] == 4 == len(lrn._moe_layers)
+    assert (hist().count, hist().sum) == (count + 1, total + 4)
+
+
 def test_sl_train_reaches_it_through_the_plugin_registry(tmp_path, monkeypatch, capsys):
     from distar_tpu import plugins
     from distar_tpu.bin import sl_train

@@ -251,12 +251,12 @@ def test_attention_without_positions_is_blind_to_rope_theta_and_has_no_head_norm
 
 def test_a_buffer_has_a_program_for_one_chunk_two_and_the_whole_and_runs_the_first_that_fits():
     sel = jnp.zeros((8, 6), jnp.int32)
-    lengths = lambda k, count: moe._lengths(moe.dispatch(sel[:, :k], 0, count))
+    lengths = lambda k, count: moe._lengths(8, moe.dispatch(sel[:, :k], 0, count))
     assert lengths(6, 8) == [8, 16, 48] and lengths(4, 8) == [8, 16, 32]
     assert lengths(3, 8) == [8, 16, 24] and lengths(2, 1) == [8] and lengths(6, 2) == [8, 16]
     plan = moe.dispatch(sel, 0, 8)
     for chunks, program in ((1, 0), (2, 1), (3, 2), (4, 2), (6, 2)):
-        assert int(moe._program(plan._replace(chunks=jnp.asarray(chunks)))) == program
+        assert int(moe._program(8, plan._replace(chunks=jnp.asarray(chunks)))) == program
 
 
 def test_the_learner_finds_both_models_by_their_published_model_type():
