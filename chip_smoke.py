@@ -167,7 +167,9 @@ def check_trainer(name: str, save_path: str, want_count: int) -> dict:
 
     hits = last(s, "distar_compile_cache_hits_total", 0.0)
     misses = last(s, "distar_compile_cache_misses_total", 0.0)
-    print(f"[{name}] compile: trace_s={last(s, 'distar_compile_trace_seconds_total'):.1f} "
+    trace_s = sum(points[max(points)] for key, points in s.items()
+                  if key.startswith("distar_compile_seconds_total{") and "stage=trace" in key)
+    print(f"[{name}] compile: trace_s={trace_s:.1f} "
           f"backend_compile_s={last(s, 'distar_compile_backend_seconds_total'):.1f} "
           f"persistent_cache hits={hits:.0f} misses={misses:.0f} "
           f"-> cache {'HIT' if hits and not misses else 'cold or partial'}", flush=True)

@@ -23,6 +23,8 @@ from ..obs import (
     feed_spans,
     get_registry,
     loop_spans,
+    mark_process_age,
+    setup_spans,
     tree_spec,
 )
 from ..utils import Config, build_logger, deep_merge_dicts
@@ -89,6 +91,24 @@ DEFAULT_LEARNER_CONFIG = Config(
 
 class BaseLearner:
     def __init__(self, cfg: Optional[dict] = None):
+        self.metrics = get_registry()
+        # set-up under named phases (obs/profiler.py::SETUP_PHASES), from here
+        # to the end of the first step of ``run``; the process's age at four
+        # points holds them to the clock and counts what came before and between
+        mark_process_age("learner_init", self.metrics)
+        self._setup_spans = setup_spans(self.metrics)
+        with self._setup_spans.span("learner_base"):
+            self._setup_base(cfg)
+        with self._setup_spans.span("dataloader"):
+            self._setup_dataloader()
+        self._setup_state()  # its own phases, by learner
+        with self._setup_spans.span("state_ready"):
+            # the init programs' device time is set-up's, not the first step's
+            jax.block_until_ready(self._state)
+        mark_process_age("learner_ready", self.metrics)
+
+    def _setup_base(self, cfg: Optional[dict]) -> None:
+        """Logger, checkpoint manager, spans, hooks and monitors."""
         self.cfg = deep_merge_dicts(DEFAULT_LEARNER_CONFIG, cfg or {})
         self.rank = jax.process_index()
         self.world_size = jax.process_count()
@@ -110,7 +130,6 @@ class BaseLearner:
             role=self.cfg.learner.get("ckpt_role", "") or self.CKPT_ROLE,
         )
         self.log_buffer: Dict[str, Any] = {}
-        self.metrics = get_registry()
         # the phases of the run loop and of the feeder thread: spans on the
         # profiler's clock and the phase histograms (obs/profiler.py)
         self.spans = loop_spans(self.metrics)
@@ -149,8 +168,6 @@ class BaseLearner:
         self._profile_req: Optional[Dict[str, Any]] = None
         self._state = None  # TrainState pytree (params, opt_state, step)
         self._dataloader: Optional[Iterator] = None
-        self._setup_dataloader()
-        self._setup_state()
 
     # pad-to-bucket entity cap: subclasses set _CAP_FN to the layout-aware
     # slicer (data.cap_entities / cap_entities_rl); one choke point for all
@@ -222,9 +239,10 @@ class BaseLearner:
             )
 
     def restore(self, path: str) -> None:
-        self._checkpointer.wait()  # the path may still be being written
-        out = load_checkpoint(path, target=self._state)
-        self._validate_restored(path, out["state"])
+        with self._setup_spans.span("restore"):
+            self._checkpointer.wait()  # the path may still be being written
+            out = load_checkpoint(path, target=self._state)
+            self._validate_restored(path, out["state"])
         layout = out.get("sharding_layout") or {}
         saved_mesh = layout.get("mesh_shape")
         cur_mesh = dict(self.mesh.shape) if getattr(self, "mesh", None) is not None else None
@@ -239,7 +257,8 @@ class BaseLearner:
                 f"resharding restore: checkpoint mesh {saved_mesh} -> "
                 f"live mesh {cur_mesh}"
             )
-        self._state = self._place_state(out["state"])
+        with self._setup_spans.span("state_place"):
+            self._state = self._place_state(out["state"])
         self.last_iter.update(out["metadata"].get("last_iter", 0))
 
     def _validate_restored(self, path: str, state) -> None:
@@ -501,6 +520,7 @@ class BaseLearner:
 
     # ------------------------------------------------------------------ run
     def run(self, max_iterations: Optional[int] = None) -> None:
+        mark_process_age("run_start", self.metrics)
         max_iterations = max_iterations or self.cfg.learner.max_iterations
         self._maybe_enable_prefetch()
 
@@ -524,52 +544,67 @@ class BaseLearner:
 
         self._stop_requested = False
 
+        def iteration():
+            # every line of an iteration is under exactly one leaf span:
+            # data_wait, pre_step, device_step's prepare / dispatch /
+            # fetch (in _train), post_step, host_callback, tick. A function,
+            # so that its batch is released when it returns, while the feeder
+            # thread still sleeps on its full queue, and not when the next
+            # ``next()`` rebinds the name beside the feeder it just woke
+            spans = self.spans
+            with spans.step("train", self.last_iter.val):
+                with spans.span("data_wait") as waited:
+                    data = next(self._dataloader)
+                with spans.span("pre_step"):
+                    self.log_buffer["data_time"] = waited.seconds
+                    self._perf.note_batch(data)
+                    self.hooks.call("before_iter", self)
+                    # stash aux refs (e.g. the SL pre-step hidden carry) so an
+                    # anomaly bundle can reconstruct the step's exact inputs
+                    self._dynamics.before_step(self)
+                with spans.span("device_step") as stepped:
+                    log_vars = self._train(data)
+                with spans.span("post_step"):
+                    t_train = stepped.seconds
+                    self.log_buffer["train_time"] = t_train
+                    self.log_buffer.update(log_vars)
+                    loss = log_vars.get("total_loss")
+                    if loss is not None:
+                        try:
+                            loss_gauge.set(float(loss))
+                        except (TypeError, ValueError):
+                            pass
+                    # detection + gauge export from the already-fetched host
+                    # log (no extra device sync); the batch is only touched
+                    # if an anomaly writes a black-box bundle
+                    self._dynamics.on_step(self, log_vars, data)
+                    self.last_iter.add(1)
+                    # before the hooks, so the metrics export among them
+                    # carries THIS iteration's step time and memory sample
+                    iters_total.inc()
+                    step_time.observe(t_train)
+                    self._perf.on_step(t_train, frames_per_iter)
+                # everything after the device step: hook pass (log
+                # reduction, checkpoint scheduling, weight publication)
+                with spans.span("host_callback"):
+                    self.hooks.call("after_iter", self)
+                with spans.span("tick"):
+                    self._profile_tick()
+
+        def more() -> bool:
+            return self.last_iter.val < max_iterations and not self._stop_requested
+
         @auto_checkpoint(lambda: self.save(self.checkpoint_path(), sync=True))
         def _run():
             self.hooks.call("before_run", self)
-            spans = self.spans
-            while self.last_iter.val < max_iterations and not self._stop_requested:
-                # every line of an iteration is under exactly one leaf span:
-                # data_wait, pre_step, device_step's prepare / dispatch /
-                # fetch (in _train), post_step, host_callback, tick
-                with spans.step("train", self.last_iter.val):
-                    with spans.span("data_wait") as waited:
-                        data = next(self._dataloader)
-                    with spans.span("pre_step"):
-                        self.log_buffer["data_time"] = waited.seconds
-                        self._perf.note_batch(data)
-                        self.hooks.call("before_iter", self)
-                        # stash aux refs (e.g. the SL pre-step hidden carry) so an
-                        # anomaly bundle can reconstruct the step's exact inputs
-                        self._dynamics.before_step(self)
-                    with spans.span("device_step") as stepped:
-                        log_vars = self._train(data)
-                    with spans.span("post_step"):
-                        t_train = stepped.seconds
-                        self.log_buffer["train_time"] = t_train
-                        self.log_buffer.update(log_vars)
-                        loss = log_vars.get("total_loss")
-                        if loss is not None:
-                            try:
-                                loss_gauge.set(float(loss))
-                            except (TypeError, ValueError):
-                                pass
-                        # detection + gauge export from the already-fetched host
-                        # log (no extra device sync); the batch is only touched
-                        # if an anomaly writes a black-box bundle
-                        self._dynamics.on_step(self, log_vars, data)
-                        self.last_iter.add(1)
-                        # before the hooks, so the metrics export among them
-                        # carries THIS iteration's step time and memory sample
-                        iters_total.inc()
-                        step_time.observe(t_train)
-                        self._perf.on_step(t_train, frames_per_iter)
-                    # everything after the device step: hook pass (log
-                    # reduction, checkpoint scheduling, weight publication)
-                    with spans.span("host_callback"):
-                        self.hooks.call("after_iter", self)
-                    with spans.span("tick"):
-                        self._profile_tick()
+            if more():
+                # the last set-up phase: this run's first iteration traces,
+                # compiles or loads the step and runs it
+                with self._setup_spans.span("first_step"):
+                    iteration()
+                mark_process_age("first_step_done", self.metrics)
+            while more():
+                iteration()
             self.hooks.call("after_run", self)
 
         try:

@@ -168,9 +168,6 @@ class DistillLearner(BaseLearner):
     def _setup_state(self) -> None:
         lc = self.cfg.learner
         B, T = lc.batch_size, lc.unroll_len
-        data = dict(next(self._dataloader))
-        data.pop("model_last_iter", None)  # host-side; _train pops it too
-        batch = jax.tree.map(jnp.asarray, self._strip_batch(self._cap(data)))
         self.optimizer = self._build_optimizer()
 
         def init_fn(rng, spatial, entity, scalar, entity_num, hidden, action, sun):
@@ -179,18 +176,25 @@ class DistillLearner(BaseLearner):
                 B, T, method=self.model.policy_forward,
             )
 
-        init_args = (
-            *(_flatten_time(batch[k]) for k in ("spatial_info", "entity_info", "scalar_info")),
-            batch["entity_num"].reshape(-1),
-            jax.tree.map(jnp.asarray, self._student_zero_hidden(B)),
-            batch["action_info"],
-            batch["selected_units_num"],
-        )
-        params = jax.jit(init_fn)(jax.random.PRNGKey(self.init_prng_seed), *init_args)
-        self._state = {
-            "params": params,
-            "opt_state": jax.jit(self.optimizer.init)(params),
-        }
+        setup = self._setup_spans
+        with setup.span("fake_batch"):
+            data = dict(next(self._dataloader))
+            data.pop("model_last_iter", None)  # host-side; _train pops it too
+            batch = jax.tree.map(jnp.asarray, self._strip_batch(self._cap(data)))
+            init_args = (
+                *(_flatten_time(batch[k]) for k in ("spatial_info", "entity_info", "scalar_info")),
+                batch["entity_num"].reshape(-1),
+                jax.tree.map(jnp.asarray, self._student_zero_hidden(B)),
+                batch["action_info"],
+                batch["selected_units_num"],
+            )
+        with setup.span("model_init"):
+            params = jax.jit(init_fn)(jax.random.PRNGKey(self.init_prng_seed), *init_args)
+        with setup.span("opt_init"):
+            self._state = {
+                "params": params,
+                "opt_state": jax.jit(self.optimizer.init)(params),
+            }
         core = self.model_cfg.encoder.core_lstm
         step_fn = make_distill_train_step(
             self.model, self.loss_cfg, self.optimizer, B, T,

@@ -173,20 +173,24 @@ class LMLearner(BaseLearner):
         B, S = lc.batch_size, lc.unroll_len
         self.mesh = shrink_dp(self.mesh, B)
         self.optimizer = self._build_optimizer()
-        tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        rng = jax.random.PRNGKey(self.init_prng_seed)
-        param_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.model.init, rng, tokens))
-        # made where they will live: the state is 16 bytes a parameter and
-        # no second copy of it fits beside it
-        variables = jax.jit(
-            lambda r: self.model.init(r, jnp.zeros(tokens.shape, tokens.dtype)),
-            out_shardings=param_sh)(rng)
-        opt_sh = fsdp_param_sharding(
-            self.mesh, jax.eval_shape(self.optimizer.init, variables["params"]))
-        self._state = {
-            "params": variables,
-            "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(variables["params"]),
-        }
+        setup = self._setup_spans
+        with setup.span("init_shapes"):  # a whole host trace of the model, for its shardings
+            tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
+            rng = jax.random.PRNGKey(self.init_prng_seed)
+            param_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.model.init, rng, tokens))
+        with setup.span("model_init"):
+            # made where they will live: the state is 16 bytes a parameter and
+            # no second copy of it fits beside it
+            variables = jax.jit(
+                lambda r: self.model.init(r, jnp.zeros(tokens.shape, tokens.dtype)),
+                out_shardings=param_sh)(rng)
+        with setup.span("opt_init"):
+            opt_sh = fsdp_param_sharding(
+                self.mesh, jax.eval_shape(self.optimizer.init, variables["params"]))
+            self._state = {
+                "params": variables,
+                "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(variables["params"]),
+            }
         repl = NamedSharding(self.mesh, P())
         self._shardings = dict(repl=repl, param=param_sh, opt=opt_sh,
                                flat=batch_sharding(self.mesh, batch_size=B))

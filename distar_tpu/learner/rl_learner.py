@@ -202,7 +202,6 @@ class RLLearner(BaseLearner):
         from ..parallel.mesh import set_context_mesh
 
         set_context_mesh(self.mesh)  # ring attention resolves sp at trace time
-        batch = self._cap(next(self._dataloader))
         self.optimizer = self._build_optimizer()
         # jit the init: eager init dispatches thousands of tiny ops
         def init_fn(rng, spatial, entity, scalar, entity_num, hidden, action, sun, vf):
@@ -212,16 +211,19 @@ class RLLearner(BaseLearner):
                 method=self.model.rl_forward,
             )
 
-        batch = jax.tree.map(jnp.asarray, batch)
-        vf = batch.get("value_feature")
-        init_args = (
-            *(_flatten_time(batch[k]) for k in ("spatial_info", "entity_info", "scalar_info")),
-            batch["entity_num"].reshape(-1),
-            batch["hidden_state"],
-            batch["action_info"],
-            batch["selected_units_num"],
-            _flatten_time(vf) if vf is not None else None,
-        )
+        setup = self._setup_spans
+        with setup.span("fake_batch"):
+            batch = self._cap(next(self._dataloader))
+            batch = jax.tree.map(jnp.asarray, batch)
+            vf = batch.get("value_feature")
+            init_args = (
+                *(_flatten_time(batch[k]) for k in ("spatial_info", "entity_info", "scalar_info")),
+                batch["entity_num"].reshape(-1),
+                batch["hidden_state"],
+                batch["action_info"],
+                batch["selected_units_num"],
+                _flatten_time(vf) if vf is not None else None,
+            )
         jitted_init = jax.jit(init_fn)
         # for admin-triggered value resets: keep only shape/dtype specs (not
         # the batch itself — that would pin it in HBM for the whole run)
@@ -234,21 +236,24 @@ class RLLearner(BaseLearner):
             return jitted_init(rng, *dummy)
 
         self._init_params = _reinit
-        params = jitted_init(jax.random.PRNGKey(self.init_prng_seed), *init_args)
+        with setup.span("model_init"):
+            params = jitted_init(jax.random.PRNGKey(self.init_prng_seed), *init_args)
         del init_args
         from ..parallel.mesh import batch_sharding, fsdp_param_sharding, time_batch_sharding
 
-        repl = NamedSharding(self.mesh, P())
-        # params sharded over the fsdp axis (replicated when fsdp == 1);
-        # Adam moments follow the same shardings, so optimizer state is
-        # 1/fsdp-sized per device
-        param_sh = fsdp_param_sharding(self.mesh, params)
-        params = jax.device_put(params, param_sh)
-        opt_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.optimizer.init, params))
-        self._state = {
-            "params": params,
-            "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(params),
-        }
+        with setup.span("state_place"):
+            repl = NamedSharding(self.mesh, P())
+            # params sharded over the fsdp axis (replicated when fsdp == 1);
+            # Adam moments follow the same shardings, so optimizer state is
+            # 1/fsdp-sized per device
+            param_sh = fsdp_param_sharding(self.mesh, params)
+            params = jax.device_put(params, param_sh)
+        with setup.span("opt_init"):
+            opt_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.optimizer.init, params))
+            self._state = {
+                "params": params,
+                "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(params),
+            }
         step_fn = make_rl_train_step(
             self.model, self.loss_cfg, self.optimizer, B, T,
             save_grad=self.cfg.learner.get("save_grad", False),

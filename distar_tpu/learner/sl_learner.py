@@ -139,17 +139,19 @@ class SLLearner(BaseLearner):
         from ..parallel.mesh import set_context_mesh
 
         set_context_mesh(self.mesh)  # ring attention resolves sp at trace time
-        core = self.model_cfg.encoder.core_lstm
-        self._hidden = tuple(
-            (jnp.zeros((B, core.hidden_size)), jnp.zeros((B, core.hidden_size)))
-            for _ in range(core.num_layers)
-        )
         self.optimizer = self._build_optimizer()
-        batch = next(self._dataloader)
-        batch.pop("new_episodes", None)
-        batch.pop("traj_lens", None)
-        batch = self._cap(batch)  # init at the capped shape: one compile, not two
-        batch = jax.tree.map(jnp.asarray, batch)
+        setup = self._setup_spans
+        with setup.span("fake_batch"):
+            core = self.model_cfg.encoder.core_lstm
+            self._hidden = tuple(
+                (jnp.zeros((B, core.hidden_size)), jnp.zeros((B, core.hidden_size)))
+                for _ in range(core.num_layers)
+            )
+            batch = next(self._dataloader)
+            batch.pop("new_episodes", None)
+            batch.pop("traj_lens", None)
+            batch = self._cap(batch)  # init at the capped shape: one compile, not two
+            batch = jax.tree.map(jnp.asarray, batch)
 
         def init_fn(rng, spatial, entity, scalar, entity_num, action, sun, hidden):
             return self.model.init(
@@ -157,30 +159,34 @@ class SLLearner(BaseLearner):
                 method=self.model.sl_forward,
             )
 
-        params = jax.jit(init_fn)(
-            jax.random.PRNGKey(self.init_prng_seed),
-            batch["spatial_info"], batch["entity_info"], batch["scalar_info"],
-            batch["entity_num"], batch["action_info"], batch["selected_units_num"],
-            self._hidden,
-        )
+        with setup.span("model_init"):
+            params = jax.jit(init_fn)(
+                jax.random.PRNGKey(self.init_prng_seed),
+                batch["spatial_info"], batch["entity_info"], batch["scalar_info"],
+                batch["entity_num"], batch["action_info"], batch["selected_units_num"],
+                self._hidden,
+            )
         from ..parallel.mesh import batch_sharding, fsdp_param_sharding
 
-        repl = NamedSharding(self.mesh, P())
-        param_sh = fsdp_param_sharding(self.mesh, params)
-        params = jax.device_put(params, param_sh)
-        opt_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.optimizer.init, params))
-        self._state = {
-            "params": params,
-            "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(params),
-        }
-        # batch_size validates here: typed MeshConfigError at compile time,
-        # not an opaque XLA sharding error on the first step
-        flat_sh = batch_sharding(self.mesh, batch_size=B)
-        self._shardings = dict(repl=repl, param=param_sh, opt=opt_sh, flat=flat_sh)
-        # the carry the step is first called with must be typed like the one
-        # it hands back (out_shardings below), or the second iteration
-        # re-traces and re-compiles the whole step
-        self._hidden = jax.device_put(self._hidden, flat_sh)
+        with setup.span("state_place"):
+            repl = NamedSharding(self.mesh, P())
+            param_sh = fsdp_param_sharding(self.mesh, params)
+            params = jax.device_put(params, param_sh)
+        with setup.span("opt_init"):
+            opt_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.optimizer.init, params))
+            self._state = {
+                "params": params,
+                "opt_state": jax.jit(self.optimizer.init, out_shardings=opt_sh)(params),
+            }
+        with setup.span("state_place"):
+            # batch_size validates here: typed MeshConfigError at compile time,
+            # not an opaque XLA sharding error on the first step
+            flat_sh = batch_sharding(self.mesh, batch_size=B)
+            self._shardings = dict(repl=repl, param=param_sh, opt=opt_sh, flat=flat_sh)
+            # the carry the step is first called with must be typed like the one
+            # it hands back (out_shardings below), or the second iteration
+            # re-traces and re-compiles the whole step
+            self._hidden = jax.device_put(self._hidden, flat_sh)
         self._train_step = jax.jit(
             make_sl_train_step(
                 self.model, self.loss_cfg, self.optimizer, B,

@@ -8,8 +8,11 @@ profiler must never take down training) and counts sessions in the registry.
 ``with spans.span("<phase>")`` is a ``jax.profiler.TraceAnnotation`` named
 ``distar:<role>/<phase>`` (so the phase sits on the device trace's clock
 whenever a profiler session is active, and costs nothing when none is) and
-one observation of the seconds in the role's phase histogram. The learner
-run loop (role ``loop``) and the feeder thread (role ``feed``) use it.
+one record of the seconds in the role's instrument: an observation of a
+phase histogram for the learner run loop (role ``loop``) and the feeder
+thread (role ``feed``), which pass through a phase every step; an increment
+of a counter for a learner's set-up (role ``setup``), whose phases
+(``SETUP_PHASES``) happen once.
 ``STEP_SCOPES`` is the fixed vocabulary of ``jax.named_scope`` / Flax module
 names under which every operation of a jitted train step is found;
 ``LM_STEP_SCOPES`` is the token-sequence learner's.
@@ -18,6 +21,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 from time import perf_counter
 from typing import Callable, Dict, Optional
 
@@ -132,10 +136,10 @@ SPAN_PREFIX = "distar:"
 
 
 class _Span:
-    __slots__ = ("_name", "_hist", "_open", "_annotation", "_t0", "seconds")
+    __slots__ = ("_name", "_record", "_open", "_annotation", "_t0", "seconds")
 
-    def __init__(self, name, hist, open_annotation):
-        self._name, self._hist, self._open = name, hist, open_annotation
+    def __init__(self, name, record, open_annotation):
+        self._name, self._record, self._open = name, record, open_annotation
         self.seconds = 0.0
 
     def __enter__(self):
@@ -147,21 +151,24 @@ class _Span:
     def __exit__(self, *exc):
         self._annotation.__exit__(*exc)
         self.seconds = perf_counter() - self._t0
-        self._hist.observe(self.seconds)
+        self._record(self.seconds)
         return False
 
 
 class Spans:
     """The phases of one host loop: ``role`` names the loop in the trace
-    (``distar:<role>/<phase>``), ``histogram`` gives the registry histogram a
-    phase's seconds are observed into. A span observes on exit also when its
-    body raised; ``span.seconds`` is readable after it. One instance serves
-    one thread's loop; spans nest by ``with``."""
+    (``distar:<role>/<phase>``), ``record`` gives for a phase the call that
+    takes its seconds (a registry histogram's ``observe``, a counter's
+    ``inc``), ``opened`` is told the phase when a span of it is made. A span
+    records on exit also when its body raised; ``span.seconds`` is readable
+    after it. One instance serves one thread's loop; spans nest by ``with``."""
 
-    def __init__(self, role: str, histogram: Callable[[str], object]):
+    def __init__(self, role: str, record: Callable[[str], Callable[[float], None]],
+                 opened: Optional[Callable[[str], None]] = None):
         self.role = role
-        self._histogram = histogram
-        self._hists: Dict[str, object] = {}
+        self._record = record
+        self._opened = opened
+        self._records: Dict[str, Callable[[float], None]] = {}
         self._profiler = None
 
     def _resolve(self):
@@ -171,21 +178,23 @@ class Spans:
             self._profiler = jax.profiler
         return self._profiler
 
-    def _hist(self, phase: str):
-        hist = self._hists.get(phase)
-        if hist is None:
-            hist = self._hists[phase] = self._histogram(phase)
-        return hist
+    def _recorder(self, phase: str) -> Callable[[float], None]:
+        record = self._records.get(phase)
+        if record is None:
+            record = self._records[phase] = self._record(phase)
+        return record
 
     def span(self, phase: str) -> _Span:
-        return _Span(f"{SPAN_PREFIX}{self.role}/{phase}", self._hist(phase),
+        if self._opened is not None:
+            self._opened(phase)
+        return _Span(f"{SPAN_PREFIX}{self.role}/{phase}", self._recorder(phase),
                      self._resolve().TraceAnnotation)
 
     def step(self, name: str, step_num: int, phase: str = "iteration") -> _Span:
         """One iteration of the loop as the profiler's own step marker
         (``StepTraceAnnotation``), observed as ``phase``."""
         profiler = self._resolve()
-        return _Span(name, self._hist(phase),
+        return _Span(name, self._recorder(phase),
                      lambda n: profiler.StepTraceAnnotation(n, step_num=step_num))
 
 
@@ -194,7 +203,7 @@ def loop_spans(registry: Optional[MetricsRegistry] = None) -> Spans:
     ``host_callback`` since PR 1; the finer ones since PR 23)."""
     reg = registry or get_registry()
     return Spans("loop", lambda phase: reg.histogram(
-        "distar_learner_step_phase_seconds", "learner step time by phase", phase=phase))
+        "distar_learner_step_phase_seconds", "learner step time by phase", phase=phase).observe)
 
 
 def feed_spans(token: str, registry: Optional[MetricsRegistry] = None) -> Spans:
@@ -202,4 +211,75 @@ def feed_spans(token: str, registry: Optional[MetricsRegistry] = None) -> Spans:
     reg = registry or get_registry()
     return Spans("feed", lambda phase: reg.histogram(
         "distar_feeder_phase_seconds", "feeder thread time per batch by phase",
-        phase=phase, token=token))
+        phase=phase, token=token).observe)
+
+
+# A learner's set-up, from the first touch of the backend to the end of its
+# first step, in the order the phases come; leaves, none inside another, and a
+# learner has the phases it has (``fake_batch`` types the init of SL and RL,
+# ``init_shapes`` the token learner's; ``restore`` runs where a launcher
+# resumes). ``first_step`` is the first iteration of a ``run``, with the run
+# loop's own spans beneath it in a trace.
+SETUP_PHASES = (
+    "backend_init", "learner_base", "dataloader", "fake_batch", "init_shapes",
+    "model_init", "opt_init", "state_place", "restore", "state_ready", "first_step",
+)
+
+# What set-up is doing now, for the compile listener's ``during`` label
+# (``utils/compile_cache.py``): the open phase, ``outside`` between phases and
+# before the first, ``run`` once a first step is over. One name for the
+# process: set-up is one thread.
+setup_during = "outside"
+
+
+def setup_spans(registry: Optional[MetricsRegistry] = None) -> Spans:
+    """A learner's set-up phases (``SETUP_PHASES``): a phase's seconds add to
+    ``distar_setup_seconds_total{phase}``, and ``setup_during`` names it
+    while it is open."""
+    reg = registry or get_registry()
+
+    def opened(phase: str) -> None:
+        global setup_during
+        if phase not in SETUP_PHASES:
+            raise ValueError(f"set-up phase {phase!r}: one of {SETUP_PHASES}")
+        setup_during = phase
+
+    def record(phase: str) -> Callable[[float], None]:
+        counter = reg.counter(
+            "distar_setup_seconds_total", "seconds of a learner's set-up by phase", phase=phase)
+
+        def closed(seconds: float) -> None:
+            global setup_during
+            counter.inc(seconds)
+            setup_during = "run" if phase == "first_step" else "outside"
+
+        return closed
+
+    return Spans("setup", record, opened)
+
+
+def process_age() -> Optional[float]:
+    """Seconds since this process started, by the kernel's record of its
+    start (not by when a module was imported); None where ``/proc`` cannot
+    say."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command, which may itself hold spaces
+            # and brackets; the process's start is the 22nd of all, in clock
+            # ticks since boot
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def mark_process_age(at: str, registry: Optional[MetricsRegistry] = None) -> None:
+    """``distar_setup_process_age_seconds{at}``: how old the process is at a
+    point of a learner's set-up (``learner_init``, ``learner_ready``,
+    ``run_start``, ``first_step_done``), so that import, the backend and what
+    the launcher did before and between are numbers beside the phases."""
+    age = process_age()
+    if age is not None:
+        (registry or get_registry()).gauge(
+            "distar_setup_process_age_seconds",
+            "seconds since the process started, at a point of a learner's set-up", at=at).set(age)
