@@ -77,7 +77,7 @@ class Encoder(nn.Module):
             locations,
             (static_cfg(self.cfg).spatial_y, static_cfg(self.cfg).spatial_x),
             static_cfg(self.cfg).encoder.scatter.type,
-            impl=static_cfg(self.cfg).encoder.scatter.get("impl", "xla"),
+            impl=static_cfg(self.cfg).encoder.scatter.get("impl", "product"),
         )
         spatial_cls = (
             nn.remat(SpatialEncoder)

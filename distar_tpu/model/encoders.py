@@ -398,7 +398,10 @@ class ValueEncoder(nn.Module):
         proj = proj * unit_mask[..., None]
         loc = jnp.stack([x["unit_x"].astype(jnp.int32), x["unit_y"].astype(jnp.int32)], axis=-1)
         H, W = x["own_units_spatial"].shape[-2:]
-        smap = scatter_connection(proj, loc, (H, W), "add")
+        smap = scatter_connection(
+            proj, loc, (H, W), "add",
+            impl=static_cfg(self.cfg).encoder.scatter.get("impl", "product"),
+        )
         spatial = jnp.concatenate(
             [
                 smap,

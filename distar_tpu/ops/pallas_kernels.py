@@ -24,8 +24,12 @@ per program does not fit VMEM at the learner's B*T.
 
 Enable via ``attn_impl='pallas'`` on ops.Transformer (model config key
 ``encoder.entity.attention_impl``) and ``impl='pallas_onehot'`` on
-ops.scatter_connection. The defaults stay ``xla`` until an on-chip A/B
-decides them (ROADMAP D2).
+ops.scatter_connection (``encoder.scatter.impl``). Neither is a default:
+attention's is ``xla`` (the kernel lost forward + backward on the chip in PR
+21's smoke), and the scatter connection's is ``product``, the same one-hot
+idea factorised and written in plain ``jax.numpy`` (``ops/scatter.py``; timed
+beside this kernel on the chip in PR 35: PERF.md section 5). ROADMAP D2 keeps
+the list of what a ``simplicity`` PR may delete.
 """
 from __future__ import annotations
 

@@ -149,21 +149,40 @@ def test_scatter_connection_add():
     np.testing.assert_array_equal(out[1, 4, 5], [1, 1, 1])
 
 
+def _drawn_scatter_case(rng, B, N, H, W):
+    """Locations (x, y) with what a frame's entities do to a map: several
+    entities in one cell, cells on all four borders and in the corners,
+    coordinates outside the map (clipped into it), and masked rows (the
+    encoder zeroes their embeddings and leaves their location at cell 0)."""
+    loc = np.stack([rng.integers(-2, W + 3, size=(B, N)), rng.integers(-2, H + 3, size=(B, N))], -1)
+    loc[:, 1] = loc[:, 0]  # a shared cell in every frame
+    loc[:, 2] = loc[:, 0]
+    loc[:, 3:7] = [(0, 1), (W - 1, H - 2), (2, 0), (1, H - 1)]  # left, right, top, bottom
+    loc[:, 7] = (W - 1, H - 1)
+    loc[:, 8] = (W + 5, H + 7)  # clipped onto the same corner
+    masked = np.zeros((B, N), bool)
+    masked[:, N - 3:] = True
+    loc[masked] = 0
+    return loc, masked
+
+
 @pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("f32", "bf16"))
-@pytest.mark.parametrize("B", (1, 5))
-@pytest.mark.parametrize("mode", ("add", "cover"))
-def test_scatter_connection_against_a_numpy_loop(mode, B, dtype):
-    """out[b, y, x] += emb[b, n], with shared cells and out-of-range
-    locations (clipped into the map); forward exactly, and in add mode the
-    gradient of a sum of squares exactly (2 * out gathered at each entity's
-    cell). Embeddings are small integers, so bf16 sums are exact too; in cover
-    mode a cell takes one writer's value and no (b, cell) is shared."""
+@pytest.mark.parametrize("B", (1, 3, 5, 16))
+@pytest.mark.parametrize("mode,impl", (("add", "product"), ("add", "xla"), ("cover", "product")),
+                         ids=("add", "add_xla", "cover"))
+def test_scatter_connection_against_a_numpy_loop(mode, impl, B, dtype):
+    """out[b, y, x] += emb[b, n], with shared cells, border cells, masked
+    rows and out-of-range locations (clipped into the map); forward exactly,
+    and in add mode the gradient of a sum of squares exactly (2 * out gathered
+    at each entity's cell), for the product (the default) and for the plain
+    scatter alike. Embeddings are small integers, so bf16 sums are exact too; in
+    cover mode a cell takes one writer's value and no (b, cell) is shared."""
     N, D, H, W = 12, 3, 5, 6
     rng = np.random.default_rng(7 + B)
     emb = rng.integers(-4, 5, size=(B, N, D)).astype(np.float32)
     if mode == "add":
-        loc = rng.integers(-2, 9, size=(B, N, 2))  # (x, y); 9 > W, H: clipped
-        loc[:, 1] = loc[:, 0]  # a shared cell in every frame
+        loc, masked = _drawn_scatter_case(rng, B, N, H, W)
+        emb[masked] = 0
     else:
         # distinct cells, none the last one: entity 0 is clipped into that
         cells = np.stack([rng.permutation(H * W - 1)[:N] for _ in range(B)])
@@ -178,7 +197,8 @@ def test_scatter_connection_against_a_numpy_loop(mode, B, dtype):
             else:
                 want[b, ys[b, n], xs[b, n]] = emb[b, n]
 
-    fn = lambda e: scatter_connection(e, jnp.asarray(loc), (H, W), mode)
+    kwargs = {} if impl == "product" else {"impl": impl}  # the default is the product
+    fn = lambda e: scatter_connection(e, jnp.asarray(loc), (H, W), mode, **kwargs)
     out = fn(jnp.asarray(emb, dtype))
     assert out.shape == (B, H, W, D) and out.dtype == dtype
     np.testing.assert_array_equal(np.asarray(out, np.float32), want)
@@ -186,6 +206,49 @@ def test_scatter_connection_against_a_numpy_loop(mode, B, dtype):
         grad = jax.grad(lambda e: jnp.sum(fn(e) ** 2))(jnp.asarray(emb, dtype))
         want_grad = 2.0 * want[np.arange(B)[:, None], ys, xs]
         np.testing.assert_array_equal(np.asarray(grad, np.float32), want_grad)
+
+
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("f32", "bf16"))
+@pytest.mark.parametrize("B", (1, 3, 16))
+@pytest.mark.parametrize("geometry", ((5, 6, 3), (4, 160, 32), (7, 24, 16)), ids=lambda g: "x".join(map(str, g)))
+def test_scatter_connection_product_against_the_scatter(geometry, B, dtype):
+    """Drawn real-valued embeddings with a dozen entities in some cells, on
+    maps whose width splits differently. float32: the product and the scatter
+    add the same addends in another order (1e-6). bfloat16: the product is
+    the float32 sum rounded ONCE (one ulp of slack for the order of the float32
+    adds), and so at least as close to it as the scatter, which rounds after
+    every add. The gradient is the same gather of the same cotangent: equal."""
+    H, W, D = geometry
+    N = 40
+    rng = np.random.default_rng(35 + B + W)
+    loc, masked = _drawn_scatter_case(rng, B, N, H, W)
+    loc[:, 20:32] = loc[:, :1]  # twelve more writers of the shared cell
+    emb = rng.standard_normal((B, N, D)).astype(np.float32)
+    emb[masked] = 0
+    emb = np.asarray(jnp.asarray(emb, dtype), np.float32)  # what the dtype holds
+    xs, ys = np.clip(loc[..., 0], 0, W - 1), np.clip(loc[..., 1], 0, H - 1)
+    want = np.zeros((B, H, W, D), np.float64)
+    np.add.at(want, (np.arange(B)[:, None], ys, xs), emb)
+
+    product = lambda e: scatter_connection(e, jnp.asarray(loc), (H, W), "add")
+    scatter = lambda e: scatter_connection(e, jnp.asarray(loc), (H, W), "add", impl="xla")
+    e = jnp.asarray(emb, dtype)
+    got, plain = np.asarray(product(e), np.float64), np.asarray(scatter(e), np.float64)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(got, plain, rtol=1e-6, atol=1e-6)
+    else:
+        once = np.asarray(jnp.asarray(want, jnp.float32).astype(jnp.bfloat16), np.float64)
+        ulp = np.maximum(np.abs(once), 2.0 ** -126) * 2.0 ** -7
+        assert np.all(np.abs(got - once) <= ulp)
+        assert np.abs(got - want).max() <= np.abs(plain - want).max() + 1e-12
+    # the cotangent is drawn, not a function of the map: both rules gather it
+    g = jnp.asarray(rng.standard_normal((B, H, W, D)), dtype)
+    grads = [jax.vjp(f, e)[1](g)[0] for f in (product, scatter)]
+    assert grads[0].dtype == dtype
+    np.testing.assert_array_equal(np.asarray(grads[0], np.float32), np.asarray(grads[1], np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(grads[0], np.float32), np.asarray(g, np.float32)[np.arange(B)[:, None], ys, xs])
 
 
 def test_scatter_connection_cover():

@@ -98,18 +98,18 @@ def test_every_kernel_in_the_module_is_covered():
 
 
 # ------------------------------------------- the scatter connection's layout
-# Why `ops/scatter.py` indexes the map by (y, x, b): its docstring. A change
-# there or in the encoder that brings the compiler's relayout loops or the
-# `dp` all-gathers back fails here, minutes of compile and a chip run before
-# a trace would show it.
+# Why `ops/scatter.py` writes add mode as a product and gathers its gradient by
+# (y, x, b): its docstring. A change there or in the encoder that brings the
+# row-at-a-time scatter, the compiler's relayout loops or the `dp` all-gathers
+# back fails here, minutes of compile and a chip run before a trace would show it.
 PLANES = 24  # what the spatial encoder concatenates beside the 32-channel map
 MAP = (152, 160)
 
 
-def _scatter_into_conv(emb, loc, planes, w):
+def _scatter_into_conv(emb, loc, planes, w, impl="product"):
     from distar_tpu.ops import scatter_connection
 
-    m = scatter_connection(emb, loc, MAP, "add")
+    m = scatter_connection(emb, loc, MAP, "add", impl=impl)
     h = jnp.concatenate([planes, m], axis=-1)
     h = jax.nn.relu(jax.lax.conv_general_dilated(
         h, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")))
@@ -117,11 +117,7 @@ def _scatter_into_conv(emb, loc, planes, w):
     return jnp.sum(h.astype(jnp.float32) ** 2)
 
 
-# frames, mesh axis size, `while(` allowed: 6 x 64 SL frames are three lane
-# tiles of 128; RL's (64 + 1) x 6 = 390 are not, and keep the forward loop
-@pytest.mark.parametrize("B,dp,loops", [(384, 1, 0), (384, 4, 0), (390, 1, 1)],
-                         ids=("b384", "b384_dp4", "b390"))
-def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp, loops):
+def _scatter_into_conv_text(topo, B, dp, impl="product"):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     mesh = Mesh(topo.devices[:dp], ("dp",))
@@ -133,15 +129,63 @@ def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp,
         jax.ShapeDtypeStruct((B * dp, *MAP, PLANES), dt, sharding=rows),
         jax.ShapeDtypeStruct((1, 1, PLANES + D, 32), dt, sharding=repl),
     )
-    text = jax.jit(jax.value_and_grad(_scatter_into_conv, argnums=(0, 3))).lower(
-        *args).compile().as_text()
-    assert "scatter" in text
-    assert text.count(" while(") <= loops
+    fn = functools.partial(_scatter_into_conv, impl=impl)
+    return jax.jit(jax.value_and_grad(fn, argnums=(0, 3))).lower(*args).compile().as_text()
+
+
+# an HLO `scatter` instruction (the scope's name is in every op_name)
+_SCATTER_OP = re.compile(r" = \S+ scatter\(")
+
+
+# frames, mesh axis size: 6 x 64 SL frames are three lane tiles of 128; RL's
+# (64 + 1) x 6 = 390 and an actor's 16 envs are not, and kept the scatter's
+# forward relayout loop until the forward pass became a product (PR 35)
+@pytest.mark.parametrize("B,dp", [(384, 1), (384, 4), (390, 1), (16, 1)],
+                         ids=("b384", "b384_dp4", "b390", "b16"))
+def test_scatter_connection_reaches_the_conv_without_relayout_loops(topo, B, dp):
+    text = _scatter_into_conv_text(topo, B, dp)
+    # forward: a product on the MXU, no row written one at a time; backward:
+    # the rows gathered in place from what the convolution's backward writes
+    assert not _SCATTER_OP.search(text)
+    assert " gather(" in text and "convolution(" in text
+    assert " while(" not in text
+    assert f"[{B * MAP[0] * MAP[1]},{D}]" not in text  # the scatter's `[rows, D]` operand
+    # the product's left one-hot (c = 4 at D = 32: `ops/scatter.py::_group`) is
+    # built inside the product's fusion; no instruction of the program writes it
+    entry = text[text.index("\nENTRY "):]
+    assert f"[{B},{N},{MAP[0] * MAP[1] // 4}]" not in entry and f"[{B},{N},{MAP[1]},{D}]" not in entry
     if dp > 1:
-        # frame b writes rows of frame b only: nothing but the weight's
-        # gradient and the scalar crosses chips
-        assert "all-gather" not in text
+        # frame b is a batch element of the product and gathers rows of frame
+        # b only: nothing but the weight's gradient and the scalar crosses chips
+        assert "all-gather" not in text and "collective-permute" not in text
         assert f"[{B * dp * N},{D}]" not in text  # the global batch's entities
+
+
+def test_the_plain_scatter_is_still_what_impl_xla_compiles(topo):
+    """What the benchmark's reference configs name: one `scatter` over the
+    map's rows, and (B = 390 is not a multiple of 128) its relayout loop."""
+    text = _scatter_into_conv_text(topo, 390, 1, impl="xla")
+    assert len(_SCATTER_OP.findall(text)) == 1
+    assert text.count(" while(") == 1
+
+
+@pytest.mark.parametrize("impl,form", [("product", "product"), ("xla", "scatter")])
+def test_a_traced_scatter_connection_counts_its_form(impl, form):
+    """`distar_scatter_connection_traced_total{form, mode}` moves once a trace
+    of the call, under the label of the form the forward pass took."""
+    from distar_tpu.obs import get_registry
+    from distar_tpu.ops import scatter_connection
+
+    name = "distar_scatter_connection_traced_total"
+    read = lambda: {k: v for k, v in get_registry().snapshot().items() if k.startswith(name)}
+    before = read()
+    kwargs = {} if impl == "product" else {"impl": impl}  # the default is the product
+    jax.eval_shape(lambda e, l: scatter_connection(e, l, (5, 6), "add", **kwargs),
+                   jax.ShapeDtypeStruct((2, 7, 3), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((2, 7, 2), jnp.int32))
+    after = read()
+    moved = {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+    assert moved == {f"{name}{{form={form},mode=add}}": 1}
 
 
 # ------------------------------------------------------ the entity embedding
