@@ -27,7 +27,9 @@ buffer (``copy_rows``), the grouped products (``group_sizes``: they skip the
 empty tail anyway), the masks, and the weighted sum, in which each buffer row
 carries the weight of the pick it serves and is added to its position
 (``add_rows``). So all of it costs what the load holds, and a router that
-sends the bound gets the whole buffer at the whole buffer's cost. Only
+sends the bound gets the whole buffer at the whole buffer's cost (a buffer
+of more than ``WHOLE_AT_ONCE_UP_TO`` chunks two chunks at a time, so that
+the bound's program holds the two-chunk program's temporaries). Only
 scalars are indexed by pick (a weight a pick and its gradient, through
 ``Dispatch.slot``). ``buffer_rows`` reports the rows walked. ``overflow``
 counts the rows routed here that the buffer did not take: 0 by construction,
@@ -62,16 +64,32 @@ from .sequence import RMSNorm
 
 Dtype = Any
 
-# megablox tiles (rows, contraction, columns) for a few thousand rows an
-# expert and widths of 1536-2048
+# megablox tiles (rows, contraction, columns). The row tile follows the rows an
+# expert can expect, which a program knows as its buffer's rows over the experts
+# held: a tile that a group's rows do not fill is computed whole, once for each
+# group that touches it, so a few thousand rows an expert (LFM2, ``nemotron_h``,
+# Kimi-VL: 2,048-4,096 buffer rows a held expert in the first chunk's program)
+# take 512-row tiles and a few hundred (``qwen3_next``: 32 held experts, 512)
+# take ``GMM_FEW_ROWS_TILE``. Contraction and columns are cut to the widths.
 GMM_TILING = (512, 1024, 1024)
+GMM_FEW_ROWS = 1024      # buffer rows a held expert below which the row tile is the smaller one
+GMM_FEW_ROWS_TILE = 128
 
 
-def route(logits, bias, top_k: int, scaling: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sigmoid router with a selection bias: ``s = sigmoid(logits)`` in
-    float32, ``sel = top_k(s + bias)``, ``w_e = s_e / (sum_{sel} s + 1e-6)``
-    times ``scaling``. The bias moves the selection only, not the weights.
-    ``logits`` [N, E] -> ``sel`` [N, k] int32, ``w`` [N, k] float32."""
+def route(logits, bias, top_k: int, scaling: float = 1.0,
+          scoring: str = "sigmoid") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``logits`` [N, E] -> ``sel`` [N, k] int32, ``w`` [N, k] float32, by the
+    router's ``scoring``. ``sigmoid`` (LFM2, ``nemotron_h``, ``deepseek_v3``):
+    ``s = sigmoid(logits)`` in float32, ``sel = top_k(s + bias)``, ``w_e = s_e
+    / (sum_{sel} s + 1e-6)`` times ``scaling``; the bias moves the selection
+    only, not the weights. ``softmax`` (``qwen3_next``): ``p =
+    softmax(logits)`` over all ``E`` in float32, ``sel = top_k(p)``, ``w_e =
+    p_e / sum_{sel} p`` times ``scaling``; ``bias`` is read by nothing."""
+    if scoring == "softmax":
+        w, sel = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), top_k)
+        return sel, scaling * w / w.sum(-1, keepdims=True)
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, sel = jax.lax.top_k(s + bias, top_k)
     w = jnp.take_along_axis(s, sel, axis=-1)
@@ -150,8 +168,9 @@ def megablox(x, w, group_sizes, interpret: bool = False):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     R, a, b = x.shape[0], x.shape[1], w.shape[2]
+    rows = GMM_TILING[0] if R // w.shape[0] >= GMM_FEW_ROWS else GMM_FEW_ROWS_TILE
     # the kernel wants whole row tiles: a buffer of 3 x 256 rows gets tiles of 256
-    tiling = (math.gcd(GMM_TILING[0], R), min(GMM_TILING[1], a), min(GMM_TILING[2], b))
+    tiling = (math.gcd(rows, R), min(GMM_TILING[1], a), min(GMM_TILING[2], b))
     return gmm(x, w, group_sizes, x.dtype, tiling, None, None, False, interpret)
 
 
@@ -183,19 +202,53 @@ def shared_expert(body: str, u, ws):
         return EXPERT_BODIES[body][0](jnp.dot, u, *ws)
 
 
-def _buffer(body: str, length: int, u, w, ws, plan: Dispatch):
-    """``FF`` [N, d] float32 from the first ``length`` rows of the buffer,
-    which hold every row present: the rows of ``u`` copied in, the body's
-    grouped products, and each row times its pick's weight added to its
-    position."""
-    token, slot = plan.token[:length], plan.slot[:length]
+def shared_gate(u, w_gate):
+    """``sigmoid(u . w_gate)`` [N, 1] float32: how far a position opens the shared expert (``qwen3_next``)."""
+    with jax.named_scope("moe_shared"):
+        return jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), w_gate, precision=jax.lax.Precision.HIGHEST))[:, None]
+
+
+def _rows(body: str, u, w, ws, token, slot, group_sizes):
+    """``FF`` [N, d] float32 from the buffer rows ``token``/``slot``, of
+    which each held expert has ``group_sizes`` in a row: the rows of ``u``
+    copied in, the body's grouped products, and each row times its pick's
+    weight added to its position."""
     present = slot < w.size
     with jax.named_scope("moe_dispatch"):
         xs = copy_rows(u, token, present)
     with jax.named_scope("moe_experts"):
-        out = EXPERT_BODIES[body][0](lambda x, m: grouped_matmul(x, m, plan.group_sizes), xs, *ws)
+        out = EXPERT_BODIES[body][0](lambda x, m: grouped_matmul(x, m, group_sizes), xs, *ws)
     with jax.named_scope("moe_combine"):
         return add_rows(jnp.where(present[:, None], out, 0), w, token, slot)
+
+
+# a buffer of more chunks than this is walked two chunks at a time: the rows of ``d`` numbers that one
+# pass over 10 chunks of 16,384 rows keeps (copied in, out of the products, weighted in float32, and
+# their cotangents) are 5 GB, and the program of the provable bound sets the step's memory whether
+# or not a load ever asks for it (PERF.md section 6, PR 36)
+WHOLE_AT_ONCE_UP_TO = 6
+
+
+def _buffer(body: str, length: int, u, w, ws, plan: Dispatch):
+    """``FF`` [N, d] float32 from the first ``length`` rows of the buffer,
+    which hold every row present."""
+    N = u.shape[0]
+    chunks = length // N
+    if chunks <= WHOLE_AT_ONCE_UP_TO:
+        return _rows(body, u, w, ws, plan.token[:length], plan.slot[:length], plan.group_sizes)
+    piece = N * (1 if chunks % 2 else 2)
+    ends = jnp.cumsum(plan.group_sizes)
+
+    @jax.checkpoint
+    def one(lo, u, w, ws):
+        # the rows of each held expert that lie in [lo, lo + piece)
+        sizes = jnp.diff(jnp.clip(ends, lo, lo + piece), prepend=lo).astype(jnp.int32)
+        take = lambda t: jax.lax.dynamic_slice_in_dim(t, lo, piece)
+        return _rows(body, u, w, ws, take(plan.token), take(plan.slot), sizes)
+
+    ff, _ = jax.lax.scan(lambda ff, lo: (ff + one(lo, u, w, ws), None), jnp.zeros((N, u.shape[1]), jnp.float32),
+                         jnp.arange(0, length, piece, dtype=jnp.int32))
+    return ff
 
 
 def _lengths(N: int, plan: Dispatch):
@@ -253,7 +306,10 @@ class ExpertsHeldMoE(nn.Module):
     Parameters: ``norm`` (the layer's feed-forward RMSNorm), ``router``
     [d, num_experts], the body's matrices (``w1``/``w3`` [count, d, width],
     ``w2`` [count, width, d]; ``relu2`` has no ``w3``) and, with
-    ``shared_width``, ``shared`` (the same names, [d, shared_width] and back).
+    ``shared_width``, ``shared`` (the same names, [d, shared_width] and back)
+    and, with ``gated_shared``, ``shared_gate`` [d] (the shared expert's output
+    times ``sigmoid(u . shared_gate)``). ``scoring`` is the router's (``route``);
+    ``softmax`` reads no bias. ``zero_centred``: the layer's norm is ``1 + w``.
     ``expert_bias`` [num_experts] is a buffer (collection ``buffers``): drawn
     at init, never trained. Returns ``(FF(RMSNorm(u)), stats)`` with ``stats``
     the ``rows`` routed to each held expert, the ``overflow``, the
@@ -271,6 +327,9 @@ class ExpertsHeldMoE(nn.Module):
     dtype: Dtype = jnp.float32
     body: str = "swiglu"
     shared_width: int = 0
+    scoring: str = "sigmoid"
+    gated_shared: bool = False
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -286,10 +345,12 @@ class ExpertsHeldMoE(nn.Module):
             lambda: 0.01 * jax.random.normal(self.make_rng("params"), (self.num_experts,)))
 
         with jax.named_scope("moe_router"):
-            u = RMSNorm(self.eps, name="norm")(x).reshape(N, d)
-            # 64 columns: float32 at full precision costs nothing, and the picks hang on it
+            u = RMSNorm(self.eps, self.zero_centred, name="norm")(x).reshape(N, d)
+            # 64 to 512 columns of the step's thousands: float32 at full precision costs little, and the picks hang on it
             logits = jnp.dot(u.astype(jnp.float32), w_router, precision=jax.lax.Precision.HIGHEST)
-            sel, w = route(logits, bias.value if self.use_bias else 0.0, self.top_k, self.scaling)
+            # the sigmoid path calls ``route`` with the four arguments it always had
+            scoring = {} if self.scoring == "sigmoid" else {"scoring": self.scoring}
+            sel, w = route(logits, bias.value if self.use_bias else 0.0, self.top_k, self.scaling, **scoring)
         with jax.named_scope("moe_dispatch"):
             plan = dispatch(sel, self.offset, self.count)
         with jax.named_scope("moe_experts"):
@@ -299,7 +360,10 @@ class ExpertsHeldMoE(nn.Module):
             shared = [self.param(f"shared_{n}", init, shape(n, self.shared_width), jnp.float32) for n in names]
             with jax.named_scope("moe_shared"):
                 shared = [p.astype(self.dtype) for p in shared]
-            y = y + shared_expert(self.body, u, shared).astype(jnp.float32)
+            from_shared = shared_expert(self.body, u, shared).astype(jnp.float32)
+            if self.gated_shared:
+                from_shared = from_shared * shared_gate(u, self.param("shared_gate", init, (d,), jnp.float32))
+            y = y + from_shared
         with jax.named_scope("moe_combine"):
             y = y.astype(x.dtype).reshape(B, S, d)
         return y, {"rows": plan.rows, "overflow": plan.overflow, "buffer_rows": N * plan.chunks,

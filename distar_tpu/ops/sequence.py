@@ -3,8 +3,8 @@ depthwise causal convolution, the gated short convolution, causal
 grouped-query attention and multi-head latent attention.
 
 These are the operators the token models share (``model/lfm2.py``,
-``model/nemotron_h.py``, ``model/deepseek_v3.py``; the state-space mixer is
-``ops/ssm.py``). Parameters
+``model/nemotron_h.py``, ``model/deepseek_v3.py``, ``model/qwen3_next.py``; the
+state-space mixer is ``ops/ssm.py``, the gated delta rule ``ops/delta.py``). Parameters
 are float32; ``dtype`` is the compute dtype of the matrix products. No
 projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
 
@@ -16,14 +16,16 @@ kernels, each with an online softmax, the blocks above the diagonal skipped
 and backward kernels of its own: where keys and values have one head size,
 flash attention (``jax.experimental.pallas.ops.tpu.flash_attention``; it
 refuses a value head of another size and has no interpret mode); where the
-value head has a size of its own (latent attention: 192 against 128), splash
-attention (``...tpu.splash_attention``), which carries one. Anywhere else a
+value head has a size of its own (latent attention: 192 against 128) or the
+head is 256 wide or wider (``qwen3_next``: the chip measured it ahead there),
+splash attention (``...tpu.splash_attention``). Anywhere else a
 loop over query blocks under ``jax.checkpoint`` (every block multiplies
 against all keys and masks, so it does twice the causal work).
 """
 from __future__ import annotations
 
-from typing import Any
+import functools
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,16 +43,28 @@ FLASH_MIN_BLOCK = 128  # the kernels' tiles are multiples of this many positions
 # beside dK and dV in one pass over the scores): 26.7 ms a layer forward and backward against 34.2 at
 # 512 with a dQ kernel of its own; 2,048 does not fit the chip's vector memory (PERF.md section 6, PR 31)
 SPLASH_BLOCK = 1024
+# at head size 256 (16 query heads over 2 key/value heads, 2 x 8,192 positions) splash attention with 512-tiles
+# is ahead of the flash kernel with 512-tiles, 34.6 ms against 43.5 forward and backward (flash with 256-tiles
+# 65.2); 1,024-tiles do not fit the chip's vector memory in either backward kernel (PERF.md section 6, PR 36)
+SPLASH_FROM_HEAD = 256
+SPLASH_BLOCK_WIDE_HEAD = 512
 
 
 class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32."""
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32.
+    ``zero_centred`` (``qwen3_next``): ``... * (1 + w)`` with ``w`` zero at
+    init, so that weight decay pulls the layer towards the plain norm and not
+    towards nothing."""
 
     eps: float = 1e-5
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + self.param("w", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(x.dtype)
@@ -74,6 +88,12 @@ def rope(x, theta: float):
     x32 = x.astype(jnp.float32)
     a, b = jnp.split(x32, 2, axis=-1)
     return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
+
+
+def rope_first(x, theta: float, rotary_dim: int):
+    """``rope`` over the first ``rotary_dim`` dimensions of each head (angles
+    ``t * theta^(-2i/rotary_dim)``), the others as they are."""
+    return jnp.concatenate([rope(x[..., :rotary_dim], theta), x[..., rotary_dim:]], axis=-1)
 
 
 def rope_interleaved(x, theta: float):
@@ -168,7 +188,7 @@ def _attention_splash(q, k, v, scale: float):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
     B, S, Hkv, G, D = q.shape
-    b = min(S, SPLASH_BLOCK)
+    b = min(S, SPLASH_BLOCK if D < SPLASH_FROM_HEAD else SPLASH_BLOCK_WIDE_HEAD)
     heads = lambda t: t.reshape(B, S, -1, t.shape[-1]).transpose(0, 2, 1, 3)
     kernel = splash.make_splash_mha(
         masks.MultiHeadMask([masks.CausalMask((S, S))] * (Hkv * G)), head_shards=1, q_seq_shards=1,
@@ -186,10 +206,16 @@ def causal_attention(q, k, v, scale: float):
     [B, S, Hkv, G, Dv]: the value head has a size of its own."""
     if q.shape[1] % FLASH_MIN_BLOCK:
         return _attention_xla(q, k, v, scale)
-    kernel = _attention_flash if v.shape[-1] == q.shape[-1] else _attention_splash
+    kernel = _attention_flash if v.shape[-1] == q.shape[-1] < SPLASH_FROM_HEAD else _attention_splash
     return jax.lax.platform_dependent(
         q, k, v, tpu=lambda *qkv: kernel(*qkv, scale),
         default=lambda *qkv: _attention_xla(*qkv, scale))
+
+
+def open_gate(out, gate):
+    """The heads' outputs times ``sigmoid(gate)``, in float32, and the mean of ``sigmoid(gate)``."""
+    opened = jax.nn.sigmoid(gate.astype(jnp.float32))
+    return (out.astype(jnp.float32) * opened).astype(out.dtype), jnp.mean(opened)
 
 
 class CausalGQAttention(nn.Module):
@@ -198,7 +224,13 @@ class CausalGQAttention(nn.Module):
     (LFM2): RMSNorm over each head of q and k (one learned scale of
     ``head_dim``), then rotary positions. Without (``nemotron_h``: the
     state-space layers carry the order): q and k as projected, and
-    ``rope_theta`` is read by nothing."""
+    ``rope_theta`` is read by nothing. ``qwen3_next``'s gated attention is
+    three more arguments: ``rotary_dim`` (the rotation takes the first that
+    many dimensions of a head and leaves the others), ``zero_centred`` (the
+    q/k norms are ``1 + w``) and ``gate`` (``q_proj`` is twice as wide, each
+    head's ``2 head_dim`` split into q and a gate; the heads' outputs are
+    multiplied by ``sigmoid(gate)`` before ``o_proj``, and the call returns
+    ``(output, the mean of sigmoid(gate))``)."""
 
     heads: int
     kv_heads: int
@@ -207,19 +239,28 @@ class CausalGQAttention(nn.Module):
     eps: float = 1e-5
     dtype: Dtype = jnp.float32
     positions: bool = True
+    rotary_dim: Optional[int] = None
+    zero_centred: bool = False
+    gate: bool = False
 
     @nn.compact
     def __call__(self, u):
         B, S, d = u.shape
         H, Hkv, D = self.heads, self.kv_heads, self.head_dim
-        q = dense(H * D, self.dtype, "q_proj")(u).reshape(B, S, H, D)
+        q = dense(H * D * (2 if self.gate else 1), self.dtype, "q_proj")(u).reshape(B, S, H, -1)
+        if self.gate:
+            q, gate = jnp.split(q, 2, axis=-1)
         k = dense(Hkv * D, self.dtype, "k_proj")(u).reshape(B, S, Hkv, D)
         v = dense(Hkv * D, self.dtype, "v_proj")(u).reshape(B, S, Hkv, D)
         if self.positions:
-            q = rope(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta)
-            k = rope(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta)
+            turn = rope if self.rotary_dim is None else functools.partial(rope_first, rotary_dim=self.rotary_dim)
+            q = turn(RMSNorm(self.eps, self.zero_centred, name="q_norm")(q), self.rope_theta)
+            k = turn(RMSNorm(self.eps, self.zero_centred, name="k_norm")(k), self.rope_theta)
         out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5)
-        return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
+        if not self.gate:
+            return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
+        out, opened = open_gate(out.reshape(B, S, H, D), gate)
+        return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D)), opened
 
 
 class LatentAttention(nn.Module):

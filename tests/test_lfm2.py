@@ -439,9 +439,25 @@ def _tiny_deepseek_v3(B=2, S=32):
 
 
 # the names of ``LM_STEP_SCOPES`` that each model's layers have, beside those every token model has
+def _tiny_qwen3_next(B=2, S=32):
+    from distar_tpu.model import Qwen3Next, default_qwen3_next_config
+    from distar_tpu.utils import deep_merge_dicts
+
+    cfg = deep_merge_dicts(default_qwen3_next_config(), {
+        "hidden_size": 64, "num_hidden_layers": 2, "full_attention_interval": 2, "linear_num_key_heads": 2,
+        "linear_key_head_dim": 8, "linear_num_value_heads": 4, "linear_value_head_dim": 8, "gdn_chunk_size": 8,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 24, "shared_expert_intermediate_size": 24,
+        "experts_held": {"offset": 2, "count": 4}, "vocab_size": 128})
+    model = Qwen3Next(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 128)
+    return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
+
+
 HAS = {"lfm2": {"short_conv", "attention", "dense_mlp"},
        "nemotron_h": {"ssm_proj", "ssm_scan", "attention", "moe_shared"},
-       "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"}}
+       "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"},
+       "qwen3_next": {"gdn_proj", "gdn_scan", "attention", "moe_shared"}}
 
 
 @pytest.mark.parametrize("which", HAS)
@@ -457,7 +473,8 @@ def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
     if which == "lfm2":
         _, model, variables, tokens, labels = build()
     else:
-        model, variables, tokens, labels = {"nemotron_h": _tiny_nemotron_h, "deepseek_v3": _tiny_deepseek_v3}[which]()
+        model, variables, tokens, labels = {"nemotron_h": _tiny_nemotron_h, "deepseek_v3": _tiny_deepseek_v3,
+                                            "qwen3_next": _tiny_qwen3_next}[which]()
     optimizer = optax.adam(1e-3)
     step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
     text = step.lower(variables, optimizer.init(variables["params"]),

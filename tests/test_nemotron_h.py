@@ -260,7 +260,7 @@ def test_a_buffer_has_a_program_for_one_chunk_two_and_the_whole_and_runs_the_fir
 
 
 def test_the_learner_finds_both_models_by_their_published_model_type():
-    assert set(TOKEN_MODELS) == {"lfm2_moe", "nemotron_h", "deepseek_v3"}
+    assert set(TOKEN_MODELS) >= {"lfm2_moe", "nemotron_h", "deepseek_v3"}   # a superset: later models only add
     assert TOKEN_MODELS["nemotron_h"][0] is NemotronH
     assert NemotronH.moe_layers({"hybrid_override_pattern": "MEMEM*EME"}) == [1, 3, 6, 8]
     assert TOKEN_MODELS["lfm2_moe"][0].moe_layers({"num_dense_layers": 1, "layer_types": ["conv"] * 5}) == [1, 2, 3, 4]

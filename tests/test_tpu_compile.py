@@ -253,7 +253,9 @@ def test_entity_embedding_compiles_to_products_that_move_no_rows(one_chip):
 # 2048 x 1536, three matrices, over a buffer of 32,768 rows; 32 heads of 64 over 4 x 8,192
 # positions. nemotron_h: 8 held experts of 2688 x 1856, two matrices (neither width a
 # multiple of the tiles), over a buffer of 16,384 rows; 32 heads of 128 over 2 x 8,192.
-EXPERTS = {"lfm2_swiglu": ("swiglu", 32768, 2048, 1536), "nemotron_h_relu2": ("relu2", 16384, 2688, 1856)}
+# qwen3_next: 32 held experts of 2048 x 512 over a buffer of 16,384 rows (512 a held expert: the smaller row tile)
+EXPERTS = {"lfm2_swiglu": ("swiglu", 32768, 2048, 1536, 8), "nemotron_h_relu2": ("relu2", 16384, 2688, 1856, 8),
+           "qwen3_next_swiglu": ("swiglu", 16384, 2048, 512, 32)}
 
 
 @pytest.mark.parametrize("which", EXPERTS)
@@ -262,8 +264,8 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, grad, which):
     from distar_tpu.ops import moe
 
     # the backend here is the CPU, the target is not: the product is chosen by what is compiled for
-    body, rows, d, width = EXPERTS[which]
-    experts, (fn_body, names) = 8, moe.EXPERT_BODIES[body]
+    body, rows, d, width, experts = EXPERTS[which]
+    fn_body, names = moe.EXPERT_BODIES[body]
     x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
     ws = [jax.ShapeDtypeStruct((experts, width, d) if n == "w2" else (experts, d, width), jnp.bfloat16,
                                sharding=one_chip) for n in names]
@@ -278,7 +280,8 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, grad, which):
     assert compiled.as_text().count("tpu_custom_call") >= (3 * n if grad else n)
 
 
-@pytest.mark.parametrize("shape", [(4, 8, 4, 64), (2, 2, 16, 128)], ids=("lfm2_32x64", "nemotron_h_32x128_over_2"))
+@pytest.mark.parametrize("shape", [(4, 8, 4, 64), (2, 2, 16, 128), (2, 2, 8, 256)],
+                         ids=("lfm2_32x64", "nemotron_h_32x128_over_2", "qwen3_next_16x256_over_2_by_splash"))
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
 def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad, shape):
     from distar_tpu.ops.sequence import causal_attention
@@ -289,6 +292,8 @@ def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad, shape)
     fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, Dh ** -0.5).astype(jnp.float32) ** 2)
     compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # a head of 256 goes to splash attention with 512-tiles (the chip measured it ahead there), narrower ones to flash
+    assert ("splash" in compiled.as_text()) == (Dh >= 256)
     # no S x S score tensor is held (8.6 GB a sequence in float32): the temporaries are the
     # library's row statistics, which its backward pass broadcasts to the key block's width
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
@@ -329,3 +334,21 @@ def test_chunked_scan_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(on
     fn = lambda *a: jnp.sum(chunked_scan(*a, 128, jnp.bfloat16)[0] ** 2)
     compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2, 3, 4)) if grad else fn).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_chunked_delta_rule_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(one_chip, grad):
+    """``ops.delta.chunked_delta_rule`` at qwen3_next's widths over 2 x 8,192 positions: the
+    ``64 x 64`` matrices, ``U``, ``W`` and every chunk's starting state of all 32 value heads at
+    once are 1.5 GB in float32 and as much again for each gradient; a group of 8 at a time,
+    recomputed in its backward pass, the whole rule's temporaries (the float32 output of all
+    heads and its cotangent among them) stay under three gigabytes."""
+    from distar_tpu.ops.delta import chunked_delta_rule
+
+    b, S, Hk, H, K, V = 2, 8192, 16, 32, 128, 128
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((b, S, Hk, K)), spec((b, S, Hk, K)), spec((b, S, H, V)), spec((b, S, H), jnp.float32),
+            spec((b, S, H), jnp.float32))
+    fn = lambda *a: jnp.sum(chunked_delta_rule(*a, 64, jnp.bfloat16)[0] ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2, 3, 4)) if grad else fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9

@@ -18,6 +18,7 @@ Programs (shapes, dtype and batch from the committed configs that
          builds it) of ``configs/lfm2_24b_a2b_v5e.yaml``
   nh     the same step of ``configs/nemotron_twotower_30b_a3b_v5e.yaml``
   kimi   the same step of ``configs/kimi_vl_a3b_v5e.yaml``
+  qwen   the same step of ``configs/qwen3_next_80b_a3b_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -47,7 +48,8 @@ RL_CONFIG = os.path.join(REPO, "configs", "rl_flagship_v5e.yaml")
 # the token-sequence steps (``check_lm``), by ``--what``
 LM_CONFIGS = {"lm": os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml"),
               "nh": os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml"),
-              "kimi": os.path.join(REPO, "configs", "kimi_vl_a3b_v5e.yaml")}
+              "kimi": os.path.join(REPO, "configs", "kimi_vl_a3b_v5e.yaml"),
+              "qwen": os.path.join(REPO, "configs", "qwen3_next_80b_a3b_v5e.yaml")}
 
 
 def _specs(tree, sharding):
@@ -341,7 +343,7 @@ def main() -> None:
         elif what in LM_CONFIGS:
             check_lm(topo, read_config(LM_CONFIGS[what]), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi, qwen)")
 
 
 if __name__ == "__main__":
