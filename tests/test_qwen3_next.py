@@ -103,6 +103,104 @@ def test_chunked_delta_rule_is_the_literal_recurrence_forward_and_backward(case)
     assert o.shape == args[2].shape and last.shape == (2, 4, 8, 6) and float(jnp.abs(want_last).max()) > 0
 
 
+# the same cases at shapes the kernel's tiles take: heads of 128, chunks of whole 16-blocks (64 as published, and 16
+# where the case's length is a few dozen positions, so that it still crosses chunk boundaries); the two cases that
+# differ only in ``groups`` are the XLA form's (the kernel reads no ``groups``), two longer ones cross grid steps
+KERNEL_CASES = {**{name: dict(kw, chunk=64 if kw["chunk"] == 64 else 16) for name, kw in RULE_CASES.items()
+                   if "groups" not in kw},
+                "three_grid_steps_of_chunks_of_16": dict(S=130, chunk=16),
+                "two_grid_steps_of_chunks_of_64": dict(S=300, chunk=64, decay=-4.0),
+                "chunks_of_three_blocks": dict(S=100, chunk=48)}     # the inverse merges blocks (0, 1), then 2 alone
+
+
+@pytest.fixture
+def executables_dropped():
+    """The interpreted kernels are long programs, and every one XLA:CPU loads holds a few thousand memory mappings
+    until its ``jit`` is dropped: the dozen of them took this file's process to the kernel's limit of 65,530
+    (``vm.max_map_count``) and a later test's compile died of it."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernel_in_interpret_mode_is_the_literal_recurrence_forward_and_backward(case, executables_dropped):
+    """``ops.delta._rule_kernel`` (the forward and the backward Pallas kernel, interpreted on the CPU) on two
+    value heads a key head: ``o``, the last state and all five gradients, to the tolerances the XLA form is held to."""
+    kw = dict(KERNEL_CASES[case])
+    S, chunk = kw.pop("S"), kw.pop("chunk")
+    args = rule_inputs(S, K=128, V=128, **kw)
+    ours = lambda *a: delta._rule_kernel(*a, chunk, True)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+    score = lambda fn: lambda *a: (lambda o, last: (o * weight).sum() + jnp.square(last).sum())(*fn(*a))
+    with jax.default_matmul_precision("highest"):     # under jit: eagerly the interpreter dispatches a kernel op by op
+        (o, last), (want_o, want_last) = jax.jit(ours)(*args), literal(*args)
+        np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=1e-4)
+        np.testing.assert_allclose(last, want_last, atol=2e-5, rtol=1e-4)
+        got = jax.jit(jax.grad(score(ours), argnums=range(5)))(*args)
+        want = jax.grad(score(literal), argnums=range(5))(*args)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.abs(b).max()) + 1e-9, rtol=0, err_msg=name)
+    assert o.shape == args[2].shape and last.shape == (2, 4, 128, 128) and float(jnp.abs(want_last).max()) > 0
+
+
+def test_the_kernel_keeps_its_operands_dtype_and_pads_to_whole_grid_steps(executables_dropped):
+    """bfloat16 operands: the products round where the XLA form's do, so the two agree to about bfloat16's own
+    step (2^-8: a sum taken in another order moves a rounding by one), outputs float32, gradients in the operands'
+    dtypes; 70 positions are padded to one grid step."""
+    bf = jnp.bfloat16
+    q, k, v, g, beta = rule_inputs(70, K=128, V=128)
+    args = (q.astype(bf), k.astype(bf), v.astype(bf), g, beta)
+    score = lambda fn: lambda *a: jnp.square(fn(*a)[0]).sum() + jnp.square(fn(*a)[1]).sum()
+    kernel, xla = lambda *a: delta._rule_kernel(*a, 64, True), lambda *a: delta._rule_xla(*a, 64, bf, 2)
+    (o, last), (want_o, want_last) = jax.jit(kernel)(*args), xla(*args)
+    assert o.dtype == last.dtype == jnp.float32 and o.shape == (2, 70, 4, 128)
+    np.testing.assert_allclose(o, want_o, atol=1e-2 * float(jnp.abs(want_o).max()))
+    np.testing.assert_allclose(last, want_last, atol=1e-2 * float(jnp.abs(want_last).max()))
+    got, want = (jax.jit(jax.grad(score(fn), argnums=range(5)))(*args) for fn in (kernel, xla))
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_allclose(a.astype(jnp.float32), b.astype(jnp.float32),
+                                   atol=3e-2 * float(jnp.abs(b.astype(jnp.float32)).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("refused", ("key_head_of_8", "value_head_of_6", "chunk_of_8", "chunk_of_128"))
+def test_a_shape_the_kernels_tiles_refuse_takes_the_xla_form_and_agrees(refused, monkeypatch):
+    shape = dict(K=128, V=128, chunk=16)
+    shape.update({"key_head_of_8": dict(K=8), "value_head_of_6": dict(V=6), "chunk_of_8": dict(chunk=8),
+                  "chunk_of_128": dict(chunk=128)}[refused])
+    chunk = shape.pop("chunk")
+    assert not delta.kernel_takes(shape["K"], shape["V"], chunk) and delta.kernel_takes(128, 256, 64)
+
+    def never(*a):
+        raise AssertionError("the kernel was asked")
+
+    monkeypatch.setattr(delta, "_rule_kernel", never)
+    args = rule_inputs(24, **shape)
+    with jax.default_matmul_precision("highest"):
+        (o, last), (want_o, want_last) = delta.chunked_delta_rule(*args, chunk), literal(*args)
+    np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(last, want_last, atol=2e-5, rtol=1e-4)
+    # and a shape the tiles take does ask it: under jit the branch for a TPU is traced whatever the platform
+    with pytest.raises(AssertionError, match="the kernel was asked"):
+        jax.jit(lambda *a: delta.chunked_delta_rule(*a, 16))(*rule_inputs(24, K=128, V=128))
+
+
+def test_off_the_tpu_a_shape_the_tiles_take_runs_the_xla_form_under_both_branches():
+    """``jax.lax.platform_dependent`` traces the kernel's branch and the XLA form's and lowers, here, the second:
+    value and gradients through the pair are the literal recurrence's, and no ``pallas_call`` is interpreted."""
+    args = rule_inputs(40, K=128, V=128)
+    ours = lambda *a: delta.chunked_delta_rule(*a, 16)
+    score = lambda fn: lambda *a: jnp.square(fn(*a)[0]).sum() + jnp.square(fn(*a)[1]).sum()
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(jax.jit(ours)(*args)[0], literal(*args)[0], atol=2e-5, rtol=1e-4)
+        got = jax.jit(jax.grad(score(ours), argnums=range(5)))(*args)
+        want = jax.grad(score(literal), argnums=range(5))(*args)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.abs(b).max()) + 1e-9, rtol=0, err_msg=name)
+    lowered = jax.jit(ours).lower(*args).as_text()
+    assert "tpu_custom_call" not in lowered and "while" in lowered     # the XLA form's loop over groups of heads
+
+
 def test_the_carry_across_a_chunk_boundary_is_what_the_later_chunks_read():
     """A rule that starts every chunk from nothing agrees with the recurrence
     up to the first boundary and nowhere after it."""

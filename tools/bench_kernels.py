@@ -1,5 +1,8 @@
 """Pallas-vs-XLA kernel check and microbench for the two flagship kernels
-(SURVEY.md §2.3 scatter_connection, §5 entity masked attention).
+(SURVEY.md §2.3 scatter_connection, §5 entity masked attention), and for the
+token model's gated delta rule (``ops/delta.py``: one Gated DeltaNet layer's
+rule at ``qwen3_next_train_b2s8k``'s shape under ``jax.checkpoint``, its two
+Pallas kernels beside the XLA form: PR 37's fragment).
 
 Runs each kernel at actor-inference and learner-training shapes, in bf16 and
 f32, forward and forward+backward, against its jnp reference: first a
@@ -71,7 +74,7 @@ def run(platform: str = "auto", iters: int = 30) -> dict:
         """Check every impl against ``reference`` (run in f32), then time it."""
         def grad_of(fn):
             return jax.jit(jax.grad(
-                lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+                lambda *a: sum(jnp.sum(x.astype(jnp.float32) ** 2) for x in jax.tree.leaves(fn(*a))),
                 argnums=grad_argnums))
 
         reference = f32(reference)
@@ -134,6 +137,32 @@ def run(platform: str = "auto", iters: int = 30) -> dict:
                 },
                 (emb,), (0,), tol,
             )
+
+    # one Gated DeltaNet layer's rule as qwen3_next_train_b2s8k runs it: 2 x 8,192 positions, 16 key and 32 value
+    # heads of 128, chunks of 64, bf16 products, decays drawn as the layer draws them, under jax.checkpoint as the
+    # decoder layer wraps it (so fwd+bwd holds the rule's replay). "xla" is the form every other platform runs
+    from distar_tpu.ops import delta
+    from distar_tpu.ops.pallas_kernels import resolve_interpret
+
+    b, S, Hk, Hv, K, V = (2, 8192, 16, 32, 128, 128) if native else (1, 80, 1, 2, 128, 128)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    rate = rng.uniform(0.0, 16.0, Hv) * np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), Hv))
+    rule_args = (
+        jnp.asarray(unit(rng.standard_normal((b, S, Hk, K))) * K ** -0.5, jnp.bfloat16),
+        jnp.asarray(unit(rng.standard_normal((b, S, Hk, K))), jnp.bfloat16),
+        jnp.asarray(rng.standard_normal((b, S, Hv, V)), jnp.bfloat16),
+        jnp.asarray(-rate * np.exp(0.9 * rng.standard_normal((b, S, Hv))), jnp.float32),
+        jnp.asarray(1.0 / (1.0 + np.exp(-0.9 * rng.standard_normal((b, S, Hv)))), jnp.float32),
+    )
+    bench(
+        "gated_delta_rule", f"{b}x{S}x{Hk}/{Hv}x{K} bfloat16",
+        lambda *a: delta._rule_xla(*a, 64, jnp.float32, 8),
+        {
+            "xla": jax.checkpoint(lambda *a: delta._rule_xla(*a, 64, jnp.bfloat16, 8)),
+            "pallas": jax.checkpoint(lambda *a: delta._rule_kernel(*a, 64, resolve_interpret(None))),
+        },
+        rule_args, (0, 1, 2, 3, 4), 4e-2,
+    )
 
     dev = jax.devices()[0]
     return {
