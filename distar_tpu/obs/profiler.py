@@ -121,11 +121,14 @@ STEP_SCOPES = (
 
 # The token-sequence learner's step (``lm_train_step``): the models' parts
 # (``model/lfm2.py``, ``model/nemotron_h.py``, ``model/deepseek_v3.py``,
-# ``model/qwen3_next.py``, ``ops/moe.py``, ``ops/ssm.py``, ``ops/delta.py``,
-# ``ops/sequence.py``; a model has the parts its layers have) and the three
-# names every step has. ``mla_core`` is latent attention's kernel alone,
-# ``mla_proj`` everything else of that layer; ``gdn_scan`` is the chunked gated
-# delta rule alone, ``gdn_proj`` everything else of a Gated DeltaNet layer.
+# ``model/qwen3_next.py``, ``model/laguna.py``, ``ops/moe.py``, ``ops/ssm.py``,
+# ``ops/delta.py``, ``ops/sequence.py``; a model has the parts its layers have)
+# and the three names every step has. ``mla_core`` is latent attention's kernel
+# alone, ``mla_proj`` everything else of that layer; ``gdn_scan`` is the chunked
+# gated delta rule alone, ``gdn_proj`` everything else of a Gated DeltaNet layer.
+# ``attn_core`` is grouped-query attention's full-causal kernel alone, ``swa_core``
+# its banded kernel alone, ``attn_proj`` everything else of such a layer: the
+# names of a model whose layers are not under ``attention`` as a whole (``laguna``).
 LM_STEP_SCOPES = (
     "embed", "short_conv", "attention", "dense_mlp",
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head",
@@ -133,6 +136,7 @@ LM_STEP_SCOPES = (
     "ssm_proj", "ssm_scan", "moe_shared",
     "mla_proj", "mla_core",
     "gdn_proj", "gdn_scan",
+    "attn_proj", "attn_core", "swa_core",
 )
 
 SPAN_PREFIX = "distar:"

@@ -3,8 +3,9 @@ depthwise causal convolution, the gated short convolution, causal
 grouped-query attention and multi-head latent attention.
 
 These are the operators the token models share (``model/lfm2.py``,
-``model/nemotron_h.py``, ``model/deepseek_v3.py``, ``model/qwen3_next.py``; the
-state-space mixer is ``ops/ssm.py``, the gated delta rule ``ops/delta.py``). Parameters
+``model/nemotron_h.py``, ``model/deepseek_v3.py``, ``model/qwen3_next.py``,
+``model/laguna.py``; the state-space mixer is ``ops/ssm.py``, the gated delta
+rule ``ops/delta.py``). Parameters
 are float32; ``dtype`` is the compute dtype of the matrix products. No
 projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
 
@@ -21,14 +22,21 @@ head is 256 wide or wider (``qwen3_next``: the chip measured it ahead there),
 splash attention (``...tpu.splash_attention``). Anywhere else a
 loop over query blocks under ``jax.checkpoint`` (every block multiplies
 against all keys and masks, so it does twice the causal work).
+
+With a ``window`` (``laguna``'s sliding layers: query ``i`` sees the keys ``i
+- window < j <= i``) the work is the band's: on a TPU splash attention with a
+local mask, which visits only the key blocks that meet the band, forward and
+backward; anywhere else the same loop over query blocks with the band in its mask.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+import math
+from typing import Any, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 Dtype = Any
@@ -48,6 +56,9 @@ SPLASH_BLOCK = 1024
 # 65.2); 1,024-tiles do not fit the chip's vector memory in either backward kernel (PERF.md section 6, PR 36)
 SPLASH_FROM_HEAD = 256
 SPLASH_BLOCK_WIDE_HEAD = 512
+# splash attention's tiles under a band of 512 keys: a query block of ``b`` positions meets the key blocks
+# that hold its ``b + window - 1`` keys (PERF.md section 5, PR 38)
+BAND_BLOCK = 512
 
 
 class RMSNorm(nn.Module):
@@ -76,24 +87,59 @@ def dense(features: int, dtype: Dtype, name: str) -> nn.Dense:
                     kernel_init=nn.initializers.normal(0.02), name=name)
 
 
+def _turn(x, inv_freq, factor=None):
+    """``x * cos + rotate_half(x) * sin`` with ``rotate_half([a, b]) = [-b, a]``
+    and the angle ``t * inv_freq[i]`` for pair ``i`` (dimension ``i`` with ``i
+    + D/2``); with a ``factor``, cos and sin times it. ``x`` is ``[B, S, H, D]``."""
+    S = x.shape[1]
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]      # [S, D/2]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
+    x32 = x.astype(jnp.float32)
+    a, b = jnp.split(x32, 2, axis=-1)
+    return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
+
+
 def rope(x, theta: float):
     """Rotary positions over the whole head, rotate-half convention:
     ``x * cos + rotate_half(x) * sin`` with ``rotate_half([a, b]) = [-b, a]``
     and angle ``t * theta^(-2i/D)`` for pair ``i``. ``x`` is ``[B, S, H, D]``."""
-    S, D = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]      # [S, D/2]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    a, b = jnp.split(x32, 2, axis=-1)
-    return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
+    D = x.shape[-1]
+    return _turn(x, theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D))
 
 
 def rope_first(x, theta: float, rotary_dim: int):
     """``rope`` over the first ``rotary_dim`` dimensions of each head (angles
     ``t * theta^(-2i/rotary_dim)``), the others as they are."""
     return jnp.concatenate([rope(x[..., :rotary_dim], theta), x[..., rotary_dim:]], axis=-1)
+
+
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float, original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's frequency table [rotary_dim / 2] (numpy, float64): pair ``j`` of
+    ``f_j = theta^(-2j/rotary_dim)`` keeps its frequency where it turns more
+    than ``beta_fast`` times within the original length, takes ``f_j / factor``
+    where it turns less than ``beta_slow`` times, and a linear ramp between:
+    ``f_j (1 - ramp_j) + (f_j / factor) ramp_j`` with ``ramp_j = clip((j - low)
+    / (high - low), 0, 1)``, ``low`` and ``high`` the floor and the ceiling of
+    ``rotary_dim ln(original / (2 pi beta)) / (2 ln theta)`` at ``beta_fast``
+    and ``beta_slow`` (9 and 18 at ``laguna``'s published numbers)."""
+    j = np.arange(rotary_dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * j / rotary_dim)
+    turns = lambda beta: rotary_dim * math.log(original_max_position_embeddings / (2 * math.pi * beta)) / (2 * math.log(theta))
+    low, high = max(math.floor(turns(beta_fast)), 0), min(math.ceil(turns(beta_slow)), rotary_dim - 1)
+    ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + f / factor * ramp
+
+
+def yarn_first(x, rotary_dim: int, theta: float, attention_factor: float, **yarn):
+    """The rotation of the first ``rotary_dim`` dimensions of each head by
+    ``yarn_inv_freq``'s table, cos and sin times ``attention_factor``; the
+    others as they are."""
+    inv_freq = jnp.asarray(yarn_inv_freq(rotary_dim, theta, **yarn), jnp.float32)
+    return jnp.concatenate([_turn(x[..., :rotary_dim], inv_freq, attention_factor), x[..., rotary_dim:]], axis=-1)
 
 
 def rope_interleaved(x, theta: float):
@@ -145,9 +191,10 @@ class ShortConv(nn.Module):
         return dense(d, self.dtype, "out_proj")(gate_c * causal_conv(gate_b * x, kernel))
 
 
-def _attention_xla(q, k, v, scale: float):
+def _attention_xla(q, k, v, scale: float, window: Optional[int] = None):
     """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
-    [B, S, Hkv, G, Dv]. One query block at a time against all keys, float32 softmax."""
+    [B, S, Hkv, G, Dv]. One query block at a time against all keys, float32 softmax;
+    with a ``window`` the mask is the band's (query ``i`` sees the keys ``i - window < j <= i``)."""
     B, S, Hkv, G, _ = q.shape
     block = min(S, XLA_QUERY_BLOCK)
     if S % block:
@@ -158,7 +205,10 @@ def _attention_xla(q, k, v, scale: float):
         qb = jax.lax.dynamic_slice_in_dim(q, start, block, axis=1)
         score = jnp.einsum("bqhgd,bkhd->bhgqk", qb, k,
                            preferred_element_type=jnp.float32) * scale
-        visible = (start + jnp.arange(block))[:, None] >= jnp.arange(S)[None, :]
+        at, key_at = (start + jnp.arange(block))[:, None], jnp.arange(S)[None, :]
+        visible = at >= key_at
+        if window is not None:
+            visible &= at - key_at < window
         prob = jax.nn.softmax(jnp.where(visible, score, -jnp.inf), axis=-1)
         return jnp.einsum("bhgqk,bkhd->bqhgd", prob.astype(v.dtype), v)
 
@@ -183,33 +233,55 @@ def _attention_flash(q, k, v, scale: float):
     return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
 
 
-def _attention_splash(q, k, v, scale: float):
+def band_mask(S: int, window: int):
+    """Splash attention's mask of the band: query ``i`` sees the keys ``i - window < j <= i``
+    (``window - 1`` keys before its own and none after)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    return masks.LocalMask((S, S), (window - 1, 0), 0)
+
+
+def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
     B, S, Hkv, G, D = q.shape
-    b = min(S, SPLASH_BLOCK if D < SPLASH_FROM_HEAD else SPLASH_BLOCK_WIDE_HEAD)
+    if window is None:
+        b, mask = min(S, SPLASH_BLOCK if D < SPLASH_FROM_HEAD else SPLASH_BLOCK_WIDE_HEAD), masks.CausalMask((S, S))
+    else:
+        # the kernel's grid holds the key blocks that meet the band and no others, in all three passes
+        b, mask = min(S, BAND_BLOCK), band_mask(S, window)
     heads = lambda t: t.reshape(B, S, -1, t.shape[-1]).transpose(0, 2, 1, 3)
+    # the fused backward kernel writes dQ once for every key block of the sequence ([S / b, heads, S, D]: 4.8 GB
+    # for 36 heads of 128 at 16,384 positions) and adds them up afterwards, whatever the mask: under a band dQ
+    # gets a kernel of its own, which walks the key blocks a query block meets
+    sizes = (dict(use_fused_bwd_kernel=True) if window is None else
+             dict(use_fused_bwd_kernel=False, block_q_dq=b, block_kv_dq=b))
     kernel = splash.make_splash_mha(
-        masks.MultiHeadMask([masks.CausalMask((S, S))] * (Hkv * G)), head_shards=1, q_seq_shards=1,
+        masks.MultiHeadMask([mask] * (Hkv * G)), head_shards=1, q_seq_shards=1,
         block_sizes=splash.BlockSizes(
             block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b, block_kv_dkv=b,
-            block_kv_dkv_compute=b, use_fused_bwd_kernel=True))
+            block_kv_dkv_compute=b, **sizes))
     # the kernel has no scale of its own, takes one sequence ([heads, S, .]) and shares a
     # key/value head among the query heads of its group itself
     out = jax.vmap(kernel)(heads(q * jnp.asarray(scale, q.dtype)), heads(k), heads(v))
     return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, v.shape[-1])
 
 
-def causal_attention(q, k, v, scale: float):
+def causal_attention(q, k, v, scale: float, window: Optional[int] = None):
     """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
-    [B, S, Hkv, G, Dv]: the value head has a size of its own."""
+    [B, S, Hkv, G, Dv]: the value head has a size of its own. With a
+    ``window``, query ``i`` sees the keys ``i - window < j <= i`` and no others."""
+    if window is not None and window >= q.shape[1]:
+        window = None                                               # the band is the whole triangle
+    plain = functools.partial(_attention_xla, scale=scale, window=window)
     if q.shape[1] % FLASH_MIN_BLOCK:
-        return _attention_xla(q, k, v, scale)
-    kernel = _attention_flash if v.shape[-1] == q.shape[-1] < SPLASH_FROM_HEAD else _attention_splash
-    return jax.lax.platform_dependent(
-        q, k, v, tpu=lambda *qkv: kernel(*qkv, scale),
-        default=lambda *qkv: _attention_xla(*qkv, scale))
+        return plain(q, k, v)
+    if window is not None:
+        kernel = functools.partial(_attention_splash, window=window)
+    else:
+        kernel = _attention_flash if v.shape[-1] == q.shape[-1] < SPLASH_FROM_HEAD else _attention_splash
+    return jax.lax.platform_dependent(q, k, v, tpu=lambda *qkv: kernel(*qkv, scale), default=plain)
 
 
 def open_gate(out, gate):
@@ -230,7 +302,18 @@ class CausalGQAttention(nn.Module):
     q/k norms are ``1 + w``) and ``gate`` (``q_proj`` is twice as wide, each
     head's ``2 head_dim`` split into q and a gate; the heads' outputs are
     multiplied by ``sigmoid(gate)`` before ``o_proj``, and the call returns
-    ``(output, the mean of sigmoid(gate))``)."""
+    ``(output, the mean of sigmoid(gate))``). ``laguna``'s layers are three
+    more: ``window`` (a query sees the ``window`` keys up to its own),
+    ``yarn`` (the rotation's table is ``yarn_inv_freq``'s and cos and sin are
+    scaled: the keys ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow`` and ``attention_factor`` of the published
+    ``rope_parameters``) and ``gate="head"`` (one number a head and position
+    from a projection of its own, ``g_proj`` [d, heads], opens the head's
+    whole output).
+
+    The kernel alone runs under the scope ``attn_core`` (``swa_core`` with a
+    ``window``), everything else under ``attn_proj``; a model that puts the
+    whole layer under ``attention`` is read by that name, the first on the path."""
 
     heads: int
     kv_heads: int
@@ -241,26 +324,40 @@ class CausalGQAttention(nn.Module):
     positions: bool = True
     rotary_dim: Optional[int] = None
     zero_centred: bool = False
-    gate: bool = False
+    gate: Union[bool, str] = False
+    window: Optional[int] = None
+    yarn: Optional[Dict[str, float]] = None
 
     @nn.compact
     def __call__(self, u):
         B, S, d = u.shape
         H, Hkv, D = self.heads, self.kv_heads, self.head_dim
-        q = dense(H * D * (2 if self.gate else 1), self.dtype, "q_proj")(u).reshape(B, S, H, -1)
-        if self.gate:
-            q, gate = jnp.split(q, 2, axis=-1)
-        k = dense(Hkv * D, self.dtype, "k_proj")(u).reshape(B, S, Hkv, D)
-        v = dense(Hkv * D, self.dtype, "v_proj")(u).reshape(B, S, Hkv, D)
-        if self.positions:
-            turn = rope if self.rotary_dim is None else functools.partial(rope_first, rotary_dim=self.rotary_dim)
-            q = turn(RMSNorm(self.eps, self.zero_centred, name="q_norm")(q), self.rope_theta)
-            k = turn(RMSNorm(self.eps, self.zero_centred, name="k_norm")(k), self.rope_theta)
-        out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5)
-        if not self.gate:
-            return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
-        out, opened = open_gate(out.reshape(B, S, H, D), gate)
-        return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D)), opened
+        if self.gate not in (False, True, "head"):
+            raise ValueError(f"gate {self.gate!r}: False, True (an element) or 'head'")
+        with jax.named_scope("attn_proj"):
+            q = dense(H * D * (2 if self.gate is True else 1), self.dtype, "q_proj")(u).reshape(B, S, H, -1)
+            if self.gate is True:
+                q, gate = jnp.split(q, 2, axis=-1)
+            elif self.gate:
+                gate = dense(H, self.dtype, "g_proj")(u)[..., None]
+            k = dense(Hkv * D, self.dtype, "k_proj")(u).reshape(B, S, Hkv, D)
+            v = dense(Hkv * D, self.dtype, "v_proj")(u).reshape(B, S, Hkv, D)
+            if self.positions:
+                if self.yarn is not None:
+                    turn = functools.partial(yarn_first, rotary_dim=self.rotary_dim or D, theta=self.rope_theta, **self.yarn)
+                elif self.rotary_dim is None:
+                    turn = functools.partial(rope, theta=self.rope_theta)
+                else:
+                    turn = functools.partial(rope_first, theta=self.rope_theta, rotary_dim=self.rotary_dim)
+                q = turn(RMSNorm(self.eps, self.zero_centred, name="q_norm")(q))
+                k = turn(RMSNorm(self.eps, self.zero_centred, name="k_norm")(k))
+        with jax.named_scope("attn_core" if self.window is None else "swa_core"):
+            out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5, window=self.window)
+        with jax.named_scope("attn_proj"):
+            if not self.gate:
+                return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
+            out, opened = open_gate(out.reshape(B, S, H, D), gate)
+            return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D)), opened
 
 
 class LatentAttention(nn.Module):

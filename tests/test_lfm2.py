@@ -454,10 +454,29 @@ def _tiny_qwen3_next(B=2, S=32):
     return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
 
 
-HAS = {"lfm2": {"short_conv", "attention", "dense_mlp"},
-       "nemotron_h": {"ssm_proj", "ssm_scan", "attention", "moe_shared"},
+def _tiny_laguna(B=2, S=32):
+    from distar_tpu.model import Laguna, default_laguna_config
+    from distar_tpu.utils import deep_merge_dicts
+
+    cfg = deep_merge_dicts(default_laguna_config(), {
+        "hidden_size": 64, "intermediate_size": 96, "layer_types": ["full_attention", "sliding_attention"],
+        "mlp_layer_types": ["dense", "sparse"], "num_attention_heads_per_layer": [4, 8], "num_key_value_heads": 4,
+        "kv_heads_held": {"count": 2}, "head_dim": 16, "sliding_window": 5, "num_experts": 8,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 24, "shared_expert_intermediate_size": 24,
+        "experts_held": {"offset": 2, "count": 4}, "vocab_size": 128})
+    model = Laguna(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 128)
+    return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
+
+
+# grouped-query attention names its kernel and the rest of the layer itself (``attn_core`` / ``attn_proj``); under a
+# model's ``attention`` they stay on the path and the trace reader gives the operation to ``attention``, the first
+ATTENTION = {"attention", "attn_proj", "attn_core"}
+HAS = {"lfm2": {"short_conv", "dense_mlp"} | ATTENTION,
+       "nemotron_h": {"ssm_proj", "ssm_scan", "moe_shared"} | ATTENTION,
        "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"},
-       "qwen3_next": {"gdn_proj", "gdn_scan", "attention", "moe_shared"}}
+       "qwen3_next": {"gdn_proj", "gdn_scan", "moe_shared"} | ATTENTION,
+       "laguna": {"attn_proj", "attn_core", "swa_core", "dense_mlp", "moe_shared"}}
 
 
 @pytest.mark.parametrize("which", HAS)
@@ -474,7 +493,7 @@ def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
         _, model, variables, tokens, labels = build()
     else:
         model, variables, tokens, labels = {"nemotron_h": _tiny_nemotron_h, "deepseek_v3": _tiny_deepseek_v3,
-                                            "qwen3_next": _tiny_qwen3_next}[which]()
+                                            "qwen3_next": _tiny_qwen3_next, "laguna": _tiny_laguna}[which]()
     optimizer = optax.adam(1e-3)
     step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
     text = step.lower(variables, optimizer.init(variables["params"]),

@@ -356,3 +356,21 @@ def test_chunked_delta_rule_compiles_for_v5e_and_holds_one_group_of_heads_at_a_t
     assert text.count("tpu_custom_call") == (2 if grad else 1)
     assert "while(" not in text and "triangular-solve" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < (1.3e9 if grad else 0.6e9)
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_banded_attention_compiles_for_v5e_at_16k_positions_and_holds_no_dq_a_key_block(one_chip, grad):
+    """``causal_attention`` with a window at ``laguna``'s sliding layer (36 query heads of 128 over
+    4 key/value heads, one sequence of 16,384, window 512): splash attention under a local mask,
+    with a dQ kernel of its own. The fused backward kernel writes dQ once for every key block of
+    the sequence, 4.8 GB here whatever the mask; with its own kernel the temporaries of the three
+    passes stay under a gigabyte and a half."""
+    from distar_tpu.ops.sequence import causal_attention
+
+    q = jax.ShapeDtypeStruct((1, 16384, 4, 9, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one_chip)
+    fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 128 ** -0.5, window=512).astype(jnp.float32) ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert "splash" in text and text.count("tpu_custom_call") >= (3 if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

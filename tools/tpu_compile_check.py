@@ -19,6 +19,7 @@ Programs (shapes, dtype and batch from the committed configs that
   nh     the same step of ``configs/nemotron_twotower_30b_a3b_v5e.yaml``
   kimi   the same step of ``configs/kimi_vl_a3b_v5e.yaml``
   qwen   the same step of ``configs/qwen3_next_80b_a3b_v5e.yaml``
+  laguna the same step of ``configs/laguna_s_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -49,7 +50,8 @@ RL_CONFIG = os.path.join(REPO, "configs", "rl_flagship_v5e.yaml")
 LM_CONFIGS = {"lm": os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml"),
               "nh": os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml"),
               "kimi": os.path.join(REPO, "configs", "kimi_vl_a3b_v5e.yaml"),
-              "qwen": os.path.join(REPO, "configs", "qwen3_next_80b_a3b_v5e.yaml")}
+              "qwen": os.path.join(REPO, "configs", "qwen3_next_80b_a3b_v5e.yaml"),
+              "laguna": os.path.join(REPO, "configs", "laguna_s_v5e.yaml")}
 
 
 def _specs(tree, sharding):
@@ -343,7 +345,7 @@ def main() -> None:
         elif what in LM_CONFIGS:
             check_lm(topo, read_config(LM_CONFIGS[what]), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi, qwen)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi, qwen, laguna)")
 
 
 if __name__ == "__main__":
