@@ -12,19 +12,19 @@ projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
 Attention over ``S`` positions never holds an ``S x S`` score tensor per
 head. Which code computes it follows from the platform the program is being
 compiled for (``jax.lax.platform_dependent``) and the shapes: on a TPU, at a
-sequence length its tiles divide (``FLASH_MIN_BLOCK``), one of JAX's own
-kernels, each with an online softmax, the blocks above the diagonal skipped
-and backward kernels of its own: where keys and values have one head size,
-flash attention (``jax.experimental.pallas.ops.tpu.flash_attention``; it
-refuses a value head of another size and has no interpret mode); where the
-value head has a size of its own (latent attention: 192 against 128) or the
-head is 256 wide or wider (``qwen3_next``: the chip measured it ahead there),
-splash attention (``...tpu.splash_attention``). Anywhere else a
-loop over query blocks under ``jax.checkpoint`` (every block multiplies
-against all keys and masks, so it does twice the causal work).
+sequence length its tiles divide (``KERNEL_MIN_BLOCK``), JAX's splash
+attention (``jax.experimental.pallas.ops.tpu.splash_attention``): an online
+softmax, the blocks above the diagonal skipped, a key/value head shared by
+the query heads of its group, a value head of its own size where the model
+has one (latent attention: 192 against 128), and backward kernels of its
+own. Its tiles, and whether dQ is computed beside dK and dV or by a kernel of
+its own, follow from the sequence length and the head sizes by the table the
+chip measured (``core_plan``). Anywhere else a loop over query blocks under
+``jax.checkpoint`` (every block multiplies against all keys and masks, so it
+does twice the causal work).
 
 With a ``window`` (``laguna``'s sliding layers: query ``i`` sees the keys ``i
-- window < j <= i``) the work is the band's: on a TPU splash attention with a
+- window < j <= i``) the work is the band's: on a TPU the same kernel with a
 local mask, which visits only the key blocks that meet the band, forward and
 backward; anywhere else the same loop over query blocks with the band in its mask.
 """
@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Optional, Union
+from typing import Any, Dict, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -42,23 +42,75 @@ from flax import linen as nn
 Dtype = Any
 
 XLA_QUERY_BLOCK = 512
-# flash attention's tiles at 8k positions: 512 x 512 score tiles keep the
-# MXU fed; the library's default of 128 is a placeholder ("select better
-# parameters", its own TODO)
-FLASH_BLOCK = 512
-FLASH_MIN_BLOCK = 128  # the kernels' tiles are multiples of this many positions
-# splash attention's tiles at 8k positions and head sizes 192/128, with its fused backward kernel (dQ
-# beside dK and dV in one pass over the scores): 26.7 ms a layer forward and backward against 34.2 at
-# 512 with a dQ kernel of its own; 2,048 does not fit the chip's vector memory (PERF.md section 6, PR 31)
-SPLASH_BLOCK = 1024
-# at head size 256 (16 query heads over 2 key/value heads, 2 x 8,192 positions) splash attention with 512-tiles
-# is ahead of the flash kernel with 512-tiles, 34.6 ms against 43.5 forward and backward (flash with 256-tiles
-# 65.2); 1,024-tiles do not fit the chip's vector memory in either backward kernel (PERF.md section 6, PR 36)
-SPLASH_FROM_HEAD = 256
-SPLASH_BLOCK_WIDE_HEAD = 512
-# splash attention's tiles under a band of 512 keys: a query block of ``b`` positions meets the key blocks
-# that hold its ``b + window - 1`` keys (PERF.md section 5, PR 38)
-BAND_BLOCK = 512
+KERNEL_MIN_BLOCK = 128  # the kernel's tiles are multiples of this many positions
+
+
+class CorePlan(NamedTuple):
+    """Splash attention's tiles, in positions, the same in the forward and the backward kernels: a query
+    block, a key block, the keys of it that are multiplied at a time, and whether dQ is computed beside dK
+    and dV in one backward kernel (``fused_bwd``) or by a kernel of its own."""
+
+    block_q: int
+    block_kv: int
+    block_kv_compute: int
+    fused_bwd: bool
+
+
+def core_plan(S: int, D: int, Dv: int, window: Optional[int] = None) -> CorePlan:
+    """The tiles of the attention core over ``S`` positions with score heads of ``D`` and value heads of
+    ``Dv``, from what the chip measured (TPU v5e; ms a layer, forward + the forward replayed under
+    ``jax.checkpoint`` + backward, bf16, the kernel alone; tiles as query/key positions, ``c`` the keys
+    multiplied at a time; PERF.md section 5, the PR named):
+
+    ============================  ===============  ===============  ===============
+    query heads x head size,      24 x 128 over 4  32 x 128 over 2  32 x 64 over 8
+    sequences x positions (PR 39) 1 x 16,384       2 x 8,192        4 x 8,192
+    ============================  ===============  ===============  ===============
+    flash attention, 512-tiles         88.6             67.9            137.5
+    splash 512/512, own dQ kernel      80.1             57.1            121.4
+    splash 512/512, fused              72.3             51.0            105.4
+    splash 1,024/1,024, own dQ         66.3             50.1            100.1
+    splash 1,024/1,024, fused          58.9             43.9             87.0
+    splash 1,024/1,024 c 512, fused    56.4             42.2             84.1
+    **splash 1,024/2,048 c 512**       **54.2**         42.7           **83.7**
+    splash 1,024/2,048 c 1,024         55.5             43.8             85.5
+    splash 2,048/1,024 c 512           56.9             44.2            (VMEM)
+    ============================  ===============  ===============  ===============
+
+    The flash kernel (which ``causal_attention`` ran at these three shapes until PR 39, handed a copy of
+    each key/value head for every query head of its group) is behind every splash row at every shape, so
+    no shape keeps it. The fused backward kernel writes dQ once for every key block of the sequence
+    (``[S / block_kv, heads, S, D]``) and adds the copies up afterwards: key blocks of 2,048 halve those
+    copies (0.8 GB at 16,384 positions where 1,024 holds 1.6), which is what they win at 16,384 and why
+    they cost nothing at 8,192; 2,048 keys fit the chip's vector memory only when multiplied 512 at a time.
+    The number of query heads a key/value head (4, 6 and 16 above) never changed the order: the kernel
+    shares the key/value head itself.
+
+    What earlier PRs measured stays (their ms are forward + backward with no replay): a head of 256
+    (``qwen3_next``, 16 over 2, 2 x 8,192) 512-tiles, 34.6 ms against the flash kernel's 43.5, 1,024-tiles
+    refused for vector memory when multiplied whole (PR 36); a value head of its own size (latent attention,
+    16 heads of 192/128, 2 x 8,192) 1,024-tiles fused, 26.7 ms against 34.2 at 512 with its own dQ kernel
+    (PR 31); a ``window`` (``laguna``, 36 x 128 over 4, a band of 512 in 16,384) 512-tiles and a dQ kernel of
+    its own, 14.2 ms against 29.0 fused, since the fused kernel's dQ copies are the whole sequence's whatever
+    the mask (4.8 GB there), and 17-21 at every other tile (PR 38). Between the measured shapes the nearest
+    row serves, cut to tiles that divide ``S`` (128 divides it: ``causal_attention`` sees to that)."""
+    if window is not None:
+        q, kv, compute, fused = 512, 512, 512, False
+    elif max(D, Dv) >= 256:
+        q, kv, compute, fused = 512, 512, 512, True
+    elif D != Dv:
+        q, kv, compute, fused = 1024, 1024, 1024, True
+    else:
+        q, kv, compute, fused = 1024, 2048, 512, True
+
+    def dividing(n, tile):
+        tile = min(n, tile)
+        while n % tile:
+            tile //= 2
+        return tile
+
+    q, kv = dividing(S, q), dividing(S, kv)
+    return CorePlan(q, kv, dividing(kv, compute), fused)
 
 
 class RMSNorm(nn.Module):
@@ -216,23 +268,6 @@ def _attention_xla(q, k, v, scale: float, window: Optional[int] = None):
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, Hkv, G, v.shape[-1])
 
 
-def _attention_flash(q, k, v, scale: float):
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes, flash_attention
-
-    B, S, Hkv, G, D = q.shape
-    b = min(S, FLASH_BLOCK)
-    heads = lambda t: t.reshape(B, S, Hkv * G, D).transpose(0, 2, 1, 3)
-    # the kernel wants one key/value head per query head: each is repeated for its group
-    rep = lambda t: heads(jnp.broadcast_to(t[:, :, :, None, :], (B, S, Hkv, G, D)))
-    out = flash_attention(
-        heads(q), rep(k), rep(v), causal=True, sm_scale=scale,
-        block_sizes=BlockSizes(
-            block_q=b, block_k_major=b, block_k=b, block_b=1,
-            block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
-            block_k_major_dq=b, block_k_dq=b, block_q_dq=b))
-    return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
-
-
 def band_mask(S: int, window: int):
     """Splash attention's mask of the band: query ``i`` sees the keys ``i - window < j <= i``
     (``window - 1`` keys before its own and none after)."""
@@ -246,22 +281,18 @@ def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
     B, S, Hkv, G, D = q.shape
-    if window is None:
-        b, mask = min(S, SPLASH_BLOCK if D < SPLASH_FROM_HEAD else SPLASH_BLOCK_WIDE_HEAD), masks.CausalMask((S, S))
-    else:
-        # the kernel's grid holds the key blocks that meet the band and no others, in all three passes
-        b, mask = min(S, BAND_BLOCK), band_mask(S, window)
+    bq, bkv, compute, fused = core_plan(S, D, v.shape[-1], window)
+    # under a band the kernel's grid holds the key blocks that meet it and no others, in all three passes
+    mask = masks.CausalMask((S, S)) if window is None else band_mask(S, window)
     heads = lambda t: t.reshape(B, S, -1, t.shape[-1]).transpose(0, 2, 1, 3)
-    # the fused backward kernel writes dQ once for every key block of the sequence ([S / b, heads, S, D]: 4.8 GB
-    # for 36 heads of 128 at 16,384 positions) and adds them up afterwards, whatever the mask: under a band dQ
-    # gets a kernel of its own, which walks the key blocks a query block meets
-    sizes = (dict(use_fused_bwd_kernel=True) if window is None else
-             dict(use_fused_bwd_kernel=False, block_q_dq=b, block_kv_dq=b))
+    sizes = dict(use_fused_bwd_kernel=True) if fused else dict(use_fused_bwd_kernel=False, block_q_dq=bq, block_kv_dq=bkv)
+    # the library keeps the tables it makes of a mask on the host (1.2 s for 24 heads at 16,384 positions),
+    # so the layers of one shape, and a layer's replay, make them once
     kernel = splash.make_splash_mha(
         masks.MultiHeadMask([mask] * (Hkv * G)), head_shards=1, q_seq_shards=1,
         block_sizes=splash.BlockSizes(
-            block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b, block_kv_dkv=b,
-            block_kv_dkv_compute=b, **sizes))
+            block_q=bq, block_kv=bkv, block_kv_compute=compute, block_q_dkv=bq, block_kv_dkv=bkv,
+            block_kv_dkv_compute=compute, **sizes))
     # the kernel has no scale of its own, takes one sequence ([heads, S, .]) and shares a
     # key/value head among the query heads of its group itself
     out = jax.vmap(kernel)(heads(q * jnp.asarray(scale, q.dtype)), heads(k), heads(v))
@@ -275,13 +306,10 @@ def causal_attention(q, k, v, scale: float, window: Optional[int] = None):
     if window is not None and window >= q.shape[1]:
         window = None                                               # the band is the whole triangle
     plain = functools.partial(_attention_xla, scale=scale, window=window)
-    if q.shape[1] % FLASH_MIN_BLOCK:
+    if q.shape[1] % KERNEL_MIN_BLOCK:
         return plain(q, k, v)
-    if window is not None:
-        kernel = functools.partial(_attention_splash, window=window)
-    else:
-        kernel = _attention_flash if v.shape[-1] == q.shape[-1] < SPLASH_FROM_HEAD else _attention_splash
-    return jax.lax.platform_dependent(q, k, v, tpu=lambda *qkv: kernel(*qkv, scale), default=plain)
+    kernel = functools.partial(_attention_splash, scale=scale, window=window)
+    return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=plain)
 
 
 def open_gate(out, gate):

@@ -207,7 +207,7 @@ def attention_xla_as_it_was(q, k, v, scale: float):
 
 # one shape of each other model (a quarter of its key/value heads): key/value heads, group, head size, value head size
 OTHERS = {"lfm2": (2, 4, 64, None), "nemotron_h": (1, 16, 128, None), "qwen3_next": (1, 8, 256, None),
-          "deepseek_v3": (4, 1, 192, 128)}
+          "deepseek_v3": (4, 1, 192, 128), "laguna_full_layer": (1, 6, 128, None)}
 
 
 @pytest.mark.parametrize("which", OTHERS)
@@ -223,6 +223,36 @@ def test_without_a_window_every_caller_gets_bit_for_bit_what_it_got(which):
     same = lambda t: t.replace("attention_xla_as_it_was", "_attention_xla")
     assert same(text(lambda *a: attention_xla_as_it_was(*a, D ** -0.5)).split("\n", 1)[1]) == \
         text(lambda *a: sequence._attention_xla(*a, D ** -0.5)).split("\n", 1)[1]
+
+
+# what ``core_plan``'s table says the chip measured: (S, D, Dv, window) -> (query block, key block, the keys of it
+# multiplied at a time, dQ in the fused backward kernel)
+PLANS = {"lfm2_train_b4s8k": ((8192, 64, 64, None), (1024, 2048, 512, True)),
+         "nemotron_twotower_train_b2s8k": ((8192, 128, 128, None), (1024, 2048, 512, True)),
+         "kimi_vl_train_b2s8k": ((8192, 192, 128, None), (1024, 1024, 1024, True)),
+         "qwen3_next_train_b2s8k": ((8192, 256, 256, None), (512, 512, 512, True)),
+         "laguna_train_b1s16k_full_layer": ((16384, 128, 128, None), (1024, 2048, 512, True)),
+         "laguna_train_b1s16k_banded_layer": ((16384, 128, 128, 512), (512, 512, 512, False))}
+
+
+@pytest.mark.parametrize("cell", PLANS)
+def test_the_kernel_rule_returns_what_the_chip_measured_at_every_cells_shape(cell):
+    shape, plan = PLANS[cell]
+    assert sequence.core_plan(*shape) == plan
+
+
+@pytest.mark.parametrize("S,D,Dv,window", [(4096, 96, 96, None), (1536, 128, 128, None), (384, 64, 64, None), (640, 64, 64, None),
+                                           (640, 128, 128, 512), (1536, 256, 256, None), (128, 192, 128, None)],
+                         ids=lambda x: str(x))
+def test_the_kernel_rule_between_the_measured_shapes_returns_tiles_the_kernel_accepts(S, D, Dv, window):
+    """Splash attention takes tiles that are multiples of 128 positions and divide the sequence, and multiplies a
+    key block in pieces that divide it; the rule hands it the largest such tiles under the measured ones."""
+    q, kv, compute, fused = sequence.core_plan(S, D, Dv, window)
+    q_most, kv_most, compute_most, fused_most = sequence.core_plan(1 << 20, D, Dv, window)   # every measured tile divides it
+    for tile, most, whole in ((q, q_most, S), (kv, kv_most, S), (compute, compute_most, kv)):
+        assert tile % 128 == 0 and whole % tile == 0 and tile <= most
+        assert tile == whole or 2 * tile > most or whole % (2 * tile)  # and no smaller than the sequence forces
+    assert fused == fused_most == (window is None)
 
 
 def test_rope_is_bit_for_bit_what_it_was_and_partial_rope_with_it():

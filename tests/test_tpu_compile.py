@@ -280,23 +280,37 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, grad, which):
     assert compiled.as_text().count("tpu_custom_call") >= (3 * n if grad else n)
 
 
-@pytest.mark.parametrize("shape", [(4, 8, 4, 64), (2, 2, 16, 128), (2, 2, 8, 256)],
-                         ids=("lfm2_32x64", "nemotron_h_32x128_over_2", "qwen3_next_16x256_over_2_by_splash"))
-@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
-def test_flash_attention_compiles_for_v5e_at_8k_positions(one_chip, grad, shape):
-    from distar_tpu.ops.sequence import causal_attention
+# (B, S, Hkv, G, D) of the four cells whose full-causal core has one head size, the tiles and the backward kernel that
+# ``core_plan`` names for them, and what the core's temporaries may hold, forward / with the gradient: the row
+# statistics and the fused backward kernel's dQ, once for every key block (a third over what the compiler reads
+# today: 0.61 / 1.81, 0.27 / 0.68, 0.27 / 2.30 and 0.13 / 0.95 GB; at 16,384 positions that dQ is [8, 24, 16384, 128]
+# bf16, 0.8 GB, where key blocks of 1,024 held 1.6; one head's S x S scores alone would be 1.07 GB more in float32)
+CORES = {"lfm2_32x64": ((4, 8192, 8, 4, 64), (1024, 2048, 512, True), (0.8e9, 2.4e9)),
+         "nemotron_h_32x128_over_2": ((2, 8192, 2, 16, 128), (1024, 2048, 512, True), (0.4e9, 0.9e9)),
+         "qwen3_next_16x256_over_2_by_splash": ((2, 8192, 2, 8, 256), (512, 512, 512, True), (0.4e9, 3.0e9)),
+         "laguna_24x128_over_4_at_16k": ((1, 16384, 4, 6, 128), (1024, 2048, 512, True), (0.2e9, 1.3e9))}
 
-    (B, Hkv, G, Dh), S = shape, 8192
+
+@pytest.mark.parametrize("which", CORES)
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_full_causal_attention_compiles_for_v5e_to_the_kernel_the_rule_names(one_chip, grad, which):
+    """The full-causal core of every token cell with one head size compiles for a v5e to the kernel
+    ``core_plan`` names for its shape: splash attention at all of them since PR 39 (the narrow heads
+    ran the flash kernel until then, a copy of each key/value head a query head with it)."""
+    from distar_tpu.ops.sequence import causal_attention, core_plan
+
+    (B, S, Hkv, G, Dh), plan, temp = CORES[which]
+    assert core_plan(S, Dh, Dh) == plan
     q = jax.ShapeDtypeStruct((B, S, Hkv, G, Dh), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((B, S, Hkv, Dh), jnp.bfloat16, sharding=one_chip)
     fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, Dh ** -0.5).astype(jnp.float32) ** 2)
     compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, kv, kv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    # a head of 256 goes to splash attention with 512-tiles (the chip measured it ahead there), narrower ones to flash
-    assert ("splash" in compiled.as_text()) == (Dh >= 256)
-    # no S x S score tensor is held (8.6 GB a sequence in float32): the temporaries are the
-    # library's row statistics, which its backward pass broadcasts to the key block's width
-    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+    text = compiled.as_text()
+    # splash attention and no other kernel: forward, and backward one fused kernel for dQ, dK and dV
+    assert "splash_mha" in text and "flash" not in text
+    assert text.count("tpu_custom_call") == (2 if grad else 1)
+    # no S x S score tensor is held (8.6 GB a sequence in float32 at 8,192 positions)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp[grad]
 
 
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
