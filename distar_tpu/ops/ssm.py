@@ -18,10 +18,31 @@ recurrence unrolls into matrix products,
 (``Q x Q``, ``Q x N`` and ``Q x P`` products on the MXU in ``dtype``, sums in
 float32), and only ``h_in -> h_out`` is carried from chunk to chunk, in
 float32: ``S / Q`` dependent steps. Every exponent is a sum of ``dt A <= 0``
-over a span, so nothing overflows. The backward pass is JAX's own derivative
-of these products, a group of heads at a time. A sequence whose length ``Q``
-does not divide is padded with ``dt = 0`` positions, which leave the state as
-it is and are cut off.
+over a span, so nothing overflows. A sequence whose length ``Q`` does not
+divide is padded with ``dt = 0`` positions, which leave the state as it is and
+are cut off.
+
+``chunked_scan`` computes that by one of two forms that the platform the
+program is lowered for and the shapes choose (``jax.lax.platform_dependent``,
+``kernel_takes``; no option). **On a TPU, two Pallas kernels**
+(``_scan_kernel``, a ``jax.custom_vjp``; ``mamba2_scan_fwd`` and
+``mamba2_scan_bwd`` in a trace): a program instance owns a batch row and one
+group's ``H / G`` heads (they share ``B`` and ``C``, so ``C B^T`` is one product
+a chunk) and walks the sequence ``CHUNKS_A_STEP`` chunks a grid step, the
+group's states in VMEM from the first chunk to the last, transposed and two
+heads of 64 side by side (``[N, 2 P]``: one ``128 x 128`` product reads or
+writes both). ``x``, ``B``, ``C`` and ``y`` are read and written where they lie
+(``[b, S, H P]``, ``[b, S, G N]``); only ``dt`` and ``dt A`` go a head a row. A
+chunk's ``Q x Q`` decays and weights never leave the chip. The backward kernel
+keeps the five operands and the states each grid step starts from, walks the
+sequence from its end, computes a step's chunks again on the chip and carries
+the states' cotangent; ``A``'s gradient is the chain rule's through ``dt A``.
+**Anywhere else, and at shapes the tiles refuse, ``_scan_xla``**: a group of
+heads at a time (``lax.map``), the carry a ``lax.scan`` over the chunks, the
+backward pass JAX's own derivative of these products with each group's
+intermediates computed again. Both forms round where the other does (products
+take ``dtype`` operands and accumulate in float32, the weights are formed in
+float32 and cast once, the state and ``y`` are float32).
 
 ``Mamba2Mixer`` is the layer around it, as ``nemotron_h`` publishes it:
 ``[z, xBC, dt] = W_in u``; ``xBC <- silu(causal_conv(xBC) + b)``; ``x, B, C``
@@ -39,6 +60,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .sequence import causal_conv, conv_kernel_init, dense
 
@@ -74,13 +97,11 @@ def _group_scan(x, dt, A, B, C, dtype: Dtype):
     return y, last
 
 
-def chunked_scan(x, dt, A, B, C, chunk: int, dtype: Dtype = jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``x`` [b, S, H, P], ``dt`` [b, S, H] float32, ``A`` [H] float32,
-    ``B``/``C`` [b, S, G, N] -> (``y`` [b, S, H, P] float32 without the
-    ``D x`` skip, the state after the last position [b, H, P, N] float32).
-    One group of ``H / G`` heads at a time (``lax.map``), each group's
-    intermediates computed again in its backward pass: the ``Q x Q`` decay
-    matrices of every head at once are 0.5 GB a layer at 16,384 positions."""
+def _scan_xla(x, dt, A, B, C, chunk: int, dtype: Dtype):
+    """``chunked_scan`` in plain XLA. One group of ``H / G`` heads at a time
+    (``lax.map``), each group's intermediates computed again in its backward
+    pass: the ``Q x Q`` decay matrices of every head at once are 0.5 GB a
+    layer at 16,384 positions."""
     b, S, H, P = x.shape
     G, N = B.shape[2:]
     Q = min(chunk, S)
@@ -95,6 +116,299 @@ def chunked_scan(x, dt, A, B, C, chunk: int, dtype: Dtype = jnp.float32) -> Tupl
          A.reshape(G, H // G), by_group(B.astype(dtype), N), by_group(C.astype(dtype), N)))
     y = jnp.moveaxis(y, 0, 3).reshape(b, nc * Q, H, P)[:, :S]               # [G, b, c, Q, h, P] -> [b, S, H, P]
     return y, jnp.moveaxis(last, 0, 1).reshape(b, H, P, N)
+
+
+# ------------------------------------------------------------- the scan as a kernel
+F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))   # a b, a b^T, a^T b
+LANES = 128
+# chunks one grid step walks: a step's fixed cost is shared, and the scheduler sees that many chunks' work at once
+CHUNKS_A_STEP = 4
+
+
+def _mm(a, b, dims=NN, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=F32, precision=precision)
+
+
+def _exact(a, b, dims=NN, ones=1):
+    """A float32 product one operand of which (``ones``: its index) holds only 0 and 1, at float32's own
+    precision: the other goes in as three bfloat16 pieces that add up to it exactly, three passes of the MXU
+    where the compiler's ``HIGHEST`` splits both operands and takes six. The sums and transpositions of a chunk's
+    per-head vectors are such products."""
+    value, pieces = (a, b)[1 - ones].astype(F32), []
+    for _ in range(3):
+        pieces.append(value.astype(jnp.bfloat16))
+        value = value - pieces[-1].astype(F32)
+    bits = (a, b)[ones].astype(jnp.bfloat16)
+    return sum(_mm(bits, piece, dims) if ones == 0 else _mm(piece, bits, dims) for piece in pieces)
+
+
+def _rounded(scale, dtype):
+    """``scale`` as the XLA form multiplies by it: rounded to ``dtype``, held in float32."""
+    return scale.astype(dtype).astype(F32)
+
+
+def _chunk_decays(dt, dta):
+    """A chunk's per-head vectors from ``dt`` and ``dt A`` [r, Q] float32 (a head a row, a position a lane), all
+    ``r`` heads at once and in both layouts: columns [Q, r] (a position a sublane) come off the rows by a product
+    with the identity, the running sum ``a`` by one with the lower triangle, and ``a``'s rows off its columns, so
+    that ``a_i - a_i`` is exactly 0."""
+    Q = dt.shape[1]
+    ii, jj = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), d) for d in (0, 1))
+    seen = ii >= jj
+    lower, eye = seen.astype(jnp.bfloat16), (ii == jj).astype(jnp.bfloat16)
+    a = _exact(lower, dta, NT, 0)                    # [Q, r]: a_i = sum_{l <= i} dt_l A
+    total = a[Q - 1:Q]                              # [1, r]
+    left = jnp.exp(total - a)                       # exp(a_Q - a_j)
+    dt_cols = _exact(eye, dt, NT, 0)
+    return dict(seen=seen, lower=lower, eye=eye, dt_rows=dt, a=a, a_rows=_exact(a, eye, TN), left=left,
+                to_end=left * dt_cols, decay=jnp.exp(a), through=jnp.exp(total))
+
+
+def _lane_of(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+
+
+def _wide(cols, t, k, P):
+    """``cols`` [rows, r], a head a lane -> [rows, k P]: head ``t k + j``'s column across its own ``P`` lanes of the
+    tile that holds ``k`` heads side by side."""
+    shape = (cols.shape[0], k * P)
+    out = jnp.broadcast_to(cols[:, t * k:t * k + 1], shape)
+    for j in range(1, k):
+        out = jnp.where(_lane_of(shape) >= j * P, jnp.broadcast_to(cols[:, t * k + j:t * k + j + 1], shape), out)
+    return out
+
+
+def _of_head(tile, j, k, P):
+    """A tile of ``k`` heads side by side with every head's lanes but head ``j``'s zeroed: a product with it is
+    that head's, and lands in (or reads) its own lanes."""
+    lane = _lane_of(tile.shape)
+    return jnp.where((lane >= j * P) & (lane < (j + 1) * P), tile, jnp.zeros_like(tile))
+
+
+def _by_head(t, k, P, r):
+    """[k P, r] of 0 and 1: lane ``l`` of tile ``t`` belongs to head ``t k + l // P``. A product with it sums a tile's
+    lanes head by head into [.., r]."""
+    lane, head = (jax.lax.broadcasted_iota(jnp.int32, (k * P, r), d) for d in (0, 1))
+    return ((lane >= (head - t * k) * P) & (lane < (head - t * k + 1) * P)).astype(jnp.bfloat16)
+
+
+def _weights(ch, cb, h, dtype):
+    """Head ``h``'s ``Q x Q`` decays ``exp(a_i - a_j)`` for ``i >= j`` (every exponent a sum of ``dt A <= 0``; the
+    mask goes on before the ``exp``) and its weights ``exp(a_i - a_j) dt_j (C_i . B_j)``, formed in float32 and
+    rounded once."""
+    E = jnp.exp(jnp.where(ch["seen"], ch["a"][:, h:h + 1] - ch["a_rows"][h:h + 1], -jnp.inf))
+    return E, (E * ch["dt_rows"][h:h + 1] * cb).astype(dtype)
+
+
+def _state_after(ch, Bc, x, S, t, k, P, dtype):
+    """A tile's states [N, k P] float32 (transposed: the state size on the sublanes, ``k`` heads' ``P`` side by
+    side on the lanes, so that one product serves them all) after the chunk."""
+    xe = (x.astype(F32) * _rounded(_wide(ch["to_end"], t, k, P), dtype)).astype(dtype)
+    return _wide(ch["through"], t, k, P) * S + _mm(Bc, xe, TN)
+
+
+def _forward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, y_ref, last_ref, *starts_ref, Q, n, k, P, dtype):
+    """One batch row, one group of ``r`` heads (``T = r / k`` tiles of ``k`` heads), ``n`` chunks of the sequence;
+    the grid's last axis walks the sequence in order and ``last_ref`` (the same block all along it) carries the
+    states. ``starts_ref``, where the backward pass will follow, takes the states this step starts from."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        last_ref[...] = jnp.zeros_like(last_ref)
+
+    if starts_ref:
+        starts_ref[0][0, 0, 0] = last_ref[0, 0]
+    for c in range(n):
+        rows = slice(c * Q, (c + 1) * Q)
+        Bc, Cc = B_ref[0, rows], C_ref[0, rows]
+        ch, cb = _chunk_decays(dt_ref[0, 0, 0, c], dta_ref[0, 0, 0, c]), _mm(Cc, Bc, NT)
+        for t in range(last_ref.shape[2]):
+            lanes = slice(t * k * P, (t + 1) * k * P)
+            x, S = x_ref[0, rows, lanes], last_ref[0, 0, t]
+            y = _wide(ch["decay"], t, k, P) * _mm(Cc, S.astype(dtype))
+            for j in range(k):
+                y = y + _mm(_weights(ch, cb, t * k + j, dtype)[1], _of_head(x, j, k, P))
+            y_ref[0, rows, lanes] = y
+            last_ref[0, 0, t] = _state_after(ch, Bc, x, S, t, k, P, dtype)
+
+
+def _backward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, starts_ref, dy_ref, dlast_ref,
+                     dx_ref, ddt_ref, ddta_ref, dB_ref, dC_ref, dS_ref, *, Q, n, k, P, dtype):
+    """The same program instance as the forward kernel's, the grid's last axis walking the sequence from its end.
+    A step walks its ``n`` chunks' states forward again from the ones the forward kernel left for it, then
+    backward through the chunks (each chunk's decays, weights and products computed again, on the chip), and
+    ``dS_ref`` carries the states' cotangent towards position 0. ``B`` and ``C`` serve all the group's heads: their
+    cotangents are summed over the heads here. Of ``a``'s cotangent, what comes through ``exp(a_i - a_j)`` is the
+    row sums less the column sums of ONE matrix (``dW_ij W_ij``): taken from two that round apart (``<dy_i, y_i>``
+    would be the row sums) they no longer cancel, and ``A``'s gradient, a sum of their running sums, is what is
+    left of them."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dS_ref[...] = dlast_ref[0, 0]
+
+    T, r = dS_ref.shape[0], dt_ref.shape[4]
+    pieces = [slice(c * Q, (c + 1) * Q) for c in range(n)]
+    chunks = [_chunk_decays(dt_ref[0, 0, 0, c], dta_ref[0, 0, 0, c]) for c in range(n)]
+    states = [[starts_ref[0, 0, 0, t] for t in range(T)]]
+    for c in range(n - 1):
+        states.append([_state_after(chunks[c], B_ref[0, pieces[c]], x_ref[0, pieces[c], t * k * P:(t + 1) * k * P],
+                                    states[c][t], t, k, P, dtype) for t in range(T)])
+    head_row = jax.lax.broadcasted_iota(jnp.int32, (r, Q), 0)
+    last_row, head_lane = (jax.lax.broadcasted_iota(jnp.int32, (Q, r), 0) == Q - 1), _lane_of((Q, r))
+    by_heads = [_by_head(t, k, P, r) for t in range(T)]
+    for c in reversed(range(n)):
+        rows, ch = pieces[c], chunks[c]
+        Bc, Cc = B_ref[0, rows], C_ref[0, rows]
+        cb = _mm(Cc, Bc, NT)
+        d_cb, dB, dC = jnp.zeros((Q, Q), F32), 0.0, 0.0
+        seen_by_i, seen_by_j, through_s = jnp.zeros((Q, r), F32), jnp.zeros((Q, r), F32), jnp.zeros((1, r), F32)
+        weight_sums = jnp.zeros((r, Q), F32)                  # sum_i dW_ij exp(a_i - a_j) (C_i . B_j), a head a row
+        for t in range(T):
+            lanes = slice(t * k * P, (t + 1) * k * P)
+            x, S, dS, dy = x_ref[0, rows, lanes], states[c][t], dS_ref[t], dy_ref[0, rows, lanes]
+            Sb, dSb, dyb = S.astype(dtype), dS.astype(dtype), dy.astype(dtype)
+            decay, to_end = _wide(ch["decay"], t, k, P), _rounded(_wide(ch["to_end"], t, k, P), dtype)
+            xe = (x.astype(F32) * to_end).astype(dtype)
+            # y = decay (C Sb) + sum_heads W x;   after = through S + B^T xe
+            held = _mm(Cc, Sb)
+            d_held = (dy * decay).astype(dtype)               # the cotangent of C Sb
+            dC = dC + _mm(d_held, Sb, NT)
+            d_xe = _mm(Bc, dSb)
+            dB = dB + _mm(xe, dSb, NT)
+            dx = d_xe * to_end
+            for j in range(k):
+                h = t * k + j
+                E, W = _weights(ch, cb, h, dtype)
+                dy_h, dt_row = _of_head(dyb, j, k, P), ch["dt_rows"][h:h + 1]
+                dx = dx + _mm(W, dy_h, TN)
+                dE = _mm(dy_h, x, NT) * E                     # dW_ij exp(a_i - a_j)
+                d_cb = d_cb + dE * dt_row
+                d_span = dE * cb                              # dW_ij W_ij / dt_j
+                weight_sums = jnp.where(head_row == h, jnp.sum(d_span, axis=0, keepdims=True), weight_sums)
+                seen_by_i = seen_by_i + jnp.where(head_lane == h, jnp.sum(d_span * dt_row, axis=1, keepdims=True), 0.0)
+            dx_ref[0, rows, lanes] = dx.astype(dx_ref.dtype)
+            seen_by_i = seen_by_i + _exact(dy * decay * held, by_heads[t])        # a_i under exp(a_i) alone
+            seen_by_j = seen_by_j + _exact(d_xe * x.astype(F32), by_heads[t])     # <d_xe_j, x_j>
+            through_s = through_s + _exact(jnp.sum(dS * S, axis=0, keepdims=True), by_heads[t])
+            dS_ref[t] = _wide(ch["through"], t, k, P) * dS + _mm(Cc, d_held, TN)
+        d_cb = d_cb.astype(dtype)
+        dB_ref[0, rows] = (dB + _mm(d_cb, Cc, TN)).astype(dB_ref.dtype)
+        dC_ref[0, rows] = (dC + _mm(d_cb, Bc)).astype(dC_ref.dtype)
+        # a_j enters exp(a_Q - a_j) dt_j (to_end) and, under the weights, -a_j; a_Q enters to_end and exp(a_Q)
+        ended = seen_by_j * ch["to_end"]
+        at_end = jnp.sum(ended, axis=0, keepdims=True) + through_s * ch["through"]
+        da = seen_by_i - ended + jnp.where(last_row, at_end, 0.0)                 # [Q, r]
+        da_rows = -ch["dt_rows"] * weight_sums                                    # [r, Q]
+        # a_j = sum_{l <= j} (dt A)_l: position l collects every j >= l
+        ddta_ref[0, 0, 0, c] = _exact(da, ch["lower"], TN) + _exact(da_rows, ch["lower"])
+        ddt_ref[0, 0, 0, c] = weight_sums + _exact(seen_by_j * ch["left"], ch["eye"], TN)
+
+
+def _kernel_call(kernel, operands, results, b, S, G, r, P, N, Q, reverse, scratch, interpret, name):
+    """``pallas_call`` over (batch row, group of heads, step of ``CHUNKS_A_STEP`` chunks). Operands and results
+    are named by kind: ``heads`` [b, S, H P], ``shared`` [b, S, G N], ``gate`` [b, G, steps, n, r, Q], ``state``
+    [b, G, T, N, k P], ``states`` [b, G, steps, T, N, k P]."""
+    n, k = CHUNKS_A_STEP, LANES // P
+    steps, T = S // (n * Q), r // k
+    at = (lambda j: steps - 1 - j) if reverse else (lambda j: j)
+    specs = {"heads": pl.BlockSpec((1, n * Q, r * P), lambda i, g, j: (i, at(j), g)),
+             "shared": pl.BlockSpec((1, n * Q, N), lambda i, g, j: (i, at(j), g)),
+             "gate": pl.BlockSpec((1, 1, 1, n, r, Q), lambda i, g, j: (i, g, at(j), 0, 0, 0)),
+             "state": pl.BlockSpec((1, 1, T, N, k * P), lambda i, g, j: (i, g, 0, 0, 0)),
+             "states": pl.BlockSpec((1, 1, 1, T, N, k * P), lambda i, g, j: (i, g, at(j), 0, 0, 0))}
+    return pl.pallas_call(
+        functools.partial(kernel, Q=Q, n=n, k=k, P=P, dtype=operands[0][1].dtype),
+        grid=(b, G, steps), in_specs=[specs[kind] for kind, _ in operands],
+        out_specs=[specs[kind] for kind, _ in results], out_shape=[shape for _, shape in results],
+        scratch_shapes=scratch, interpret=interpret, name=name,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(*(x for _, x in operands))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "keep_starts"))
+def _kernel_forward(x, dt, dta, B, C, chunk: int, interpret: bool, keep_starts: bool):
+    """(Under ``jit`` so that a model's layers share ONE trace of the kernel's long body.) The operands where they
+    lie (heads side by side on the last axis, which is a reshape) but for ``dt`` and ``dt A``, which go a head a
+    row (4 bytes a position and head each); the sequence padded to whole steps with ``dt = 0``, which leaves the
+    state as it is; the forward kernel, and its results in the caller's layouts."""
+    b, S, H, P = x.shape
+    G, N = B.shape[2:]
+    Q, n, r, k = chunk, CHUNKS_A_STEP, H // G, LANES // P
+    pad = -S % (Q * n)
+    padded = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) if pad else t
+    Sp = S + pad
+    steps, T = Sp // (Q * n), r // k
+    flat = lambda t: padded(t).reshape(b, Sp, -1)
+    gate = lambda t: padded(t).reshape(b, steps, n, Q, G, r).transpose(0, 4, 1, 2, 5, 3)
+    operands = [("heads", flat(x)), ("gate", gate(dt)), ("gate", gate(dta)), ("shared", flat(B)), ("shared", flat(C))]
+    shape = jax.ShapeDtypeStruct
+    results = [("heads", shape((b, Sp, H * P), F32)), ("state", shape((b, G, T, N, k * P), F32))]
+    if keep_starts:
+        results.append(("states", shape((b, G, steps, T, N, k * P), F32)))
+    out = _kernel_call(_forward_kernel, operands, results, b, Sp, G, r, P, N, Q, False, [], interpret,
+                       "mamba2_scan_fwd")
+    last = out[1].reshape(b, G, T, N, k, P).transpose(0, 1, 2, 4, 5, 3).reshape(b, H, P, N)
+    return (out[0][:, :S].reshape(b, S, H, P), last), ([x for _, x in operands], out[2] if keep_starts else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _scan_kernel(x, dt, dta, B, C, chunk: int, interpret: bool):
+    """``chunked_scan`` as two Pallas kernels: ``x``/``B``/``C`` in the products' dtype, ``dt`` and ``dta`` (``dt
+    A``) float32 (``A``'s gradient is the chain rule's, outside). The backward pass keeps the five operands and the
+    states every grid step starts from."""
+    return _kernel_forward(x, dt, dta, B, C, chunk, interpret, False)[0]
+
+
+def _scan_kernel_fwd(x, dt, dta, B, C, chunk, interpret):
+    return _kernel_forward(x, dt, dta, B, C, chunk, interpret, True)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _scan_kernel_bwd(chunk, interpret, kept, cotangents):
+    (x, dt, dta, B, C), starts = kept
+    dy, dlast = cotangents
+    b, S, H, P = dy.shape
+    Sp, (G, _, T, N, tile) = x.shape[1], starts.shape[1:]
+    r, k = H // G, tile // P
+    operands = [("heads", x), ("gate", dt), ("gate", dta), ("shared", B), ("shared", C), ("states", starts),
+                ("heads", jnp.pad(dy.reshape(b, S, H * P), ((0, 0), (0, Sp - S), (0, 0)))),
+                ("state", dlast.reshape(b, G, T, k, P, N).transpose(0, 1, 2, 5, 3, 4).reshape(b, G, T, N, tile))]
+    shape = jax.ShapeDtypeStruct
+    results = [("heads", shape(x.shape, x.dtype)), ("gate", shape(dt.shape, F32)), ("gate", shape(dt.shape, F32)),
+               ("shared", shape(B.shape, B.dtype)), ("shared", shape(B.shape, B.dtype))]
+    dx, ddt, ddta, dB, dC = _kernel_call(_backward_kernel, operands, results, b, Sp, G, r, P, N, chunk, True,
+                                         [pltpu.VMEM((T, N, tile), F32)], interpret, "mamba2_scan_bwd")
+    ungate = lambda t: t.transpose(0, 2, 3, 5, 1, 4).reshape(b, Sp, H)[:, :S]      # [b, G, steps, n, r, Q] -> [b, S, H]
+    return (dx[:, :S].reshape(b, S, H, P), ungate(ddt), ungate(ddta), dB[:, :S].reshape(b, S, G, N),
+            dC[:, :S].reshape(b, S, G, N))
+
+
+_scan_kernel.defvjp(_scan_kernel_fwd, _scan_kernel_bwd)
+
+
+def kernel_takes(P: int, N: int, G: int, H: int, chunk: int) -> bool:
+    """The kernels' tiles: heads of half a lane tile, two side by side in one (heads of a whole tile do not get
+    through the TPU compiler as the kernels stand), a group's heads whole tiles, the state whole lane tiles, a
+    chunk whole sublane tiles of the products' dtype."""
+    return 2 * P == LANES and H % G == 0 and (H // G) % 2 == 0 and N % LANES == 0 and chunk % 16 == 0
+
+
+def chunked_scan(x, dt, A, B, C, chunk: int, dtype: Dtype = jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``x`` [b, S, H, P], ``dt`` [b, S, H] float32, ``A`` [H] float32,
+    ``B``/``C`` [b, S, G, N] -> (``y`` [b, S, H, P] float32 without the
+    ``D x`` skip, the state after the last position [b, H, P, N] float32).
+    Lowered for a TPU, at shapes its tiles take (``kernel_takes``), the Pallas
+    kernels above; anywhere else ``_scan_xla``."""
+    xla = lambda *a: _scan_xla(*a, chunk, dtype)
+    if not kernel_takes(x.shape[3], B.shape[3], B.shape[2], x.shape[2], chunk):
+        return xla(x, dt, A, B, C)
+
+    def kernel(x, dt, A, B, C):
+        dt = dt.astype(F32)
+        return _scan_kernel(x.astype(dtype), dt, dt * A, B.astype(dtype), C.astype(dtype), chunk, False)
+
+    return jax.lax.platform_dependent(x, dt, A, B, C, tpu=kernel, default=xla)
 
 
 def state_rms(per_head):
@@ -128,9 +442,11 @@ class GroupedRMSNormGated(nn.Module):
     def __call__(self, y, z):
         scale = self.param("scale", nn.initializers.ones, (y.shape[-1],), jnp.float32)
         t = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-        g = t.reshape(*t.shape[:-1], -1, self.group)
-        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + self.eps)
-        return (g.reshape(t.shape) * scale).astype(y.dtype)
+        # a group at a time, as slices of the channel axis: a reshape to [.., groups, group] splits the lane axis,
+        # which on a TPU is a copy of the tensor each way once a kernel has pinned its layout (PERF.md section 6, PR 40)
+        groups = jnp.split(t, t.shape[-1] // self.group, axis=-1)
+        g = jnp.concatenate([g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + self.eps) for g in groups], -1)
+        return (g * scale).astype(y.dtype)
 
 
 class Mamba2Mixer(nn.Module):
@@ -168,12 +484,12 @@ class Mamba2Mixer(nn.Module):
             A = -jnp.exp(self.param("A_log", lambda key, shape: jnp.log(jnp.arange(1.0, H + 1.0)), (H,)))
             D = self.param("D", nn.initializers.ones, (H,), jnp.float32)
             dt = nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            x = x.reshape(Bt, S, H, P)
         with jax.named_scope("ssm_scan"):
-            y, last = chunked_scan(x, dt, A, B.reshape(Bt, S, G, N), C.reshape(Bt, S, G, N),
+            y, last = chunked_scan(x.reshape(Bt, S, H, P), dt, A, B.reshape(Bt, S, G, N), C.reshape(Bt, S, G, N),
                                    self.chunk, self.dtype)
         with jax.named_scope("ssm_proj"):
-            y = (y + D[:, None] * x.astype(jnp.float32)).astype(self.dtype).reshape(Bt, S, inner)
+            # the skip where the projections' operands lie ([.., H P], a head's D over its P lanes), no head an axis
+            y = (y.reshape(Bt, S, inner) + jnp.repeat(D, P) * x.astype(jnp.float32)).astype(self.dtype)
             y = GroupedRMSNormGated(inner // G, self.eps, name="gated_norm")(y, z)
             out = dense(d, self.dtype, "out_proj")(y)
         return out, state_rms(jnp.mean(jnp.square(last), axis=(0, 2, 3)))

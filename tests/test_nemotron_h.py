@@ -142,6 +142,117 @@ def test_chunked_scan_holds_through_decays_that_underflow():
     assert np.isfinite(g).all()
 
 
+# the scan's two Pallas kernels, interpreted: heads of 64 two to a lane tile and a state of 128 as published, chunks
+# of 16 (a grid step is four of them, 64 positions) where the case's length is a few dozen positions
+KERNEL_CASES = {"two_whole_grid_steps": dict(S=128), "steps_and_a_part_padded_with_dt_0": dict(S=70),
+                "one_chunk": dict(S=16), "eight_heads_a_group": dict(S=40, H=8, G=1),
+                "decays_that_underflow_and_that_hardly_decay": dict(S=40, A=(-80.0, -1e-4)),
+                "the_published_chunk": dict(S=130, chunk=128, b=1, H=2, G=1)}
+
+
+@pytest.fixture
+def executables_dropped():
+    """The interpreted kernels are long programs, and every one XLA:CPU loads holds a few thousand memory mappings
+    until its ``jit`` is dropped (``tests/test_qwen3_next.py::executables_dropped``; PERF.md section 7.29 (e))."""
+    yield
+    jax.clear_caches()
+
+
+def kernel_scan(chunk):
+    return lambda x, dt, A, B, C: ssm._scan_kernel(x, dt, dt * A, B, C, chunk, True)
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernels_in_interpret_mode_are_the_literal_recurrence_forward_and_backward(case, executables_dropped):
+    """``ops.ssm._scan_kernel`` (the forward and the backward Pallas kernel, interpreted on the CPU): ``y``, the
+    last state and all five gradients (``A``'s by the chain rule through ``dt A``)."""
+    kw = dict(KERNEL_CASES[case])
+    S, chunk, A_of = kw.pop("S"), kw.pop("chunk", 16), kw.pop("A", ())
+    x, dt, A, B, C = scan_inputs(S, **{"H": 4, "P": 64, "G": 2, "N": 128, **kw})
+    args = (x, dt, A.at[:len(A_of)].set(jnp.asarray(A_of)) if A_of else A, B, C)
+    weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    score = lambda f: lambda *a: (lambda y, last: ((y * weight).sum() + jnp.square(last).sum(), (y, last)))(*f(*a))
+    with jax.default_matmul_precision("highest"):     # under jit: eagerly the interpreter dispatches a kernel op by op
+        (_, (y, last)), got = jax.jit(jax.value_and_grad(score(kernel_scan(chunk)), range(5), has_aux=True))(*args)
+        (_, (want, want_last)), grads = jax.value_and_grad(score(literal), range(5), has_aux=True)(*args)
+    assert y.shape == x.shape and last.shape == (x.shape[0], x.shape[2], 64, 128) and np.isfinite(y).all()
+    # float32 against float32: sums over a state of 128 taken in another order (the XLA form reads the same)
+    np.testing.assert_allclose(y, want, atol=1e-6 * float(jnp.abs(want).max()) + 2e-5, rtol=1e-4)
+    np.testing.assert_allclose(last, want_last, atol=1e-6 * float(jnp.abs(want_last).max()) + 2e-5, rtol=1e-4)
+    for name, g, w in zip(("x", "dt", "A", "B", "C"), got, grads):
+        assert np.isfinite(g).all(), name
+        np.testing.assert_allclose(g, w, atol=1e-4 * float(jnp.abs(w).max()), rtol=0, err_msg=name)
+
+
+def test_the_kernels_keep_their_operands_dtype_and_round_where_the_xla_form_rounds(executables_dropped):
+    """bfloat16 operands, ``dt`` and ``A`` drawn as the layer draws them (steps of 1e-3 to 1e-1, ``A`` = -1, -2, ..),
+    two chunks of the published 128 positions: products of bfloat16 values summed in float32, the weights rounded once, the state float32.
+    Each gradient's distance from the float32 recurrence's is about the XLA form's own at bfloat16. ``A``'s is the
+    one that tells: it is a sum of running sums of ``a``'s cotangent, whose row and column parts cancel only when
+    both are sums of one matrix (taken from ``<dy_i, y_i>`` and the weights' column sums, which round apart, it read
+    0.015 here where the XLA form reads 0.003, and was 0.9 off on the chip: PERF.md section 6, PR 40; float32 operands
+    and chunks of 16 show nothing of it)."""
+    bf = jnp.bfloat16
+    b, S, H, P, G, N, Q = 1, 256, 4, 64, 2, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    step = jnp.exp(jax.random.uniform(ks[1], (H,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    args = (jax.random.normal(ks[0], (b, S, H, P)).astype(bf), step * jnp.exp(0.5 * jax.random.normal(ks[2], (b, S, H))),
+            -jnp.arange(1.0, H + 1.0), jax.random.normal(ks[3], (b, S, G, N)).astype(bf),
+            jax.random.normal(ks[4], (b, S, G, N)).astype(bf))
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    score = lambda f: lambda *a: (lambda y, last: ((y * weight).sum() + 1e-3 * jnp.square(last).sum(), (y, last)))(*f(*a))
+    (_, (y, last)), got = jax.jit(jax.value_and_grad(score(kernel_scan(Q)), range(5), has_aux=True))(*args)
+    (_, (xla_y, _)), xla = jax.value_and_grad(score(lambda *a: ssm._scan_xla(*a, Q, bf)), range(5), has_aux=True)(*args)
+    with jax.default_matmul_precision("highest"):
+        (_, (want, want_last)), grads = jax.value_and_grad(
+            score(lambda x, dt, A, B, C: ssm._scan_xla(x.astype(jnp.float32), dt, A, B.astype(jnp.float32),
+                                                       C.astype(jnp.float32), Q, jnp.float32)), range(5), has_aux=True)(*args)
+    assert y.dtype == last.dtype == jnp.float32 and y.shape == (b, S, H, P)
+    off = lambda g, w: float(jnp.linalg.norm(g.astype(jnp.float32) - w) / jnp.linalg.norm(w))
+    assert off(y, want) < 2 * off(xla_y, want) + 1e-3 and off(last, want_last) < 1e-2
+    for name, g, x, w in zip(("x", "dt", "A", "B", "C"), got, xla, grads):
+        assert g.dtype == x.dtype and g.shape == x.shape, name
+        assert off(g, w) < 2 * off(x, w) + 2e-3, (name, off(g, w), off(x, w))
+
+
+@pytest.mark.parametrize("refused", ("head_of_8", "state_of_16"))
+def test_a_shape_the_kernels_tiles_refuse_takes_the_xla_form(refused, monkeypatch):
+    shape = dict({"H": 4, "P": 64, "G": 2, "N": 128}, **{"head_of_8": dict(P=8), "state_of_16": dict(N=16)}[refused])
+    assert not ssm.kernel_takes(shape["P"], shape["N"], shape["G"], shape["H"], 16)
+    assert ssm.kernel_takes(64, 128, 8, 64, 128) and not ssm.kernel_takes(64, 128, 8, 64, 8)   # published; tiny's chunk
+    assert not ssm.kernel_takes(64, 128, 4, 12, 128)                                           # three heads a group
+    assert not ssm.kernel_takes(128, 128, 8, 32, 128)                                          # a head a whole lane tile
+
+    def never(*a):
+        raise AssertionError("the kernel was asked")
+
+    monkeypatch.setattr(ssm, "_scan_kernel", never)
+    args = scan_inputs(24, **shape)
+    (y, last), (want, want_last) = jax.jit(lambda *a: ssm.chunked_scan(*a, 16))(*args), literal(*args)
+    np.testing.assert_allclose(y, want, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(last, want_last, atol=1e-4, rtol=1e-4)
+    # and a shape the tiles take does ask it: under jit the branch for a TPU is traced whatever the platform
+    with pytest.raises(AssertionError, match="the kernel was asked"):
+        jax.jit(lambda *a: ssm.chunked_scan(*a, 16))(*scan_inputs(24, H=4, P=64, G=2, N=128))
+
+
+def test_off_the_tpu_a_shape_the_tiles_take_runs_the_xla_form_under_both_branches():
+    """``jax.lax.platform_dependent`` traces the kernels' branch and the XLA form's and lowers, here, the second:
+    value and gradients through the pair are the literal recurrence's, and no ``pallas_call`` is interpreted."""
+    args = scan_inputs(40, H=4, P=64, G=2, N=128)
+    ours = lambda *a: ssm.chunked_scan(*a, 16)
+    score = lambda f: lambda *a: jnp.square(f(*a)[0]).sum() + jnp.square(f(*a)[1]).sum()
+    with jax.default_matmul_precision("highest"):
+        want = literal(*args)[0]
+        np.testing.assert_allclose(jax.jit(ours)(*args)[0], want, atol=1e-6 * float(jnp.abs(want).max()) + 2e-5, rtol=1e-4)
+        got = jax.jit(jax.grad(score(ours), argnums=range(5)))(*args)
+        grads = jax.grad(score(literal), argnums=range(5))(*args)
+    for name, g, w in zip(("x", "dt", "A", "B", "C"), got, grads):
+        np.testing.assert_allclose(g, w, atol=1e-4 * float(jnp.abs(w).max()), rtol=0, err_msg=name)
+    lowered = jax.jit(ours).lower(*args).as_text()
+    assert "tpu_custom_call" not in lowered and "while" in lowered     # the XLA form's loop over groups of heads
+
+
 def test_mixer_is_causal_and_has_the_published_parameters():
     mixer = ssm.Mamba2Mixer(heads=8, head_dim=8, groups=2, state=16, conv_kernel=4, chunk=8)
     u = jax.random.normal(jax.random.PRNGKey(0), (1, 24, 32))

@@ -351,6 +351,36 @@ def test_chunked_scan_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(on
 
 
 @pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_the_mamba2_mixer_compiles_for_v5e_to_the_scans_two_kernels(one_chip, grad):
+    """``ops.ssm.Mamba2Mixer`` at nemotron_h's published widths over 2 x 8,192 positions in bfloat16, wrapped as the
+    decoder layer wraps it (``jax.checkpoint``): lowered for a TPU its scan is ``mamba2_scan_fwd`` (twice with the
+    replay) and ``mamba2_scan_bwd``, no loop over groups of heads or chunks is left under ``ssm_scan``, and no
+    chunk's ``128 x 128`` decay matrices of a group's 8 heads are a temporary of the program. What is: the
+    projection's ``[2, 8192, 10304]`` results, ``y`` and its cotangent in float32 (268 MB each) and the states the
+    grid steps start from (67 MB). And the layer around the kernels stays in the layout they pin: no copy of a float32
+    ``[2, 8192, 4096]`` tensor is left (the group norm's reshape to ``[.., 8, 512]`` cost two each way: PERF.md
+    section 6, PR 40)."""
+    from distar_tpu.ops.ssm import Mamba2Mixer
+
+    mixer = Mamba2Mixer(heads=64, head_dim=64, groups=8, state=128, conv_kernel=4, chunk=128, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((2, 8192, 2688), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+                          jax.eval_shape(mixer.init, jax.random.PRNGKey(0), u))
+    layer = jax.checkpoint(lambda p, u: mixer.apply(p, u))
+    fn = lambda p, u: (lambda out, rms: jnp.sum(out.astype(jnp.float32) ** 2) + rms)(*layer(p, u))
+    compiled = jax.jit(jax.value_and_grad(fn, argnums=(0, 1)) if grad else fn).lower(params, u).compile()
+    text = compiled.as_text()
+    calls = [ln.split("=")[0].strip() for ln in text.split("\n") if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(name.split(".")[0] for name in calls) == (
+        ["%mamba2_scan_bwd", "%mamba2_scan_fwd", "%mamba2_scan_fwd"] if grad else ["%mamba2_scan_fwd"])
+    under_scan = [ln for ln in text.split("\n") if "ssm_scan" in ln]
+    assert under_scan and not [ln for ln in under_scan if "while(" in ln]
+    assert "while(" not in text and not re.search(r"f32\[[0-9,]*128,128,8\]", text)
+    assert not re.search(r"= f32\[(2,8192,4096|2048,8,8,512)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < (3.0e9 if grad else 1.2e9)   # 2.50 and 0.87 GB (PR 40)
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
 def test_chunked_delta_rule_compiles_for_v5e_and_holds_one_group_of_heads_at_a_time(one_chip, grad):
     """``ops.delta.chunked_delta_rule`` at qwen3_next's widths over 2 x 8,192 positions, lowered for a TPU, is its
     Pallas kernels (one forward; one more backward, and the forward once again for every chunk's starting state):

@@ -2,7 +2,9 @@
 (SURVEY.md §2.3 scatter_connection, §5 entity masked attention), and for the
 token model's gated delta rule (``ops/delta.py``: one Gated DeltaNet layer's
 rule at ``qwen3_next_train_b2s8k``'s shape under ``jax.checkpoint``, its two
-Pallas kernels beside the XLA form: PR 37's fragment).
+Pallas kernels beside the XLA form: PR 37's fragment; ``ops/ssm.py``: one
+Mamba-2 layer's scan at ``nemotron_twotower_train_b2s8k``'s shape, the same
+way: PR 40's). ``--ops`` names the fragments to run (all of them by default).
 
 Runs each kernel at actor-inference and learner-training shapes, in bf16 and
 f32, forward and forward+backward, against its jnp reference: first a
@@ -41,7 +43,10 @@ def _time(fn, args, iters=30, warmup=3):
     return (time.perf_counter() - t0) / iters * 1e6  # us
 
 
-def run(platform: str = "auto", iters: int = 30) -> dict:
+OPS = ("masked_attention", "scatter_add", "gated_delta_rule", "mamba2_scan")
+
+
+def run(platform: str = "auto", iters: int = 30, ops=OPS) -> dict:
     from distar_tpu.parallel.executor import select_backend
 
     select_backend(platform)
@@ -72,6 +77,8 @@ def run(platform: str = "auto", iters: int = 30) -> dict:
 
     def bench(op, shape, reference, impls, args, grad_argnums, tol):
         """Check every impl against ``reference`` (run in f32), then time it."""
+        if op not in ops:
+            return
         def grad_of(fn):
             return jax.jit(jax.grad(
                 lambda *a: sum(jnp.sum(x.astype(jnp.float32) ** 2) for x in jax.tree.leaves(fn(*a))),
@@ -164,6 +171,30 @@ def run(platform: str = "auto", iters: int = 30) -> dict:
         rule_args, (0, 1, 2, 3, 4), 4e-2,
     )
 
+    # one Mamba-2 layer's scan as nemotron_twotower_train_b2s8k runs it: 2 x 8,192 positions, 64 heads of 64 in 8
+    # groups, state 128, chunks of 128, bf16 products, dt and A drawn as the layer draws them, under jax.checkpoint
+    from distar_tpu.ops import ssm
+
+    b, S, H, P, G, N, Q = (2, 8192, 64, 64, 8, 128, 128) if native else (1, 80, 4, 64, 2, 128, 16)
+    scan_args = (
+        jnp.asarray(rng.standard_normal((b, S, H, P)), jnp.bfloat16),
+        jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), H)) * np.exp(0.5 * rng.standard_normal((b, S, H))),
+                    jnp.float32),
+        -jnp.arange(1.0, H + 1.0),
+        jnp.asarray(rng.standard_normal((b, S, G, N)), jnp.bfloat16),
+        jnp.asarray(rng.standard_normal((b, S, G, N)), jnp.bfloat16),
+    )
+    bench(
+        "mamba2_scan", f"{b}x{S}x{H}x{P} g{G} n{N} bfloat16",
+        lambda *a: ssm._scan_xla(*a, Q, jnp.float32),
+        {
+            "xla": jax.checkpoint(lambda *a: ssm._scan_xla(*a, Q, jnp.bfloat16)),
+            "pallas": jax.checkpoint(lambda x, dt, A, B, C: ssm._scan_kernel(x, dt, dt * A, B, C, Q,
+                                                                             resolve_interpret(None))),
+        },
+        scan_args, (0, 1, 2, 3, 4), 4e-2,
+    )
+
     dev = jax.devices()[0]
     return {
         "metric": "pallas-vs-xla kernel check + microbench",
@@ -182,8 +213,9 @@ def main() -> None:
     p.add_argument("--platform", default="auto", choices=("auto", "cpu", "tpu"))
     p.add_argument("--iters", type=int, default=30)
     p.add_argument("--out", default=None)
+    p.add_argument("--ops", default=",".join(OPS), help="comma-separated fragments to run")
     args = p.parse_args()
-    report = run(args.platform, args.iters)
+    report = run(args.platform, args.iters, tuple(args.ops.split(",")))
     for r in report["rows"]:
         print(f"  {r['op']:18s} {r['pass']:8s} {r['shape']:32s} {r['impl']:14s} "
               f"{r['us']:10.1f} us   x{r['speedup_vs_xla']:.2f}")
