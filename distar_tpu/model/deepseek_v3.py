@@ -51,6 +51,7 @@ from ..ops.moe import ExpertsHeldMoE
 from ..ops.sequence import LatentAttention, RMSNorm, SwiGLU
 from ..utils import Config
 from .config import cdtype, static_cfg
+from .token_decoder import decode, rms
 
 
 def default_deepseek_v3_config() -> Config:
@@ -105,7 +106,6 @@ class DecoderLayer(nn.Module):
                 held.offset, held.count, cfg.routed_scaling_factor, cfg.use_expert_bias,
                 cfg.rms_norm_eps, dtype, body="swiglu",
                 shared_width=cfg.n_shared_experts * cfg.moe_intermediate_size, name="moe")(x)
-        rms = lambda t: jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32))))
         x = x + ff
         return x, dict(stats, rms=rms(x), attn_rms=rms(attn), ff_rms=rms(ff))
 
@@ -126,26 +126,6 @@ class DeepseekV3(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        cfg, dtype = static_cfg(self.cfg), cdtype(self.cfg)
-        embedding = self.param("embedding", nn.initializers.normal(1.0),
-                               (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        with jax.named_scope("embed"):
-            x = embedding.astype(dtype)[tokens]
-        layer_cls = nn.remat(DecoderLayer) if cfg.remat else DecoderLayer
-        per_layer = []
-        for i in range(cfg.num_hidden_layers):
-            x, stats = layer_cls(self.cfg, i, name=f"layer_{i}")(x)
-            per_layer.append(stats)
-        with jax.named_scope("lm_head"):
-            h = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
-            head = self.param("lm_head", nn.initializers.normal(0.02),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            logits = jnp.einsum("bsd,dv->bsv", h, head.astype(dtype), preferred_element_type=jnp.float32)
-        moe = [s for s in per_layer if "rows" in s]
-        return logits, {
-            **{k: jnp.stack([s[k] for s in per_layer]) for k in ("rms", "attn_rms", "ff_rms")},
-            "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
-            "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-        }
+        cfg = static_cfg(self.cfg)
+        return decode(self, tokens, DecoderLayer, cfg.num_hidden_layers, eps=cfg.rms_norm_eps, embedding_scale=1.0,
+                      stacked=("rms", "attn_rms", "ff_rms"))

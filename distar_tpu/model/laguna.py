@@ -61,6 +61,7 @@ from ..ops.moe import ExpertsHeldMoE
 from ..ops.sequence import CausalGQAttention, RMSNorm, SwiGLU
 from ..utils import Config
 from .config import cdtype, static_cfg
+from .token_decoder import decode, rms
 
 YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "attention_factor")
 
@@ -135,7 +136,6 @@ class DecoderLayer(nn.Module):
                 cfg.moe_routed_scaling_factor, use_bias=False, eps=eps, dtype=dtype, body="swiglu",
                 shared_width=cfg.shared_expert_intermediate_size, scoring="softmax", name="moe")(x)
             stats.update(moe)
-        rms = lambda t: jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32))))
         x = x + ff
         return x, dict(stats, rms=rms(x), mixer_rms=rms(mixed), ff_rms=rms(ff))
 
@@ -157,31 +157,11 @@ class Laguna(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        cfg, dtype = static_cfg(self.cfg), cdtype(self.cfg)
+        cfg = static_cfg(self.cfg)
         layers = len(cfg.layer_types)
         if not (len(cfg.mlp_layer_types) == len(cfg.num_attention_heads_per_layer) == layers):
             raise ValueError("layer_types, mlp_layer_types and num_attention_heads_per_layer list the same layers")
         if not cfg.norm_topk_prob or cfg.gating != "per-head":
             raise ValueError("norm_topk_prob false or gating other than 'per-head': not this model's equations")
-        embedding = self.param("embedding", nn.initializers.normal(1.0),
-                               (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        with jax.named_scope("embed"):
-            x = embedding.astype(dtype)[tokens]
-        layer_cls = nn.remat(DecoderLayer) if cfg.remat else DecoderLayer
-        per_layer = []
-        for i in range(layers):
-            x, stats = layer_cls(self.cfg, i, name=f"layer_{i}")(x)
-            per_layer.append(stats)
-        with jax.named_scope("lm_head"):
-            h = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
-            head = self.param("lm_head", nn.initializers.normal(0.02),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            logits = jnp.einsum("bsd,dv->bsv", h, head.astype(dtype), preferred_element_type=jnp.float32)
-        moe = [s for s in per_layer if "rows" in s]
-        return logits, {
-            **{k: jnp.stack([s[k] for s in per_layer]) for k in ("rms", "mixer_rms", "ff_rms")},
-            "attn_gate_mean": {f"layer_{i}": s["attn_gate_mean"] for i, s in enumerate(per_layer)},
-            "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
-            **{k: sum(s[k] for s in moe) if moe else jnp.zeros((), jnp.int32)
-               for k in ("overflow", "buffer_rows", "row_indexed")},
-        }
+        return decode(self, tokens, DecoderLayer, layers, eps=cfg.rms_norm_eps, embedding_scale=1.0,
+                      stacked=("rms", "mixer_rms", "ff_rms"), by_layer=("attn_gate_mean",))

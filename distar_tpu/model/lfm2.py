@@ -34,6 +34,7 @@ from ..ops.moe import ExpertsHeldMoE
 from ..ops.sequence import CausalGQAttention, RMSNorm, ShortConv, SwiGLU
 from ..utils import Config
 from .config import cdtype, static_cfg
+from .token_decoder import decode, rms
 
 
 def default_lfm2_config() -> Config:
@@ -91,7 +92,6 @@ class DecoderLayer(nn.Module):
                 cfg.num_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
                 held.offset, held.count, cfg.routed_scaling_factor, cfg.use_expert_bias,
                 cfg.norm_eps, dtype, name="moe")(x)
-        rms = lambda t: jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32))))
         x = x + ff
         return x, dict(stats, rms=rms(x), ff_rms=rms(ff))
 
@@ -113,26 +113,6 @@ class LFM2(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        cfg, dtype = static_cfg(self.cfg), cdtype(self.cfg)
-        embedding = self.param("embedding", nn.initializers.normal(0.02),
-                               (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        with jax.named_scope("embed"):
-            x = embedding.astype(dtype)[tokens]
-        layer_cls = nn.remat(DecoderLayer) if cfg.remat else DecoderLayer
-        per_layer = []
-        for i in range(len(cfg.layer_types)):
-            x, stats = layer_cls(self.cfg, i, name=f"layer_{i}")(x)
-            per_layer.append(stats)
-        with jax.named_scope("lm_head"):
-            h = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-            logits = jnp.einsum("bsd,vd->bsv", h, embedding.astype(dtype),
-                                preferred_element_type=jnp.float32)
-        moe = [s for s in per_layer if "rows" in s]
-        return logits, {
-            "rms": jnp.stack([s["rms"] for s in per_layer]),
-            "ff_rms": jnp.stack([s["ff_rms"] for s in per_layer]),
-            "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
-            "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-        }
+        cfg = static_cfg(self.cfg)
+        return decode(self, tokens, DecoderLayer, len(cfg.layer_types), eps=cfg.norm_eps, tied=True,
+                      stacked=("rms", "ff_rms"))

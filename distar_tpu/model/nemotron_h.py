@@ -46,6 +46,7 @@ from ..ops.sequence import CausalGQAttention, RMSNorm
 from ..ops.ssm import Mamba2Mixer
 from ..utils import Config
 from .config import cdtype, static_cfg
+from .token_decoder import decode, rms
 
 
 def default_nemotron_h_config() -> Config:
@@ -112,7 +113,6 @@ class MixerLayer(nn.Module):
                 shared_width=cfg.moe_shared_expert_intermediate_size, name="moe")(x)
         else:
             raise ValueError(f"layer {self.index} of the pattern is {kind!r}: 'M', '*' or 'E'")
-        rms = lambda t: jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32))))
         x = x + out
         return x, dict(stats, rms=rms(x), mixer_rms=rms(out))
 
@@ -134,29 +134,6 @@ class NemotronH(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        cfg, dtype = static_cfg(self.cfg), cdtype(self.cfg)
-        embedding = self.param("embedding", nn.initializers.normal(0.02),
-                               (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        with jax.named_scope("embed"):
-            x = embedding.astype(dtype)[tokens]
-        layer_cls = nn.remat(MixerLayer) if cfg.remat else MixerLayer
-        per_layer = []
-        for i in range(len(cfg.hybrid_override_pattern)):
-            x, stats = layer_cls(self.cfg, i, name=f"layer_{i}")(x)
-            per_layer.append(stats)
-        with jax.named_scope("lm_head"):
-            h = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-            head = self.param("lm_head", nn.initializers.normal(0.02),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            logits = jnp.einsum("bsd,dv->bsv", h, head.astype(dtype), preferred_element_type=jnp.float32)
-        moe = [s for s in per_layer if "rows" in s]
-        return logits, {
-            "rms": jnp.stack([s["rms"] for s in per_layer]),
-            "mixer_rms": jnp.stack([s["mixer_rms"] for s in per_layer]),
-            "ssm_state_rms": {f"layer_{i}": s["ssm_state_rms"] for i, s in enumerate(per_layer)
-                              if "ssm_state_rms" in s},
-            "rows": jnp.stack([s["rows"] for s in moe]) if moe else jnp.zeros((0, 0), jnp.int32),
-            "overflow": sum(s["overflow"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "buffer_rows": sum(s["buffer_rows"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-            "row_indexed": sum(s["row_indexed"] for s in moe) if moe else jnp.zeros((), jnp.int32),
-        }
+        cfg = static_cfg(self.cfg)
+        return decode(self, tokens, MixerLayer, len(cfg.hybrid_override_pattern), eps=cfg.norm_eps,
+                      stacked=("rms", "mixer_rms"), by_layer=("ssm_state_rms",))

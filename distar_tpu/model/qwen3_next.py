@@ -59,6 +59,7 @@ from ..ops.moe import ExpertsHeldMoE
 from ..ops.sequence import CausalGQAttention, RMSNorm
 from ..utils import Config
 from .config import cdtype, static_cfg
+from .token_decoder import decode, rms
 
 
 def default_qwen3_next_config() -> Config:
@@ -125,7 +126,6 @@ class DecoderLayer(nn.Module):
             cfg.num_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size, held.offset, held.count,
             use_bias=False, eps=eps, dtype=dtype, body="swiglu", shared_width=cfg.shared_expert_intermediate_size,
             scoring="softmax", gated_shared=True, zero_centred=True, name="moe")(x)
-        rms = lambda t: jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32))))
         x = x + ff
         return x, dict(stats, **moe, rms=rms(x), mixer_rms=rms(mixed), ff_rms=rms(ff))
 
@@ -150,26 +150,9 @@ class Qwen3Next(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        cfg, dtype = static_cfg(self.cfg), cdtype(self.cfg)
+        cfg = static_cfg(self.cfg)
         if not cfg.norm_topk_prob:
             raise ValueError("norm_topk_prob false: the router renormalises over its picks (ops.moe.route)")
-        embedding = self.param("embedding", nn.initializers.normal(1.0),
-                               (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        with jax.named_scope("embed"):
-            x = embedding.astype(dtype)[tokens]
-        layer_cls = nn.remat(DecoderLayer) if cfg.remat else DecoderLayer
-        per_layer = []
-        for i in range(cfg.num_hidden_layers):
-            x, stats = layer_cls(self.cfg, i, name=f"layer_{i}")(x)
-            per_layer.append(stats)
-        with jax.named_scope("lm_head"):
-            h = RMSNorm(cfg.rms_norm_eps, zero_centred=True, name="final_norm")(x)
-            head = self.param("lm_head", nn.initializers.normal(0.02),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            logits = jnp.einsum("bsd,dv->bsv", h, head.astype(dtype), preferred_element_type=jnp.float32)
-        by_layer = lambda k: {f"layer_{i}": s[k] for i, s in enumerate(per_layer) if k in s}
-        return logits, {
-            **{k: jnp.stack([s[k] for s in per_layer]) for k in ("rms", "mixer_rms", "ff_rms", "rows")},
-            **{k: by_layer(k) for k in ("gdn_state_rms", "gdn_decay_mean", "attn_gate_mean")},
-            **{k: sum(s[k] for s in per_layer) for k in ("overflow", "buffer_rows", "row_indexed")},
-        }
+        return decode(self, tokens, DecoderLayer, cfg.num_hidden_layers, eps=cfg.rms_norm_eps, zero_centred=True,
+                      embedding_scale=1.0, stacked=("rms", "mixer_rms", "ff_rms"),
+                      by_layer=("gdn_state_rms", "gdn_decay_mean", "attn_gate_mean"))

@@ -71,11 +71,11 @@ from flax import linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .chunked_kernel import CHUNKS_A_STEP, F32, HIGHEST, NT, TN, kept_starts, mm, padded, rounded, walk
 from .sequence import causal_conv, conv_kernel_init, dense
 from .ssm import _dt_bias_init, state_rms
 
 Dtype = Any
-F32 = jnp.float32
 
 
 def _inverse(L):
@@ -134,10 +134,8 @@ def _rule_xla(q, k, v, g, beta, chunk: int, dtype: Dtype, groups: int):
     Hk, K = q.shape[2:]
     groups = math.gcd(groups, Hk)
     C = min(chunk, S)
-    pad = -S % C
-    if pad:
-        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v, g, beta))
-    nc = (S + pad) // C
+    q, k, v, g, beta = (padded(t, C) for t in (q, k, v, g, beta))
+    nc = q.shape[1] // C
 
     def by_group(t, heads, *rest):   # [b, S, heads, ...] -> [groups, b, nc, heads / groups, C, ...]
         t = t.reshape(b, nc, C, groups, heads // groups, *rest)
@@ -152,26 +150,13 @@ def _rule_xla(q, k, v, g, beta, chunk: int, dtype: Dtype, groups: int):
 
 
 # ------------------------------------------------------------- the rule as a kernel
-HIGHEST = jax.lax.Precision.HIGHEST
-NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))   # a b, a b^T, a^T b
 BLOCK = 16            # the diagonal blocks the kernel's inverse solves by substitution
-# chunks one grid step walks: a step's fixed cost is shared, and the compiler sees that many systems at once
-CHUNKS_A_STEP = 4
-
-
-def _mm(a, b, dims=NN, precision=None):
-    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=F32, precision=precision)
-
-
-def _rounded(scale, dtype):
-    """``scale`` as the XLA form multiplies by it: rounded to ``dtype``, held in float32."""
-    return scale.astype(dtype).astype(F32)
 
 
 def _times(x, scale, dtype):
     """``x * scale.astype(dtype)`` in ``dtype``, as the XLA form multiplies: the product of two ``dtype`` values,
     rounded once (computed in float32, where the product of two bfloat16 values is exact)."""
-    return (x.astype(F32) * _rounded(scale, dtype)).astype(dtype)
+    return (x.astype(F32) * rounded(scale, dtype)).astype(dtype)
 
 
 def _kernel_inverse(L):
@@ -201,7 +186,7 @@ def _kernel_inverse(L):
         # only the second block of every pair of blocks changes: its rows alone are multiplied
         blocks = [X[m:m + w] for m in range(0, C, w)]
         rows = jnp.concatenate(blocks[1::2], axis=0)
-        rows = rows - _mm(_mm(rows, by_system(below), precision=HIGHEST), by_system(X), precision=HIGHEST)
+        rows = rows - mm(mm(rows, by_system(below), precision=HIGHEST), by_system(X), precision=HIGHEST)
         blocks[1::2] = jnp.split(rows, list(range(w, rows.shape[0], w)))
         X = jnp.concatenate(blocks, axis=0)
         shift += 1
@@ -221,7 +206,7 @@ def _chunk_system(q, k, g, beta):
     gamma_row = jnp.sum(jnp.where(eye, gamma, 0.0), axis=0, keepdims=True)               # [1, C]
     total = gamma[C - 1:C]                                                                # [1, 1]
     Gamma = jnp.where(seen, jnp.exp(jnp.where(seen, gamma - gamma_row, 0.0)), 0.0)
-    kk, qk = _mm(k, k, NT), _mm(q, k, NT)
+    kk, qk = mm(k, k, NT), mm(q, k, NT)
     beta_c = column(beta)
     return dict(ii=ii, jj=jj, eye=eye, seen=seen, Gamma=Gamma, kk=kk, qk=qk, beta=beta_c,
                 L=jnp.where(ii > jj, beta_c * kk * Gamma, 0.0),
@@ -244,13 +229,13 @@ def _step_systems(qs, ks, g_ref, beta_ref, r, dtype):
 def _chunk_with_state(sy, q, k, v, S, dtype):
     """The rest of a chunk's forward pass: ``S`` [K, V] float32 the state it starts from."""
     vb, kb = _times(v, sy["beta"], dtype), _times(k, sy["beta"] * sy["decay"], dtype)
-    U, W = _mm(sy["Tb"], vb), _mm(sy["Tb"], kb).astype(dtype)
+    U, W = mm(sy["Tb"], vb), mm(sy["Tb"], kb).astype(dtype)
     Sb = S.astype(dtype)
-    new = (U - _mm(W, Sb)).astype(dtype)
+    new = (U - mm(W, Sb)).astype(dtype)
     k_to_end, q_decayed = _times(k, sy["to_end"], dtype), _times(q, sy["decay"], dtype)
     within = (sy["qk"] * sy["Gamma"]).astype(dtype)
-    o = _mm(q_decayed, Sb) + _mm(within, new)
-    after = sy["through"] * S + _mm(k_to_end, new, TN)
+    o = mm(q_decayed, Sb) + mm(within, new)
+    after = sy["through"] * S + mm(k_to_end, new, TN)
     return o, after, dict(vb=vb, kb=kb, W=W, Sb=Sb, new=new, k_to_end=k_to_end, q_decayed=q_decayed, within=within)
 
 
@@ -304,27 +289,27 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, d
                 sy[x] for x in ("T", "Tb", "Gamma", "kk", "qk", "beta", "decay", "to_end"))
             do, dSb = do_ref[0, rows, heads].astype(dtype), dS.astype(dtype)
             # o = q_decayed Sb + within new;  after = through S + k_to_end^T new;  new = U - W Sb
-            d_q_decayed, d_within = _mm(do, fw["Sb"], NT), _mm(do, fw["new"], NT)
-            d_new = (_mm(fw["within"], do, TN) + _mm(fw["k_to_end"], dSb)).astype(dtype)
-            d_k_to_end = _mm(fw["new"], dSb, NT)
-            d_W = (-_mm(d_new, fw["Sb"], NT)).astype(dtype)
-            dS_ref[h] = sy["through"] * dS + _mm(fw["q_decayed"], do, TN) - _mm(fw["W"], d_new, TN)
+            d_q_decayed, d_within = mm(do, fw["Sb"], NT), mm(do, fw["new"], NT)
+            d_new = (mm(fw["within"], do, TN) + mm(fw["k_to_end"], dSb)).astype(dtype)
+            d_k_to_end = mm(fw["new"], dSb, NT)
+            d_W = (-mm(d_new, fw["Sb"], NT)).astype(dtype)
+            dS_ref[h] = sy["through"] * dS + mm(fw["q_decayed"], do, TN) - mm(fw["W"], d_new, TN)
             d_through = jnp.sum(row_sum(dS * S), axis=0, keepdims=True)                   # [1, 1]
             # U = Tb vb;  W = Tb kb;  T = (I + L)^-1;  L = beta kk Gamma below the diagonal
-            d_T = _mm(d_new, fw["vb"], NT) + _mm(d_W, fw["kb"], NT)
-            d_vb, d_kb = _mm(Tb, d_new, TN), _mm(Tb, d_W, TN)
+            d_T = mm(d_new, fw["vb"], NT) + mm(d_W, fw["kb"], NT)
+            d_vb, d_kb = mm(Tb, d_new, TN), mm(Tb, d_W, TN)
             d_L = jnp.where(sy["ii"] > sy["jj"],
-                            -_mm(_mm(T, d_T, TN, precision=HIGHEST), T, NT, precision=HIGHEST), 0.0)
+                            -mm(mm(T, d_T, TN, precision=HIGHEST), T, NT, precision=HIGHEST), 0.0)
             d_L_Gamma = d_L * Gamma
             d_kk, d_qk = (d_L_Gamma * beta).astype(dtype), (d_within * Gamma).astype(dtype)
             d_exponent = d_L_Gamma * beta * kk + d_within * Gamma * qk      # the cotangent of gamma_i - gamma_j
             on_k, on_q = row_sum(d_kb * kf), row_sum(d_q_decayed * qf)
             ended = row_sum(d_k_to_end * kf) * to_end
-            dk[c] = dk[c] + (_mm(d_kk, k) + _mm(d_kk, k, TN) + _mm(d_qk, q, TN)
-                             + d_kb * _rounded(beta * decay, dtype)
-                             + d_k_to_end * _rounded(to_end, dtype))
-            dq[c] = dq[c] + _mm(d_qk, k) + d_q_decayed * _rounded(decay, dtype)
-            dv_ref[0, rows, heads] = (d_vb * _rounded(beta, dtype)).astype(dv_ref.dtype)
+            dk[c] = dk[c] + (mm(d_kk, k) + mm(d_kk, k, TN) + mm(d_qk, q, TN)
+                             + d_kb * rounded(beta * decay, dtype)
+                             + d_k_to_end * rounded(to_end, dtype))
+            dq[c] = dq[c] + mm(d_qk, k) + d_q_decayed * rounded(decay, dtype)
+            dv_ref[0, rows, heads] = (d_vb * rounded(beta, dtype)).astype(dv_ref.dtype)
             d_beta = row_sum(d_L_Gamma * kk) + row_sum(d_vb * v_ref[0, rows, heads].astype(F32)) + decay * on_k
             to_the_left = jnp.sum(jnp.where(sy["eye"], jnp.sum(d_exponent, axis=0, keepdims=True), 0.0), axis=1,
                                   keepdims=True)                                         # the column sums, as a column
@@ -338,62 +323,39 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, d
 
 
 def _kernel_call(kernel, operands, results, b, S, Hk, r, K, V, C, reverse, scratch, interpret, name):
-    """``pallas_call`` over (batch row, key head, step of ``CHUNKS_A_STEP`` chunks). Operands and results are named
-    by kind: ``key`` [b, S, Hk K], ``value`` [b, S, H V], ``gate`` [b, H, steps, n, C], ``state`` [b, H, K, V],
+    """``chunked_kernel.walk`` over (batch row, key head, step of ``CHUNKS_A_STEP`` chunks). Operands and results are
+    named by kind: ``key`` [b, S, Hk K], ``value`` [b, S, H V], ``gate`` [b, H, steps, n, C], ``state`` [b, H, K, V],
     ``states`` [b, H, steps, K, V]."""
     n = CHUNKS_A_STEP
-    steps = S // (n * C)
-    at = (lambda j: steps - 1 - j) if reverse else (lambda j: j)
-    specs = {"key": pl.BlockSpec((1, n * C, K), lambda i, h, j: (i, at(j), h)),
-             "value": pl.BlockSpec((1, n * C, r * V), lambda i, h, j: (i, at(j), h)),
-             "gate": pl.BlockSpec((1, r, 1, n, C), lambda i, h, j: (i, h, at(j), 0, 0)),
-             "state": pl.BlockSpec((1, r, K, V), lambda i, h, j: (i, h, 0, 0)),
-             "states": pl.BlockSpec((1, r, 1, K, V), lambda i, h, j: (i, h, at(j), 0, 0))}
-    return pl.pallas_call(
-        functools.partial(kernel, C=C, n=n, r=r, V=V, dtype=operands[0][1].dtype),
-        grid=(b, Hk, steps), in_specs=[specs[kind] for kind, _ in operands],
-        out_specs=[specs[kind] for kind, _ in results], out_shape=[shape for _, shape in results],
-        scratch_shapes=scratch, interpret=interpret, name=name,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*(x for _, x in operands))
+    specs = lambda at: {"key": pl.BlockSpec((1, n * C, K), lambda i, h, j: (i, at(j), h)),
+                        "value": pl.BlockSpec((1, n * C, r * V), lambda i, h, j: (i, at(j), h)),
+                        "gate": pl.BlockSpec((1, r, 1, n, C), lambda i, h, j: (i, h, at(j), 0, 0)),
+                        "state": pl.BlockSpec((1, r, K, V), lambda i, h, j: (i, h, 0, 0)),
+                        "states": pl.BlockSpec((1, r, 1, K, V), lambda i, h, j: (i, h, at(j), 0, 0))}
+    return walk(functools.partial(kernel, C=C, n=n, r=r, V=V, dtype=operands[0][1].dtype), specs, operands, results,
+                (b, Hk, S // (n * C)), reverse, scratch, interpret, name)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "keep_starts"))
 def _kernel_forward(q, k, v, g, beta, chunk: int, interpret: bool, keep_starts: bool):
-    """(Under ``jit`` so that a model's layers share ONE trace of the kernel's long body.) The operands in the
-    kernel's layouts (the sequence padded to whole steps with ``g = 0``, ``beta = 0``, which leave the state as it
-    is; heads side by side on the last axis, which is a reshape; ``g``/``beta`` a head a row, which moves 2 x 4 bytes
-    a position and head), the forward kernel, and its results in the caller's."""
+    """The operands in the kernel's layouts (the sequence padded to whole steps with ``g = 0``, ``beta = 0``, which
+    leave the state as it is; heads side by side on the last axis, which is a reshape; ``g``/``beta`` a head a row,
+    which moves 2 x 4 bytes a position and head), the forward kernel, and its results in the caller's."""
     b, S, H, V = v.shape
     Hk, K = q.shape[2:]
-    C, r = chunk, H // Hk
-    pad = -S % (C * CHUNKS_A_STEP)
-    padded = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) if pad else t
-    Sp = S + pad
-    flat = lambda t: padded(t).reshape(b, Sp, -1)
-    gate = lambda t: padded(t.astype(F32)).swapaxes(1, 2).reshape(b, H, Sp // (C * CHUNKS_A_STEP), CHUNKS_A_STEP, C)
+    C, r, step = chunk, H // Hk, chunk * CHUNKS_A_STEP
+    Sp = S + -S % step
+    flat = lambda t: padded(t, step).reshape(b, Sp, -1)
+    gate = lambda t: padded(t.astype(F32), step).swapaxes(1, 2).reshape(b, H, Sp // step, CHUNKS_A_STEP, C)
     operands = [("key", flat(q)), ("key", flat(k)), ("value", flat(v)), ("gate", gate(g)), ("gate", gate(beta))]
     shape = jax.ShapeDtypeStruct
     results = [("value", shape((b, Sp, H * V), F32)), ("state", shape((b, H, K, V), F32))]
     if keep_starts:
-        results.append(("states", shape((b, H, Sp // (C * CHUNKS_A_STEP), K, V), F32)))
+        results.append(("states", shape((b, H, Sp // step, K, V), F32)))
     out = _kernel_call(_forward_kernel, operands, results, b, Sp, Hk, r, K, V, C, False, [], interpret,
                        "gated_delta_rule_fwd")
     return (out[0][:, :S].reshape(b, S, H, V), out[1]), ([x for _, x in operands], out[2] if keep_starts else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _rule_kernel(q, k, v, g, beta, chunk: int, interpret: bool):
-    """``chunked_delta_rule`` as two Pallas kernels: ``q``/``k``/``v`` in the products' dtype, ``g``/``beta``
-    float32. The backward pass keeps the five operands and the state every grid step starts from."""
-    return _kernel_forward(q, k, v, g, beta, chunk, interpret, False)[0]
-
-
-def _rule_kernel_fwd(q, k, v, g, beta, chunk, interpret):
-    return _kernel_forward(q, k, v, g, beta, chunk, interpret, True)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
 def _rule_kernel_bwd(chunk, interpret, kept, cotangents):
     (q, k, v, g, beta), starts = kept
     do, dlast = cotangents
@@ -413,7 +375,10 @@ def _rule_kernel_bwd(chunk, interpret, kept, cotangents):
     return heads(dq, Hk), heads(dk, Hk), heads(dv, H), ungate(dg), ungate(dbeta)
 
 
-_rule_kernel.defvjp(_rule_kernel_fwd, _rule_kernel_bwd)
+# ``chunked_delta_rule`` as two Pallas kernels, ``(q, k, v, g, beta, chunk, interpret)``: ``q``/``k``/``v`` in the
+# products' dtype, ``g``/``beta`` float32. The backward pass keeps the five operands and the state every grid step
+# starts from
+_rule_kernel = kept_starts(_kernel_forward, _rule_kernel_bwd)
 
 
 def kernel_takes(K: int, V: int, chunk: int) -> bool:

@@ -63,6 +63,7 @@ from flax import linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .chunked_kernel import CHUNKS_A_STEP, F32, NN, NT, TN, kept_starts, mm, padded, rounded, walk
 from .sequence import causal_conv, conv_kernel_init, dense
 
 Dtype = Any
@@ -105,10 +106,8 @@ def _scan_xla(x, dt, A, B, C, chunk: int, dtype: Dtype):
     b, S, H, P = x.shape
     G, N = B.shape[2:]
     Q = min(chunk, S)
-    pad = -S % Q
-    if pad:
-        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (x, dt, B, C))
-    nc = (S + pad) // Q
+    x, dt, B, C = (padded(t, Q) for t in (x, dt, B, C))
+    nc = x.shape[1] // Q
     by_group = lambda t, *rest: jnp.moveaxis(t.reshape(b, nc, Q, G, *rest), 3, 0)
     y, last = jax.lax.map(
         lambda group: jax.checkpoint(functools.partial(_group_scan, dtype=dtype))(*group),
@@ -119,16 +118,7 @@ def _scan_xla(x, dt, A, B, C, chunk: int, dtype: Dtype):
 
 
 # ------------------------------------------------------------- the scan as a kernel
-F32 = jnp.float32
-HIGHEST = jax.lax.Precision.HIGHEST
-NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))   # a b, a b^T, a^T b
 LANES = 128
-# chunks one grid step walks: a step's fixed cost is shared, and the scheduler sees that many chunks' work at once
-CHUNKS_A_STEP = 4
-
-
-def _mm(a, b, dims=NN, precision=None):
-    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=F32, precision=precision)
 
 
 def _exact(a, b, dims=NN, ones=1):
@@ -141,12 +131,7 @@ def _exact(a, b, dims=NN, ones=1):
         pieces.append(value.astype(jnp.bfloat16))
         value = value - pieces[-1].astype(F32)
     bits = (a, b)[ones].astype(jnp.bfloat16)
-    return sum(_mm(bits, piece, dims) if ones == 0 else _mm(piece, bits, dims) for piece in pieces)
-
-
-def _rounded(scale, dtype):
-    """``scale`` as the XLA form multiplies by it: rounded to ``dtype``, held in float32."""
-    return scale.astype(dtype).astype(F32)
+    return sum(mm(bits, piece, dims) if ones == 0 else mm(piece, bits, dims) for piece in pieces)
 
 
 def _chunk_decays(dt, dta):
@@ -205,8 +190,8 @@ def _weights(ch, cb, h, dtype):
 def _state_after(ch, Bc, x, S, t, k, P, dtype):
     """A tile's states [N, k P] float32 (transposed: the state size on the sublanes, ``k`` heads' ``P`` side by
     side on the lanes, so that one product serves them all) after the chunk."""
-    xe = (x.astype(F32) * _rounded(_wide(ch["to_end"], t, k, P), dtype)).astype(dtype)
-    return _wide(ch["through"], t, k, P) * S + _mm(Bc, xe, TN)
+    xe = (x.astype(F32) * rounded(_wide(ch["to_end"], t, k, P), dtype)).astype(dtype)
+    return _wide(ch["through"], t, k, P) * S + mm(Bc, xe, TN)
 
 
 def _forward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, y_ref, last_ref, *starts_ref, Q, n, k, P, dtype):
@@ -222,13 +207,13 @@ def _forward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, y_ref, last_ref, *star
     for c in range(n):
         rows = slice(c * Q, (c + 1) * Q)
         Bc, Cc = B_ref[0, rows], C_ref[0, rows]
-        ch, cb = _chunk_decays(dt_ref[0, 0, 0, c], dta_ref[0, 0, 0, c]), _mm(Cc, Bc, NT)
+        ch, cb = _chunk_decays(dt_ref[0, 0, 0, c], dta_ref[0, 0, 0, c]), mm(Cc, Bc, NT)
         for t in range(last_ref.shape[2]):
             lanes = slice(t * k * P, (t + 1) * k * P)
             x, S = x_ref[0, rows, lanes], last_ref[0, 0, t]
-            y = _wide(ch["decay"], t, k, P) * _mm(Cc, S.astype(dtype))
+            y = _wide(ch["decay"], t, k, P) * mm(Cc, S.astype(dtype))
             for j in range(k):
-                y = y + _mm(_weights(ch, cb, t * k + j, dtype)[1], _of_head(x, j, k, P))
+                y = y + mm(_weights(ch, cb, t * k + j, dtype)[1], _of_head(x, j, k, P))
             y_ref[0, rows, lanes] = y
             last_ref[0, 0, t] = _state_after(ch, Bc, x, S, t, k, P, dtype)
 
@@ -260,7 +245,7 @@ def _backward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, starts_ref, dy_ref, d
     for c in reversed(range(n)):
         rows, ch = pieces[c], chunks[c]
         Bc, Cc = B_ref[0, rows], C_ref[0, rows]
-        cb = _mm(Cc, Bc, NT)
+        cb = mm(Cc, Bc, NT)
         d_cb, dB, dC = jnp.zeros((Q, Q), F32), 0.0, 0.0
         seen_by_i, seen_by_j, through_s = jnp.zeros((Q, r), F32), jnp.zeros((Q, r), F32), jnp.zeros((1, r), F32)
         weight_sums = jnp.zeros((r, Q), F32)                  # sum_i dW_ij exp(a_i - a_j) (C_i . B_j), a head a row
@@ -268,21 +253,21 @@ def _backward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, starts_ref, dy_ref, d
             lanes = slice(t * k * P, (t + 1) * k * P)
             x, S, dS, dy = x_ref[0, rows, lanes], states[c][t], dS_ref[t], dy_ref[0, rows, lanes]
             Sb, dSb, dyb = S.astype(dtype), dS.astype(dtype), dy.astype(dtype)
-            decay, to_end = _wide(ch["decay"], t, k, P), _rounded(_wide(ch["to_end"], t, k, P), dtype)
+            decay, to_end = _wide(ch["decay"], t, k, P), rounded(_wide(ch["to_end"], t, k, P), dtype)
             xe = (x.astype(F32) * to_end).astype(dtype)
             # y = decay (C Sb) + sum_heads W x;   after = through S + B^T xe
-            held = _mm(Cc, Sb)
+            held = mm(Cc, Sb)
             d_held = (dy * decay).astype(dtype)               # the cotangent of C Sb
-            dC = dC + _mm(d_held, Sb, NT)
-            d_xe = _mm(Bc, dSb)
-            dB = dB + _mm(xe, dSb, NT)
+            dC = dC + mm(d_held, Sb, NT)
+            d_xe = mm(Bc, dSb)
+            dB = dB + mm(xe, dSb, NT)
             dx = d_xe * to_end
             for j in range(k):
                 h = t * k + j
                 E, W = _weights(ch, cb, h, dtype)
                 dy_h, dt_row = _of_head(dyb, j, k, P), ch["dt_rows"][h:h + 1]
-                dx = dx + _mm(W, dy_h, TN)
-                dE = _mm(dy_h, x, NT) * E                     # dW_ij exp(a_i - a_j)
+                dx = dx + mm(W, dy_h, TN)
+                dE = mm(dy_h, x, NT) * E                      # dW_ij exp(a_i - a_j)
                 d_cb = d_cb + dE * dt_row
                 d_span = dE * cb                              # dW_ij W_ij / dt_j
                 weight_sums = jnp.where(head_row == h, jnp.sum(d_span, axis=0, keepdims=True), weight_sums)
@@ -291,10 +276,10 @@ def _backward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, starts_ref, dy_ref, d
             seen_by_i = seen_by_i + _exact(dy * decay * held, by_heads[t])        # a_i under exp(a_i) alone
             seen_by_j = seen_by_j + _exact(d_xe * x.astype(F32), by_heads[t])     # <d_xe_j, x_j>
             through_s = through_s + _exact(jnp.sum(dS * S, axis=0, keepdims=True), by_heads[t])
-            dS_ref[t] = _wide(ch["through"], t, k, P) * dS + _mm(Cc, d_held, TN)
+            dS_ref[t] = _wide(ch["through"], t, k, P) * dS + mm(Cc, d_held, TN)
         d_cb = d_cb.astype(dtype)
-        dB_ref[0, rows] = (dB + _mm(d_cb, Cc, TN)).astype(dB_ref.dtype)
-        dC_ref[0, rows] = (dC + _mm(d_cb, Bc)).astype(dC_ref.dtype)
+        dB_ref[0, rows] = (dB + mm(d_cb, Cc, TN)).astype(dB_ref.dtype)
+        dC_ref[0, rows] = (dC + mm(d_cb, Bc)).astype(dC_ref.dtype)
         # a_j enters exp(a_Q - a_j) dt_j (to_end) and, under the weights, -a_j; a_Q enters to_end and exp(a_Q)
         ended = seen_by_j * ch["to_end"]
         at_end = jnp.sum(ended, axis=0, keepdims=True) + through_s * ch["through"]
@@ -306,41 +291,31 @@ def _backward_kernel(x_ref, dt_ref, dta_ref, B_ref, C_ref, starts_ref, dy_ref, d
 
 
 def _kernel_call(kernel, operands, results, b, S, G, r, P, N, Q, reverse, scratch, interpret, name):
-    """``pallas_call`` over (batch row, group of heads, step of ``CHUNKS_A_STEP`` chunks). Operands and results
-    are named by kind: ``heads`` [b, S, H P], ``shared`` [b, S, G N], ``gate`` [b, G, steps, n, r, Q], ``state``
-    [b, G, T, N, k P], ``states`` [b, G, steps, T, N, k P]."""
+    """``chunked_kernel.walk`` over (batch row, group of heads, step of ``CHUNKS_A_STEP`` chunks). Operands and
+    results are named by kind: ``heads`` [b, S, H P], ``shared`` [b, S, G N], ``gate`` [b, G, steps, n, r, Q],
+    ``state`` [b, G, T, N, k P], ``states`` [b, G, steps, T, N, k P]."""
     n, k = CHUNKS_A_STEP, LANES // P
-    steps, T = S // (n * Q), r // k
-    at = (lambda j: steps - 1 - j) if reverse else (lambda j: j)
-    specs = {"heads": pl.BlockSpec((1, n * Q, r * P), lambda i, g, j: (i, at(j), g)),
-             "shared": pl.BlockSpec((1, n * Q, N), lambda i, g, j: (i, at(j), g)),
-             "gate": pl.BlockSpec((1, 1, 1, n, r, Q), lambda i, g, j: (i, g, at(j), 0, 0, 0)),
-             "state": pl.BlockSpec((1, 1, T, N, k * P), lambda i, g, j: (i, g, 0, 0, 0)),
-             "states": pl.BlockSpec((1, 1, 1, T, N, k * P), lambda i, g, j: (i, g, at(j), 0, 0, 0))}
-    return pl.pallas_call(
-        functools.partial(kernel, Q=Q, n=n, k=k, P=P, dtype=operands[0][1].dtype),
-        grid=(b, G, steps), in_specs=[specs[kind] for kind, _ in operands],
-        out_specs=[specs[kind] for kind, _ in results], out_shape=[shape for _, shape in results],
-        scratch_shapes=scratch, interpret=interpret, name=name,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*(x for _, x in operands))
+    T = r // k
+    specs = lambda at: {"heads": pl.BlockSpec((1, n * Q, r * P), lambda i, g, j: (i, at(j), g)),
+                        "shared": pl.BlockSpec((1, n * Q, N), lambda i, g, j: (i, at(j), g)),
+                        "gate": pl.BlockSpec((1, 1, 1, n, r, Q), lambda i, g, j: (i, g, at(j), 0, 0, 0)),
+                        "state": pl.BlockSpec((1, 1, T, N, k * P), lambda i, g, j: (i, g, 0, 0, 0)),
+                        "states": pl.BlockSpec((1, 1, 1, T, N, k * P), lambda i, g, j: (i, g, at(j), 0, 0, 0))}
+    return walk(functools.partial(kernel, Q=Q, n=n, k=k, P=P, dtype=operands[0][1].dtype), specs, operands, results,
+                (b, G, S // (n * Q)), reverse, scratch, interpret, name)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "keep_starts"))
 def _kernel_forward(x, dt, dta, B, C, chunk: int, interpret: bool, keep_starts: bool):
-    """(Under ``jit`` so that a model's layers share ONE trace of the kernel's long body.) The operands where they
-    lie (heads side by side on the last axis, which is a reshape) but for ``dt`` and ``dt A``, which go a head a
-    row (4 bytes a position and head each); the sequence padded to whole steps with ``dt = 0``, which leaves the
-    state as it is; the forward kernel, and its results in the caller's layouts."""
+    """The operands where they lie (heads side by side on the last axis, which is a reshape) but for ``dt`` and
+    ``dt A``, which go a head a row (4 bytes a position and head each); the sequence padded to whole steps with
+    ``dt = 0``, which leaves the state as it is; the forward kernel, and its results in the caller's layouts."""
     b, S, H, P = x.shape
     G, N = B.shape[2:]
     Q, n, r, k = chunk, CHUNKS_A_STEP, H // G, LANES // P
-    pad = -S % (Q * n)
-    padded = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) if pad else t
-    Sp = S + pad
+    Sp = S + -S % (Q * n)
     steps, T = Sp // (Q * n), r // k
-    flat = lambda t: padded(t).reshape(b, Sp, -1)
-    gate = lambda t: padded(t).reshape(b, steps, n, Q, G, r).transpose(0, 4, 1, 2, 5, 3)
+    flat = lambda t: padded(t, Q * n).reshape(b, Sp, -1)
+    gate = lambda t: padded(t, Q * n).reshape(b, steps, n, Q, G, r).transpose(0, 4, 1, 2, 5, 3)
     operands = [("heads", flat(x)), ("gate", gate(dt)), ("gate", gate(dta)), ("shared", flat(B)), ("shared", flat(C))]
     shape = jax.ShapeDtypeStruct
     results = [("heads", shape((b, Sp, H * P), F32)), ("state", shape((b, G, T, N, k * P), F32))]
@@ -352,19 +327,6 @@ def _kernel_forward(x, dt, dta, B, C, chunk: int, interpret: bool, keep_starts: 
     return (out[0][:, :S].reshape(b, S, H, P), last), ([x for _, x in operands], out[2] if keep_starts else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _scan_kernel(x, dt, dta, B, C, chunk: int, interpret: bool):
-    """``chunked_scan`` as two Pallas kernels: ``x``/``B``/``C`` in the products' dtype, ``dt`` and ``dta`` (``dt
-    A``) float32 (``A``'s gradient is the chain rule's, outside). The backward pass keeps the five operands and the
-    states every grid step starts from."""
-    return _kernel_forward(x, dt, dta, B, C, chunk, interpret, False)[0]
-
-
-def _scan_kernel_fwd(x, dt, dta, B, C, chunk, interpret):
-    return _kernel_forward(x, dt, dta, B, C, chunk, interpret, True)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
 def _scan_kernel_bwd(chunk, interpret, kept, cotangents):
     (x, dt, dta, B, C), starts = kept
     dy, dlast = cotangents
@@ -384,7 +346,10 @@ def _scan_kernel_bwd(chunk, interpret, kept, cotangents):
             dC[:, :S].reshape(b, S, G, N))
 
 
-_scan_kernel.defvjp(_scan_kernel_fwd, _scan_kernel_bwd)
+# ``chunked_scan`` as two Pallas kernels, ``(x, dt, dta, B, C, chunk, interpret)``: ``x``/``B``/``C`` in the products'
+# dtype, ``dt`` and ``dta`` (``dt A``) float32 (``A``'s gradient is the chain rule's, outside). The backward pass keeps
+# the five operands and the states every grid step starts from
+_scan_kernel = kept_starts(_kernel_forward, _scan_kernel_bwd)
 
 
 def kernel_takes(P: int, N: int, G: int, H: int, chunk: int) -> bool:

@@ -135,6 +135,16 @@ def _bound_compiled_program_accumulation():
     jax.clear_caches()
 
 
+@pytest.fixture
+def executables_dropped():
+    """For the tests that run a Pallas kernel interpreted (``tests/test_qwen3_next.py``, ``tests/test_nemotron_h.py``):
+    such kernels are long programs, and every one XLA:CPU loads holds a few thousand memory mappings until its ``jit``
+    is dropped. A dozen of them took a test file's process to the kernel's limit of 65,530 (``vm.max_map_count``) and a
+    later test's compile died of it (PERF.md section 7.29 (e))."""
+    yield
+    jax.clear_caches()
+
+
 # shared tiny flagship-shaped model config for learner/actor tests (several
 # older test files still carry local copies; new tests should import this)
 SMALL_MODEL = {

@@ -150,14 +150,6 @@ KERNEL_CASES = {"two_whole_grid_steps": dict(S=128), "steps_and_a_part_padded_wi
                 "the_published_chunk": dict(S=130, chunk=128, b=1, H=2, G=1)}
 
 
-@pytest.fixture
-def executables_dropped():
-    """The interpreted kernels are long programs, and every one XLA:CPU loads holds a few thousand memory mappings
-    until its ``jit`` is dropped (``tests/test_qwen3_next.py::executables_dropped``; PERF.md section 7.29 (e))."""
-    yield
-    jax.clear_caches()
-
-
 def kernel_scan(chunk):
     return lambda x, dt, A, B, C: ssm._scan_kernel(x, dt, dt * A, B, C, chunk, True)
 

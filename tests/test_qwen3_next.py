@@ -113,15 +113,6 @@ KERNEL_CASES = {**{name: dict(kw, chunk=64 if kw["chunk"] == 64 else 16) for nam
                 "chunks_of_three_blocks": dict(S=100, chunk=48)}     # the inverse merges blocks (0, 1), then 2 alone
 
 
-@pytest.fixture
-def executables_dropped():
-    """The interpreted kernels are long programs, and every one XLA:CPU loads holds a few thousand memory mappings
-    until its ``jit`` is dropped: the dozen of them took this file's process to the kernel's limit of 65,530
-    (``vm.max_map_count``) and a later test's compile died of it."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.mark.parametrize("case", KERNEL_CASES)
 def test_the_kernel_in_interpret_mode_is_the_literal_recurrence_forward_and_backward(case, executables_dropped):
     """``ops.delta._rule_kernel`` (the forward and the backward Pallas kernel, interpreted on the CPU) on two
