@@ -1,5 +1,5 @@
 """Token-sequence learner: next-token training of a token model
-(``model.TOKEN_MODELS``: ``LFM2``, ``NemotronH``, ``DeepseekV3``, ``Qwen3Next``, ``Laguna``; the config's
+(``model.TOKEN_MODELS``: ``LFM2``, ``NemotronH``, ``DeepseekV3``, ``Qwen3Next``, ``Laguna``, ``Phi4Flash``; the config's
 ``model.model_type`` says which, ``lfm2_moe`` when it says nothing) on
 ``BaseLearner``'s run loop, feeder, optimizer, dynamics tree and checkpoints.
 
@@ -17,7 +17,7 @@ Started through the ordinary launcher, which resolves a learner by pipeline
   python -m distar_tpu.bin.sl_train --pipeline distar_tpu.learner.lm_learner \\
       --config configs/lfm2_24b_a2b_v5e.yaml --iters N
       (or configs/nemotron_twotower_30b_a3b_v5e.yaml, configs/kimi_vl_a3b_v5e.yaml,
-      configs/qwen3_next_80b_a3b_v5e.yaml, configs/laguna_s_v5e.yaml)
+      configs/qwen3_next_80b_a3b_v5e.yaml, configs/laguna_s_v5e.yaml, configs/phi4_mini_flash_v5e.yaml)
 
 With no ``set_dataloader`` it trains on ``FakeTokenDataloader`` (Zipf ids).
 """
@@ -119,7 +119,8 @@ def _flat_log(info: Dict[str, Any], moe_layers) -> Dict[str, float]:
     ``moe_rows_sum/layer_<i>`` and ``moe_rows_max/layer_<i>`` over them; a
     statistic that only some layers have comes as a dict by layer
     (``ssm_state_rms/layer_<i>``; ``gdn_state_rms/``, ``gdn_decay_mean/`` and
-    ``attn_gate_mean/layer_<i>`` of ``qwen3_next``; ``attn_gate_mean/`` of every ``laguna`` layer)."""
+    ``attn_gate_mean/layer_<i>`` of ``qwen3_next``; ``attn_gate_mean/`` of every ``laguna`` layer;
+    ``ssm_state_rms/`` and ``diff_lambda/layer_<i>`` of ``phi4flash``, whose ``memory_rms`` is one number)."""
     log = {}
     for k, v in info.items():
         if isinstance(v, dict):

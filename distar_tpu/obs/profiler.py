@@ -121,14 +121,17 @@ STEP_SCOPES = (
 
 # The token-sequence learner's step (``lm_train_step``): the models' parts
 # (``model/lfm2.py``, ``model/nemotron_h.py``, ``model/deepseek_v3.py``,
-# ``model/qwen3_next.py``, ``model/laguna.py``, ``ops/moe.py``, ``ops/ssm.py``,
+# ``model/qwen3_next.py``, ``model/laguna.py``, ``model/phi4flash.py``, ``ops/moe.py``, ``ops/ssm.py``,
 # ``ops/delta.py``, ``ops/sequence.py``; a model has the parts its layers have)
 # and the three names every step has. ``mla_core`` is latent attention's kernel
 # alone, ``mla_proj`` everything else of that layer; ``gdn_scan`` is the chunked
 # gated delta rule alone, ``gdn_proj`` everything else of a Gated DeltaNet layer.
 # ``attn_core`` is grouped-query attention's full-causal kernel alone, ``swa_core``
 # its banded kernel alone, ``attn_proj`` everything else of such a layer: the
-# names of a model whose layers are not under ``attention`` as a whole (``laguna``).
+# names of a model whose layers are not under ``attention`` as a whole (``laguna``,
+# ``phi4flash``). ``mamba1_scan`` is Mamba-1's selective scan alone, ``mamba1_proj``
+# everything else of such a layer; ``gmu`` a gated memory unit; ``cross_core`` the
+# kernel of an attention layer that reads an earlier layer's keys and values.
 LM_STEP_SCOPES = (
     "embed", "short_conv", "attention", "dense_mlp",
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head",
@@ -137,6 +140,7 @@ LM_STEP_SCOPES = (
     "mla_proj", "mla_core",
     "gdn_proj", "gdn_scan",
     "attn_proj", "attn_core", "swa_core",
+    "mamba1_proj", "mamba1_scan", "gmu", "cross_core",
 )
 
 SPAN_PREFIX = "distar:"

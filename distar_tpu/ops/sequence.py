@@ -1,13 +1,16 @@
-"""Layers of a causal token-sequence model: RMSNorm, rotary positions, the
-depthwise causal convolution, the gated short convolution, causal
-grouped-query attention and multi-head latent attention.
+"""Layers of a causal token-sequence model: RMSNorm and LayerNorm, rotary
+positions, the depthwise causal convolution, the gated short convolution,
+causal grouped-query attention, differential attention and multi-head latent
+attention.
 
 These are the operators the token models share (``model/lfm2.py``,
 ``model/nemotron_h.py``, ``model/deepseek_v3.py``, ``model/qwen3_next.py``,
-``model/laguna.py``; the state-space mixer is ``ops/ssm.py``, the gated delta
-rule ``ops/delta.py``). Parameters
+``model/laguna.py``, ``model/phi4flash.py``; the state-space mixers are
+``ops/ssm.py``, the gated delta rule ``ops/delta.py``). Parameters
 are float32; ``dtype`` is the compute dtype of the matrix products. No
-projection has a bias. Sequences are ``[B, S, d]``, position 0 first.
+projection has a bias but ``phi4flash``'s attention projections (``dense(...,
+bias=True)``), and every norm is an RMSNorm but that model's (``LayerNorm``,
+with a bias). Sequences are ``[B, S, d]``, position 0 first.
 
 Attention over ``S`` positions never holds an ``S x S`` score tensor per
 head. Which code computes it follows from the platform the program is being
@@ -23,10 +26,17 @@ chip measured (``core_plan``). Anywhere else a loop over query blocks under
 ``jax.checkpoint`` (every block multiplies against all keys and masks, so it
 does twice the causal work).
 
-With a ``window`` (``laguna``'s sliding layers: query ``i`` sees the keys ``i
-- window < j <= i``) the work is the band's: on a TPU the same kernel with a
+With a ``window`` (``laguna``'s sliding layers and ``phi4flash``'s: query ``i``
+sees the keys ``i - window < j <= i``) the work is the band's: on a TPU the same kernel with a
 local mask, which visits only the key blocks that meet the band, forward and
 backward; anywhere else the same loop over query blocks with the band in its mask.
+
+``DifferentialAttention`` (``phi4flash``) is two softmaxes a pair of heads over
+a value twice a head wide, ``a1 - lambda a2`` under an RMSNorm over the pair;
+it hands ``causal_attention`` ONE call a layer (a key head with the two query
+heads of its parity in its pair, the pair's value as its value head: the core
+takes a value head of its own size), with its keys and values projected or,
+in a cross-attention layer, handed in from the layer that made them.
 """
 from __future__ import annotations
 
@@ -92,13 +102,21 @@ def core_plan(S: int, D: int, Dv: int, window: Optional[int] = None) -> CorePlan
     16 heads of 192/128, 2 x 8,192) 1,024-tiles fused, 26.7 ms against 34.2 at 512 with its own dQ kernel
     (PR 31); a ``window`` (``laguna``, 36 x 128 over 4, a band of 512 in 16,384) 512-tiles and a dQ kernel of
     its own, 14.2 ms against 29.0 fused, since the fused kernel's dQ copies are the whole sequence's whatever
-    the mask (4.8 GB there), and 17-21 at every other tile (PR 38). Between the measured shapes the nearest
+    the mask (4.8 GB there), and 17-21 at every other tile (PR 38).
+
+    A score head NARROWER than its value head (``phi4flash``'s differential attention: 20 key heads of 64, two
+    query heads each, the pair's value of 128; 2 x 8,192; ms a layer as in the table, PR 42) goes by the
+    table's own row: 1,024/2,048 c 512 fused 38.4, 1,024/1,024 c 512 38.6, 1,024/2,048 c 1,024 39.0, 1,024/1,024
+    whole (latent attention's row) 39.3, 1,024/1,024 with its own dQ kernel 47.9, 512-tiles 48.7, query blocks of
+    2,048 refused for vector memory. Under the band of 512 the window's row: 512-tiles with a dQ kernel of its
+    own 14.6, multiplied 256 keys at a time 15.4, 256/512 18.5, 1,024/512 18.8, 512/1,024 18.9, 1,024-tiles 20.7,
+    256-tiles 21.6, 512-tiles fused 23.6. Between the measured shapes the nearest
     row serves, cut to tiles that divide ``S`` (128 divides it: ``causal_attention`` sees to that)."""
     if window is not None:
         q, kv, compute, fused = 512, 512, 512, False
     elif max(D, Dv) >= 256:
         q, kv, compute, fused = 512, 512, 512, True
-    elif D != Dv:
+    elif D > Dv:
         q, kv, compute, fused = 1024, 1024, 1024, True
     else:
         q, kv, compute, fused = 1024, 2048, 512, True
@@ -133,9 +151,25 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(x.dtype)
 
 
-def dense(features: int, dtype: Dtype, name: str) -> nn.Dense:
-    """A bias-free projection with float32 parameters and ``dtype`` products."""
-    return nn.Dense(features, use_bias=False, dtype=dtype, param_dtype=jnp.float32,
+class LayerNorm(nn.Module):
+    """``(x - mean(x)) / sqrt(var(x) + eps) * scale + bias`` over the last axis, in float32 (``phi4flash``:
+    every norm of that model)."""
+
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        y = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + self.eps)
+        return (y * scale + bias).astype(x.dtype)
+
+
+def dense(features: int, dtype: Dtype, name: str, bias: bool = False) -> nn.Dense:
+    """A projection with float32 parameters and ``dtype`` products, without a bias unless ``bias`` (zero at init)."""
+    return nn.Dense(features, use_bias=bias, dtype=dtype, param_dtype=jnp.float32,
                     kernel_init=nn.initializers.normal(0.02), name=name)
 
 
@@ -386,6 +420,59 @@ class CausalGQAttention(nn.Module):
                 return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))
             out, opened = open_gate(out.reshape(B, S, H, D), gate)
             return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D)), opened
+
+
+class DifferentialAttention(nn.Module):
+    """Differential attention (``phi4flash``): the heads pair up, query pair ``j`` = query heads ``(2j, 2j + 1)``
+    reads key/value pair ``j // (heads / kv_heads)`` = key heads ``(k1, k2)`` and the value ``V = [v1, v2]``,
+    ``2 head_dim`` wide, and
+
+      a1 = softmax(q1 k1^T / sqrt(head_dim)) V,   a2 = softmax(q2 k2^T / sqrt(head_dim)) V
+      lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init      (four learned vectors of ``head_dim`` a layer)
+      o_j = (1 - lambda_init) RMSNorm(a1 - lambda a2)               (``pair_norm``: one learned scale of ``2 head_dim``)
+
+    under the layer's mask (every key up to the query's own; with a ``window``, the keys ``i - window < j <= i``);
+    the output is ``W_o [o_j of every pair] + b``. q, k, v and o have biases. ``causal_attention`` is handed ONE
+    call a layer: ``kv_heads`` key heads of ``head_dim``, each read by the ``heads / kv_heads`` query heads of its
+    parity in its pair, with the pair's ``V`` as its value head (the kernel takes a value head of its own size).
+    With ``kv`` (``(k, v)`` [B, S, kv_heads, head_dim] as an earlier layer projected them: cross-attention) the
+    layer has no key/value projection of its own. Returns ``(output, (k, v), lambda)``.
+
+    The kernel alone runs under the scope ``attn_core`` (``swa_core`` with a ``window``, ``cross_core`` with
+    ``kv`` handed in), everything else under ``attn_proj``."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    lambda_init: float
+    eps: float = 1e-5
+    dtype: Dtype = jnp.float32
+    window: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, u, kv=None):
+        B, S, d = u.shape
+        H, Hkv, D = self.heads, self.kv_heads, self.head_dim
+        G, pairs = H // Hkv, Hkv // 2
+        core = "cross_core" if kv is not None else "attn_core" if self.window is None else "swa_core"
+        with jax.named_scope("attn_proj"):
+            q = dense(H * D, self.dtype, "q_proj", bias=True)(u)
+            if kv is None:
+                kv = tuple(dense(Hkv * D, self.dtype, name, bias=True)(u).reshape(B, S, Hkv, D)
+                           for name in ("k_proj", "v_proj"))
+            k, v = kv
+            # key head 2p + r is read by the query heads of parity r in the G query pairs of key/value pair p
+            q = q.reshape(B, S, pairs, G, 2, D).swapaxes(3, 4).reshape(B, S, Hkv, G, D)
+            wide = jnp.repeat(v.reshape(B, S, pairs, 2 * D), 2, axis=2)
+            vector = lambda name: self.param(name, nn.initializers.normal(0.1), (D,), jnp.float32)
+            lam = (jnp.exp(jnp.sum(vector("lambda_q1") * vector("lambda_k1")))
+                   - jnp.exp(jnp.sum(vector("lambda_q2") * vector("lambda_k2"))) + self.lambda_init)
+        with jax.named_scope(core):
+            out = causal_attention(q, k, wide, D ** -0.5, window=self.window)       # [B, S, (p, r), G, 2 D]
+        with jax.named_scope("attn_proj"):
+            out = out.reshape(B, S, pairs, 2, G, 2 * D).astype(jnp.float32)
+            o = RMSNorm(self.eps, name="pair_norm")(out[:, :, :, 0] - lam * out[:, :, :, 1]) * (1.0 - self.lambda_init)
+            return dense(d, self.dtype, "o_proj", bias=True)(o.astype(self.dtype).reshape(B, S, H * D)), kv, lam
 
 
 class LatentAttention(nn.Module):

@@ -1,6 +1,8 @@
-"""The Mamba-2 state-space mixer and its chunked scan.
+"""The state-space mixers and their scans: Mamba-2's chunked scan (``nemotron_h``)
+and, further down, Mamba-1's selective scan with its gated memory unit
+(``phi4flash``).
 
-One head of the layer carries a state ``h`` [P, N] (``P`` = head size, ``N``
+**Mamba-2.** One head of the layer carries a state ``h`` [P, N] (``P`` = head size, ``N``
 = state size) along the sequence:
 
   h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T        y_t = h_t C_t + D x_t
@@ -50,6 +52,25 @@ from ``xBC``; ``dt <- softplus(dt + dt_bias)``; the scan; ``y <- RMSNorm over
 groups of d_inner / G (y * silu(z)) * g``; ``W_out y``. Its operations sit
 under two scopes: ``ssm_scan`` (``chunked_scan`` alone) and ``ssm_proj``
 (everything else).
+
+**Mamba-1.** A channel of the layer carries a state ``h`` [N] (``N`` = 16):
+
+  h_t = exp(dt_t A) * h_{t-1} + (dt_t x_t) B_t      y_t = h_t . C_t + D x_t
+
+with ``A`` [channels, N] negative, ``dt_t`` and ``x_t`` a number a channel and
+position and ``B_t``, ``C_t`` [N] shared by every channel: a decay for every
+(channel, state) pair, so no span of positions unrolls into a matrix product
+as Mamba-2's one decay a head lets it. ``selective_scan`` is ``S`` dependent
+steps of elementwise float32 work, on a TPU as two Pallas kernels on the same
+walk as the scan above (``chunked_kernel.walk`` / ``kept_starts``;
+``mamba1_scan_fwd`` and ``mamba1_scan_bwd`` in a trace) with the ``[N,
+channels]`` state on the chip from the first position to the last, anywhere
+else ``_s6_xla``, a ``lax.scan`` over chunks; no form holds more than a grid
+step's worth of the ``[positions, channels, N]`` history. ``Mamba1Mixer`` is
+the layer around it as ``phi4flash`` publishes it (scopes ``mamba1_scan`` for
+the scan alone and ``mamba1_proj`` for everything else) and returns the
+scan's output before the gate as well: the memory that model's
+``GatedMemoryUnit`` layers read.
 """
 from __future__ import annotations
 
@@ -376,6 +397,210 @@ def chunked_scan(x, dt, A, B, C, chunk: int, dtype: Dtype = jnp.float32) -> Tupl
     return jax.lax.platform_dependent(x, dt, A, B, C, tpu=kernel, default=xla)
 
 
+# ------------------------------------------------------------- Mamba-1: the selective scan
+# channels a program instance of the selective scan's kernels owns at the most (lanes: eight tiles of 128; the columns
+# of ``B_t`` and ``C_t`` are spread over the lanes once a position however many tiles read them, and 2,048 would not
+# fit the backward kernel's states beside its blocks), and positions a chunk. The chip, 2 x 8,192 x 5,120, forward /
+# forward + backward, ms (PR 42): 32 x 512 3.5 / 22.3, 16 x 512 4.0 / 24.0, 64 x 512 3.3 / 24.1, 32 x 256 4.1 / 36.7,
+# 32 x 1,024 3.5 / 17.0
+S6_LANES = 1024
+S6_CHUNK = 32
+
+
+def _s6_lanes(c: int) -> int:
+    """The widest block of whole lane tiles, ``S6_LANES`` at the most, that divides ``c`` channels."""
+    return max(lanes for lanes in range(LANES, min(c, S6_LANES) + 1, LANES) if c % lanes == 0)
+
+
+def _s6_xla(x, dt, A, B, C, chunk: int):
+    """``selective_scan`` in plain XLA: a ``lax.scan`` over chunks of ``chunk`` positions that carries the state,
+    each chunk the literal recurrence (a ``lax.scan`` step a position) under ``jax.checkpoint``, so that forward
+    and backward hold one chunk's ``[chunk, b, c, N]`` states and no more."""
+    b, S, c = x.shape
+    Q = min(chunk, S)
+    x, dt, B, C = (padded(t.astype(F32), Q) for t in (x, dt, B, C))       # dt = 0 behind the end: the state stays
+    by_chunk = lambda t: t.reshape(b, -1, Q, t.shape[-1]).transpose(1, 2, 0, 3)          # [chunks, Q, b, .]
+
+    @jax.checkpoint
+    def one_chunk(h, of_chunk):
+        def step(h, at):
+            x_t, dt_t, B_t, C_t = at
+            h = jnp.exp(dt_t[..., None] * A) * h + (dt_t * x_t)[..., None] * B_t[:, None, :]
+            return h, jnp.sum(h * C_t[:, None, :], axis=-1)
+
+        return jax.lax.scan(step, h, of_chunk)
+
+    last, y = jax.lax.scan(one_chunk, jnp.zeros((b, c, A.shape[1]), F32), tuple(by_chunk(t) for t in (x, dt, B, C)))
+    return y.transpose(2, 0, 1, 3).reshape(b, -1, c)[:, :S], last        # [chunks, Q, b, c] -> [b, S, c]
+
+
+def _s6_forward_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, last_ref, *starts_ref, Q, n):
+    """One batch row, one block of channels (a channel a lane, the state ``[N, lanes]`` float32 with ``N`` on the
+    sublanes), ``n`` chunks of ``Q`` positions; the grid's last axis walks the sequence in order and ``last_ref``
+    (the same block all along it) carries the state. A position is one step of elementwise work on the state's
+    tiles: ``dt_t`` and ``dt_t x_t`` a row across the sublanes, ``B_t`` and ``C_t`` a column across the lanes (they
+    come transposed, ``[N, Q]`` a chunk), the sum over ``N`` a sum over sublanes."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        last_ref[...] = jnp.zeros_like(last_ref)
+
+    if starts_ref:
+        starts_ref[0][0, 0] = last_ref[0]
+    A = A_ref[...]
+
+    def chunk(c, h):
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+        x, dt = x_ref[0, rows].astype(F32), dt_ref[0, rows]
+        Bt, Ct = B_ref[0, c].astype(F32), C_ref[0, c].astype(F32)
+        dtx = dt * x
+        for t in range(Q):
+            h = jnp.exp(dt[t:t + 1] * A) * h + dtx[t:t + 1] * Bt[:, t:t + 1]
+            y_ref[0, pl.ds(c * Q + t, 1)] = jnp.sum(h * Ct[:, t:t + 1], axis=0, keepdims=True)
+        return h
+
+    last_ref[0] = jax.lax.fori_loop(0, n, chunk, last_ref[0])
+
+
+def _s6_backward_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, starts_ref, dy_ref, dlast_ref,
+                        dx_ref, ddt_ref, dA_ref, dB_ref, dC_ref, carry_ref, states_ref, *, Q, n):
+    """The same program instance as the forward kernel's, the grid's last axis walking the sequence from its end.
+    A step walks its ``n Q`` positions forward again from the state the forward kernel left for it, every
+    position's state into ``states_ref`` (``[n Q + 1, N, lanes]``, on the chip), then backward: with ``g_t`` the
+    state's cotangent (``carry_ref`` carries ``a_{t+1} g_{t+1}`` towards position 0) and ``a_t = exp(dt_t A)``,
+
+      g_t = carry + dy_t C_t^T,   dC_t = h_t^T dy_t,   d(dt x)_t = g_t B_t,   dB_t = g_t^T (dt x)_t,
+      d(dt A)_t = g_t * h_{t-1} * a_t,   carry = a_t * g_t.
+
+    ``B`` and ``C`` serve every channel: this block's part of their cotangents goes out transposed, a chunk a tile,
+    and the blocks are summed outside; ``A``'s part is summed over the sequence here (``dA_ref``: the same block
+    all along it) and over the batch rows outside."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        carry_ref[...] = dlast_ref[0]
+        dA_ref[...] = jnp.zeros_like(dA_ref)
+
+    A = A_ref[...]
+    states_ref[0] = starts_ref[0, 0]
+
+    def again(c, h):
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+        x, dt, Bt = x_ref[0, rows].astype(F32), dt_ref[0, rows], B_ref[0, c].astype(F32)
+        dtx = dt * x
+        for t in range(Q):
+            h = jnp.exp(dt[t:t + 1] * A) * h + dtx[t:t + 1] * Bt[:, t:t + 1]
+            states_ref[c * Q + t + 1] = h
+        return h
+
+    jax.lax.fori_loop(0, n, again, starts_ref[0, 0])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (A.shape[0], Q), 1)
+
+    def back(k, carried):
+        g_next, dA = carried
+        c = n - 1 - k
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+        x, dt, dy = x_ref[0, rows].astype(F32), dt_ref[0, rows], dy_ref[0, rows].astype(F32)
+        Bt, Ct = B_ref[0, c].astype(F32), C_ref[0, c].astype(F32)
+        dtx = dt * x
+        dBt, dCt = jnp.zeros(lane.shape, F32), jnp.zeros(lane.shape, F32)
+        for t in reversed(range(Q)):
+            h, before = states_ref[c * Q + t + 1], states_ref[c * Q + t]
+            g = g_next + dy[t:t + 1] * Ct[:, t:t + 1]
+            a = jnp.exp(dt[t:t + 1] * A)
+            d_dtx = jnp.sum(g * Bt[:, t:t + 1], axis=0, keepdims=True)              # [1, lanes]
+            d_dta = g * before * a
+            dCt = jnp.where(lane == t, jnp.sum(h * dy[t:t + 1], axis=1, keepdims=True), dCt)
+            dBt = jnp.where(lane == t, jnp.sum(g * dtx[t:t + 1], axis=1, keepdims=True), dBt)
+            dx_ref[0, pl.ds(c * Q + t, 1)] = d_dtx * dt[t:t + 1]
+            ddt_ref[0, pl.ds(c * Q + t, 1)] = d_dtx * x[t:t + 1] + jnp.sum(d_dta * A, axis=0, keepdims=True)
+            dA = dA + d_dta * dt[t:t + 1]
+            g_next = a * g
+        dB_ref[0, 0, c], dC_ref[0, 0, c] = dBt, dCt
+        return g_next, dA
+
+    carry_ref[...], dA = jax.lax.fori_loop(0, n, back, (carry_ref[...], jnp.zeros_like(A)))
+    dA_ref[0] += dA
+
+
+def _s6_call(kernel, operands, results, b, S, c, N, Q, reverse, scratch, interpret, name):
+    """``chunked_kernel.walk`` over (batch row, block of channels, step of ``CHUNKS_A_STEP`` chunks). Kinds:
+    ``channels`` [b, S, c], ``decay`` [N, c] (``A`` transposed), ``shared`` [b, S / Q, N, Q] (``B``, ``C`` transposed
+    a chunk), ``state`` [b, N, c], ``states`` [b, steps, N, c], ``shares`` [b, blocks, S / Q, N, Q]."""
+    n, lanes = CHUNKS_A_STEP, _s6_lanes(c)
+    specs = lambda at: {"channels": pl.BlockSpec((1, n * Q, lanes), lambda i, g, j: (i, at(j), g)),
+                        "decay": pl.BlockSpec((N, lanes), lambda i, g, j: (0, g)),
+                        "shared": pl.BlockSpec((1, n, N, Q), lambda i, g, j: (i, at(j), 0, 0)),
+                        "state": pl.BlockSpec((1, N, lanes), lambda i, g, j: (i, 0, g)),
+                        "states": pl.BlockSpec((1, 1, N, lanes), lambda i, g, j: (i, at(j), 0, g)),
+                        "shares": pl.BlockSpec((1, 1, n, N, Q), lambda i, g, j: (i, g, at(j), 0, 0))}
+    return walk(functools.partial(kernel, Q=Q, n=n), specs, operands, results, (b, c // lanes, S // (n * Q)),
+                reverse, scratch, interpret, name)
+
+
+def _s6_forward(x, dt, A, B, C, chunk: int, interpret: bool, keep_starts: bool):
+    """The sequence padded to whole steps with ``dt = 0``, which leaves the state as it is; ``A``, and ``B`` and
+    ``C`` a chunk, transposed (the state lies ``[N, channels]``); the forward kernel."""
+    b, S, c = x.shape
+    N, Q, n = A.shape[1], chunk, CHUNKS_A_STEP
+    x, dt, B, C = (padded(t, Q * n) for t in (x, dt, B, C))
+    Sp = x.shape[1]
+    by_chunk = lambda t: t.reshape(b, Sp // Q, Q, N).swapaxes(2, 3)
+    operands = [("channels", x), ("channels", dt), ("decay", A.T), ("shared", by_chunk(B)), ("shared", by_chunk(C))]
+    shape = jax.ShapeDtypeStruct
+    results = [("channels", shape((b, Sp, c), F32)), ("state", shape((b, N, c), F32))]
+    if keep_starts:
+        results.append(("states", shape((b, Sp // (Q * n), N, c), F32)))
+    out = _s6_call(_s6_forward_kernel, operands, results, b, Sp, c, N, Q, False, [], interpret, "mamba1_scan_fwd")
+    return (out[0][:, :S], out[1].swapaxes(1, 2)), ([t for _, t in operands], out[2] if keep_starts else None)
+
+
+def _s6_backward(chunk, interpret, kept, cotangents):
+    (x, dt, At, Bt, Ct), starts = kept
+    dy, dlast = cotangents
+    b, Sp, c = x.shape
+    S, N, Q, n = dy.shape[1], At.shape[0], chunk, CHUNKS_A_STEP
+    lanes = _s6_lanes(c)
+    operands = [("channels", x), ("channels", dt), ("decay", At), ("shared", Bt), ("shared", Ct), ("states", starts),
+                ("channels", jnp.pad(dy, ((0, 0), (0, Sp - S), (0, 0)))), ("state", dlast.swapaxes(1, 2))]
+    shape = jax.ShapeDtypeStruct
+    share = ("shares", shape((b, c // lanes, Sp // Q, N, Q), F32))
+    results = [("channels", shape(x.shape, F32)), ("channels", shape(x.shape, F32)), ("state", shape((b, N, c), F32)),
+               share, share]
+    dx, ddt, dA, dB, dC = _s6_call(
+        _s6_backward_kernel, operands, results, b, Sp, c, N, Q, True,
+        [pltpu.VMEM((N, lanes), F32), pltpu.VMEM((n * Q + 1, N, lanes), F32)], interpret, "mamba1_scan_bwd")
+    whole = lambda t, like: t.sum(axis=1).swapaxes(2, 3).reshape(b, Sp, N)[:, :S].astype(like.dtype)
+    return dx[:, :S].astype(x.dtype), ddt[:, :S], dA.sum(axis=0).T, whole(dB, Bt), whole(dC, Ct)
+
+
+# ``selective_scan`` as two Pallas kernels, ``(x, dt, A, B, C, chunk, interpret)``; the backward pass keeps the five
+# operands as the kernel is handed them and the state every grid step starts from
+_s6_kernel = kept_starts(_s6_forward, _s6_backward)
+
+
+def s6_kernel_takes(c: int, N: int) -> bool:
+    """The kernels' tiles: whole lane tiles of channels, the state size whole sublane tiles."""
+    return c % LANES == 0 and N % 8 == 0
+
+
+def selective_scan(x, dt, A, B, C) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Mamba-1's scan: ``x`` [b, S, c], ``dt`` [b, S, c] float32, ``A`` [c, N] float32 (negative), ``B``/``C``
+    [b, S, N] -> (``y`` [b, S, c] float32 without the ``D x`` skip, the state after the last position [b, c, N]
+    float32):
+
+      h_t = exp(dt_t A) * h_{t-1} + (dt_t x_t) B_t^T  (elementwise over [c, N], h_{-1} = 0),   y_t = h_t C_t
+
+    A decay for every (channel, state) pair: no product form, all of it elementwise work in float32. Lowered for a
+    TPU, at shapes its tiles take (``s6_kernel_takes``), two Pallas kernels (``mamba1_scan_fwd`` and
+    ``mamba1_scan_bwd`` in a trace) through ``chunked_kernel.walk``, the state on the chip from the first position
+    to the last and the backward pass computing a grid step's states again from the one it started from; anywhere
+    else ``_s6_xla``, a ``lax.scan`` over chunks. Neither holds more than a step's ``[positions, c, N]``."""
+    xla = lambda *a: _s6_xla(*a, S6_CHUNK)
+    if not s6_kernel_takes(x.shape[2], A.shape[1]):
+        return xla(x, dt, A, B, C)
+    kernel = lambda x, dt, A, B, C: _s6_kernel(x, dt.astype(F32), A, B, C, S6_CHUNK, False)
+    return jax.lax.platform_dependent(x, dt, A, B, C, tpu=kernel, default=xla)
+
+
 def state_rms(per_head):
     """One number for a layer's carried state, from the mean square of each
     head's ``[P, N]`` state: the geometric mean over the heads of their RMS.
@@ -458,3 +683,56 @@ class Mamba2Mixer(nn.Module):
             y = GroupedRMSNormGated(inner // G, self.eps, name="gated_norm")(y, z)
             out = dense(d, self.dtype, "out_proj")(y)
         return out, state_rms(jnp.mean(jnp.square(last), axis=(0, 2, 3)))
+
+
+class Mamba1Mixer(nn.Module):
+    """The Mamba-1 layer as ``phi4flash`` has it: ``[x, z] = W_in u``; ``x <- silu(causal_conv(x) + b)``;
+    ``[dt_r, B, C] = W_x x``; ``dt = softplus(W_dt dt_r + dt_bias)``; ``A = -exp(A_log)``; ``y = selective_scan(x,
+    dt, A, B, C) + D x``; ``W_out (y silu(z))``. ``u`` [B, S, d] -> (the mixer's output [B, S, d], ``y`` [B, S,
+    inner] BEFORE the gate ``silu(z)``: what a later layer's gated memory unit reads, ``state_rms`` of the state
+    after the last position, a channel a head). Parameters: ``in_proj`` [d, 2 inner], ``conv_kernel`` [L, inner]
+    and ``conv_bias``, ``x_proj`` [inner, dt_rank + 2 N], ``dt_proj`` [dt_rank, inner], ``dt_bias`` [inner],
+    ``A_log`` [inner, N] (drawn log(1..N) a channel), ``D`` [inner] (ones), ``out_proj`` [inner, d]. The scan alone
+    runs under the scope ``mamba1_scan``, everything else under ``mamba1_proj``."""
+
+    inner: int
+    state: int = 16
+    dt_rank: int = 160
+    conv_kernel: int = 4
+    conv_bias: bool = True
+    dt_range: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)   # time_step_min, _max, _floor
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        inner, N, R = self.inner, self.state, self.dt_rank
+        with jax.named_scope("mamba1_proj"):
+            x, z = jnp.split(dense(2 * inner, self.dtype, "in_proj")(u), 2, axis=-1)
+            kernel = self.param("conv_kernel", conv_kernel_init(self.conv_kernel), (self.conv_kernel, inner))
+            bias = self.param("conv_bias", conv_kernel_init(self.conv_kernel), (inner,)) if self.conv_bias else None
+            x = nn.silu(causal_conv(x, kernel, bias))
+            dt, B, C = jnp.split(dense(R + 2 * N, self.dtype, "x_proj")(x), [R, R + N], axis=-1)
+            dt_bias = self.param("dt_bias", _dt_bias_init(*self.dt_range), (inner,))
+            dt = nn.softplus(dense(inner, self.dtype, "dt_proj")(dt).astype(jnp.float32) + dt_bias)
+            A = -jnp.exp(self.param(
+                "A_log", lambda key, shape: jnp.log(jnp.broadcast_to(jnp.arange(1.0, N + 1.0), shape)), (inner, N)))
+            D = self.param("D", nn.initializers.ones, (inner,), jnp.float32)
+        with jax.named_scope("mamba1_scan"):
+            y, last = selective_scan(x, dt, A, B, C)
+        with jax.named_scope("mamba1_proj"):
+            y = (y + D * x.astype(jnp.float32)).astype(self.dtype)
+            out = dense(u.shape[-1], self.dtype, "out_proj")(y * nn.silu(z))
+        return out, y, state_rms(jnp.mean(jnp.square(last), axis=(0, 2)))
+
+
+class GatedMemoryUnit(nn.Module):
+    """``W_out (M * silu(W_in u))`` (``phi4flash``'s cross-decoder): ``memory`` [B, S, inner] is an earlier
+    Mamba-1 layer's pre-gate ``y``, which gates this layer's own projection of ``u`` [B, S, d]. No scan, no
+    convolution and no parameters of the state-space kind: ``in_proj`` [d, inner] and ``out_proj`` [inner, d]."""
+
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u, memory):
+        gate = nn.silu(dense(memory.shape[-1], self.dtype, "in_proj")(u))
+        return dense(u.shape[-1], self.dtype, "out_proj")(memory * gate)

@@ -469,14 +469,28 @@ def _tiny_laguna(B=2, S=32):
     return model, model.init(jax.random.PRNGKey(0), tokens), tokens, tokens
 
 
+def _tiny_phi4flash(B=2, S=32):
+    from distar_tpu.model import Phi4Flash, default_phi4flash_config
+    from distar_tpu.utils import deep_merge_dicts
+
+    cfg = deep_merge_dicts(default_phi4flash_config(), {
+        "hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 8, "num_key_value_heads": 4,
+        "sliding_window": 5, "mamba_dt_rank": 4, "vocab_size": 128})
+    model = Phi4Flash(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 128)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(0), tokens), tokens, tokens
+
+
 # grouped-query attention names its kernel and the rest of the layer itself (``attn_core`` / ``attn_proj``); under a
 # model's ``attention`` they stay on the path and the trace reader gives the operation to ``attention``, the first
 ATTENTION = {"attention", "attn_proj", "attn_core"}
-HAS = {"lfm2": {"short_conv", "dense_mlp"} | ATTENTION,
-       "nemotron_h": {"ssm_proj", "ssm_scan", "moe_shared"} | ATTENTION,
-       "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"},
-       "qwen3_next": {"gdn_proj", "gdn_scan", "moe_shared"} | ATTENTION,
-       "laguna": {"attn_proj", "attn_core", "swa_core", "dense_mlp", "moe_shared"}}
+EXPERTS = {"moe_router", "moe_dispatch", "moe_experts", "moe_combine"}        # every model but ``phi4flash`` has them
+HAS = {"lfm2": {"short_conv", "dense_mlp"} | ATTENTION | EXPERTS,
+       "nemotron_h": {"ssm_proj", "ssm_scan", "moe_shared"} | ATTENTION | EXPERTS,
+       "deepseek_v3": {"mla_proj", "mla_core", "dense_mlp", "moe_shared"} | EXPERTS,
+       "qwen3_next": {"gdn_proj", "gdn_scan", "moe_shared"} | ATTENTION | EXPERTS,
+       "laguna": {"attn_proj", "attn_core", "swa_core", "dense_mlp", "moe_shared"} | EXPERTS,
+       "phi4flash": {"mamba1_proj", "mamba1_scan", "gmu", "cross_core", "attn_proj", "attn_core", "swa_core", "dense_mlp"}}
 
 
 @pytest.mark.parametrize("which", HAS)
@@ -493,7 +507,8 @@ def test_the_steps_scopes_are_on_the_compiled_program(tmp_path, which):
         _, model, variables, tokens, labels = build()
     else:
         model, variables, tokens, labels = {"nemotron_h": _tiny_nemotron_h, "deepseek_v3": _tiny_deepseek_v3,
-                                            "qwen3_next": _tiny_qwen3_next, "laguna": _tiny_laguna}[which]()
+                                            "qwen3_next": _tiny_qwen3_next, "laguna": _tiny_laguna,
+                                            "phi4flash": _tiny_phi4flash}[which]()
     optimizer = optax.adam(1e-3)
     step = jax.jit(make_lm_train_step(model, optimizer, dynamics=tree_spec({}, {"type": "none"})))
     text = step.lower(variables, optimizer.init(variables["params"]),

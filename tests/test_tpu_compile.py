@@ -418,3 +418,60 @@ def test_banded_attention_compiles_for_v5e_at_16k_positions_and_holds_no_dq_a_ke
     text = compiled.as_text()
     assert "splash" in text and text.count("tpu_custom_call") >= (3 if grad else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# (window, the tiles and backward kernel ``core_plan`` names, what the core's temporaries may hold forward / with the
+# gradient) of ``phi4flash``'s differential attention core: 20 key heads of 64, two query heads each, the pair's
+# 128-wide value as the value head, over 2 x 8,192 positions
+DIFF_CORES = {"full_and_cross": (None, (1024, 2048, 512, True), (0.5e9, 1.4e9)),       # 0.34 / 1.09 GB (PR 42)
+              "window_512": (512, (512, 512, 512, False), (0.5e9, 1.3e9))}                 # 0.34 / 1.01 GB
+
+
+@pytest.mark.parametrize("which", DIFF_CORES)
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_differential_attention_core_compiles_for_v5e_at_score_64_value_128(one_chip, grad, which):
+    """``causal_attention`` as ``ops.sequence.DifferentialAttention`` calls it, ONE call a layer: splash attention at
+    the tiles ``core_plan`` names for score heads of 64 with value heads of 128, under the causal mask (the full and
+    the cross layer) and under the band of 512 (a dQ kernel of its own there: the fused kernel's dQ copies are the
+    whole sequence's whatever the mask)."""
+    from distar_tpu.ops.sequence import causal_attention, core_plan
+
+    window, plan, temp = DIFF_CORES[which]
+    assert core_plan(8192, 64, 128, window) == plan
+    q = jax.ShapeDtypeStruct((2, 8192, 20, 2, 64), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 8192, 20, 64), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 20, 128), jnp.bfloat16, sharding=one_chip)
+    fn = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 0.125, window=window).astype(jnp.float32) ** 2)
+    compiled = jax.jit(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn).lower(q, k, v).compile()
+    text = compiled.as_text()
+    assert "splash" in text and text.count("tpu_custom_call") == ((2 if plan[3] else 3) if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp[grad]
+
+
+@pytest.mark.parametrize("grad", (False, True), ids=("fwd", "grad"))
+def test_the_mamba1_mixer_compiles_for_v5e_to_the_selective_scans_two_kernels(one_chip, grad):
+    """``ops.ssm.Mamba1Mixer`` at ``phi4flash``'s published widths (2560 -> 5120 channels, a state of 16) over 2 x
+    8,192 positions in bfloat16, wrapped as the decoder layer wraps it (``jax.checkpoint``): lowered for a TPU its scan
+    is ``mamba1_scan_fwd`` (twice with the replay) and ``mamba1_scan_bwd``, no loop over positions or chunks is left
+    in the program, and nothing of the states' history (``[2, 8192, 5120, 16]`` float32, 5.4 GB) is a temporary of
+    it: the largest array with the state's two axes is the states the grid steps start from, one a 128 positions
+    (``[2, 64, 16, 5120]``, 42 MB)."""
+    from distar_tpu.ops.ssm import Mamba1Mixer
+
+    mixer = Mamba1Mixer(inner=5120, state=16, dt_rank=160, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((2, 8192, 2560), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+                          jax.eval_shape(mixer.init, jax.random.PRNGKey(0), u))
+    layer = jax.checkpoint(lambda p, u: mixer.apply(p, u))
+    fn = lambda p, u: (lambda out, memory, rms: jnp.sum(out.astype(jnp.float32) ** 2)
+                       + jnp.sum(memory.astype(jnp.float32)) + rms)(*layer(p, u))
+    compiled = jax.jit(jax.value_and_grad(fn, argnums=(0, 1)) if grad else fn).lower(params, u).compile()
+    text = compiled.as_text()
+    calls = [ln.split("=")[0].strip() for ln in text.split("\n") if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(name.split(".")[0] for name in calls) == (
+        ["%mamba1_scan_bwd", "%mamba1_scan_fwd", "%mamba1_scan_fwd"] if grad else ["%mamba1_scan_fwd"])
+    assert "while(" not in text and [ln for ln in text.split("\n") if "mamba1_scan" in ln]
+    history = [int(n) for n in re.findall(r"f32\[2,(\d+),16,5120\]", text)] + [
+        int(n) for n in re.findall(r"f32\[2,(\d+),5120,16\]", text)]
+    assert max(history, default=0) <= 8192 // 128
+    assert compiled.memory_analysis().temp_size_in_bytes < (3.8e9 if grad else 1.8e9)   # 3.03 and 1.34 GB (PR 42)

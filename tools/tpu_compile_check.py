@@ -20,6 +20,7 @@ Programs (shapes, dtype and batch from the committed configs that
   kimi   the same step of ``configs/kimi_vl_a3b_v5e.yaml``
   qwen   the same step of ``configs/qwen3_next_80b_a3b_v5e.yaml``
   laguna the same step of ``configs/laguna_s_v5e.yaml``
+  phi4flash  the same step of ``configs/phi4_mini_flash_v5e.yaml``
 
 Usage (CPU sandbox; minutes per step program, so not a tier-1 test):
   JAX_PLATFORMS=cpu python tools/tpu_compile_check.py --what sl,rl,actor
@@ -51,7 +52,8 @@ LM_CONFIGS = {"lm": os.path.join(REPO, "configs", "lfm2_24b_a2b_v5e.yaml"),
               "nh": os.path.join(REPO, "configs", "nemotron_twotower_30b_a3b_v5e.yaml"),
               "kimi": os.path.join(REPO, "configs", "kimi_vl_a3b_v5e.yaml"),
               "qwen": os.path.join(REPO, "configs", "qwen3_next_80b_a3b_v5e.yaml"),
-              "laguna": os.path.join(REPO, "configs", "laguna_s_v5e.yaml")}
+              "laguna": os.path.join(REPO, "configs", "laguna_s_v5e.yaml"),
+              "phi4flash": os.path.join(REPO, "configs", "phi4_mini_flash_v5e.yaml")}
 
 
 def _specs(tree, sharding):
@@ -345,7 +347,7 @@ def main() -> None:
         elif what in LM_CONFIGS:
             check_lm(topo, read_config(LM_CONFIGS[what]), args.batch_size, args.mesh)
         else:
-            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi, qwen, laguna)")
+            raise SystemExit(f"unknown program {what!r} (sl, rl, actor, lm, nh, kimi, qwen, laguna, phi4flash)")
 
 
 if __name__ == "__main__":
