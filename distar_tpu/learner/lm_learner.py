@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import compute_lm_loss
 from ..model import TOKEN_MODELS
+from ..ops.sequence import cores_kept
 from ..parallel import MeshSpec, assemble_global, make_mesh
 from ..utils import deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
@@ -177,7 +178,8 @@ class LMLearner(BaseLearner):
         self.mesh = shrink_dp(self.mesh, B)
         self.optimizer = self._build_optimizer()
         setup = self._setup_spans
-        with setup.span("init_shapes"):  # a whole host trace of the model, for its shardings
+        # a whole host trace of the model, for its shardings and for what its layers' remat keeps
+        with setup.span("init_shapes"), cores_kept() as kept:
             tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
             rng = jax.random.PRNGKey(self.init_prng_seed)
             param_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.model.init, rng, tokens))
@@ -202,6 +204,14 @@ class LMLearner(BaseLearner):
             donate_argnums=(0, 1), out_shardings=(param_sh, opt_sh, repl))
         self._perf.set_collectives(self.mesh, variables)
         self._perf.set_state_bytes(self._state)
+        self.metrics.gauge(
+            "distar_lm_cores_kept",
+            "full-causal attention cores whose forward results go by a name a layer's remat saves").set(len(kept))
+        self.metrics.gauge(
+            "distar_lm_cores_kept_bytes", "bytes of those results (out and logsumexp), all cores").set(sum(kept))
+        self.logger.info(
+            f"{type(self.model).__name__}: {len(kept)} full-causal attention cores name their forward results (out "
+            f"and logsumexp, {sum(kept)} bytes) for the layers' remat to keep (remat={bool(self.model_cfg.remat)})")
         self._moe_rows = self.metrics.histogram(
             "distar_moe_rows_here", "rows routed to the experts held here, all layers, per step")
         self._moe_load = self.metrics.histogram(

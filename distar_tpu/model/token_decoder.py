@@ -3,7 +3,8 @@ model's own layers.
 
   x_0     = W_emb[tokens]                          (scope ``embed``)
   x_{i+1}, stats_i = Layer_i(x_i)                  (module ``layer_<i>``, recomputed in the backward pass
-                                                    where the config's ``remat`` says so)
+                                                    where the config's ``remat`` says so, but for what
+                                                    ``ops.sequence.CORE_KEPT`` names)
   x_{i+1}, handed_{i+1}, stats_i = Layer_i(x_i, handed_i)   where a model's layers hand tensors on to later
                                                     layers (``hands_on``; ``handed_0`` = {})
   logits  = Norm(x_L) W_head, float32              (scope ``lm_head``, module ``final_norm``)
@@ -24,6 +25,20 @@ layer's scan, one attention layer's keys and values) goes through the layer
 loop beside the residual stream as a second argument and a second result of
 every layer, under the same ``nn.remat``, so a reader's gradient reaches the
 maker through every layer between and nothing is a side channel.
+
+What the remat keeps: a layer's input (and what was handed to it), as any
+``jax.checkpoint`` does, and the forward results of a full-causal attention
+core, ``out`` [B, heads, S, Dv] and ``logsumexp`` [B, heads, S] float32, which
+splash attention names ``CORE_KEPT`` inside its forward rule. Its backward
+kernels need those two and only its forward kernel can make them, at a cost
+quadratic in ``S``: 95 and 110 ms of replay for every GB kept at 2 x 8,192 and
+16,384 positions (PERF.md section 5, PR 44), so the replay runs the layer
+up to the kernel's operands (the projections: cheap) and stops there.
+Everything else is replayed: matrix products and elementwise chains, whose
+activations are what memory bounds, and the kernels that are linear in ``S``
+(the banded core, the scans, the delta rule: 4-25 ms a GB). The policy is one
+rule for every model and needs no key: with ``remat`` off everything is kept
+and the name does nothing.
 """
 from __future__ import annotations
 
@@ -33,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..ops.sequence import LayerNorm, RMSNorm
+from ..ops.sequence import CORE_KEPT, LayerNorm, RMSNorm
 from .config import cdtype, static_cfg
 
 
@@ -60,7 +75,8 @@ def decode(model: nn.Module, tokens, layer: Type[nn.Module], layers: int, *, eps
                             (cfg.vocab_size, cfg.hidden_size), jnp.float32)
     with jax.named_scope("embed"):
         x = embedding.astype(dtype)[tokens]
-    layer_cls = nn.remat(layer) if cfg.remat else layer
+    # a layer's replay keeps what only a full-causal attention kernel can make, and nothing else
+    layer_cls = nn.remat(layer, policy=jax.checkpoint_policies.save_only_these_names(CORE_KEPT)) if cfg.remat else layer
     per_layer, handed = [], {}
     for i in range(layers):
         if hands_on:

@@ -40,6 +40,8 @@ in a cross-attention layer, handed in from the layer that made them.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Union
@@ -53,6 +55,10 @@ Dtype = Any
 
 XLA_QUERY_BLOCK = 512
 KERNEL_MIN_BLOCK = 128  # the kernel's tiles are multiples of this many positions
+# the name a full-causal core's forward results go by (``jax.ad_checkpoint.checkpoint_name``): a layer's
+# remat that saves this name (``model/token_decoder.py::decode``) does not run the kernel again in its replay
+CORE_KEPT = "attn_core_kept"
+_kept_log: contextvars.ContextVar = contextvars.ContextVar("cores_kept", default=None)
 
 
 class CorePlan(NamedTuple):
@@ -310,6 +316,19 @@ def band_mask(S: int, window: int):
     return masks.LocalMask((S, S), (window - 1, 0), 0)
 
 
+@contextlib.contextmanager
+def cores_kept():
+    """The cores that were given the name ``CORE_KEPT`` by a trace made inside the ``with``: a list with one
+    entry a core, the bytes of its forward results that go by the name (``out`` [B, heads, S, Dv] in the
+    operands' dtype and ``logsumexp`` [B, heads, S] in float32)."""
+    kept = []
+    token = _kept_log.set(kept)
+    try:
+        yield kept
+    finally:
+        _kept_log.reset(token)
+
+
 def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
@@ -320,10 +339,16 @@ def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
     mask = masks.CausalMask((S, S)) if window is None else band_mask(S, window)
     heads = lambda t: t.reshape(B, S, -1, t.shape[-1]).transpose(0, 2, 1, 3)
     sizes = dict(use_fused_bwd_kernel=True) if fused else dict(use_fused_bwd_kernel=False, block_q_dq=bq, block_kv_dq=bkv)
+    # the backward kernels need ``out`` and ``logsumexp``, which only the forward kernel can make: over the whole
+    # triangle that kernel is quadratic in ``S`` and its results go by a name, so a remat that saves the name
+    # replays the layer up to the kernel and not through it; under a band it is linear, and replayed
+    kept = CORE_KEPT if window is None else None
+    if kept and _kept_log.get() is not None:
+        _kept_log.get().append(B * Hkv * G * S * (v.shape[-1] * q.dtype.itemsize + 4))
     # the library keeps the tables it makes of a mask on the host (1.2 s for 24 heads at 16,384 positions),
     # so the layers of one shape, and a layer's replay, make them once
     kernel = splash.make_splash_mha(
-        masks.MultiHeadMask([mask] * (Hkv * G)), head_shards=1, q_seq_shards=1,
+        masks.MultiHeadMask([mask] * (Hkv * G)), head_shards=1, q_seq_shards=1, residual_checkpoint_name=kept,
         block_sizes=splash.BlockSizes(
             block_q=bq, block_kv=bkv, block_kv_compute=compute, block_q_dkv=bq, block_kv_dkv=bkv,
             block_kv_dkv_compute=compute, **sizes))
@@ -336,7 +361,14 @@ def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
 def causal_attention(q, k, v, scale: float, window: Optional[int] = None):
     """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
     [B, S, Hkv, G, Dv]: the value head has a size of its own. With a
-    ``window``, query ``i`` sees the keys ``i - window < j <= i`` and no others."""
+    ``window``, query ``i`` sees the keys ``i - window < j <= i`` and no others.
+
+    Over the whole triangle (no ``window``, or one that covers the sequence) the kernel's forward results, ``out``
+    and ``logsumexp``, go by the name ``CORE_KEPT``: under a ``jax.checkpoint`` that saves the name
+    (``model/token_decoder.py::decode``) the backward pass takes them from memory and the replay does not run the
+    forward kernel again, which is quadratic in ``S`` and the only thing that can make them. Under a band the
+    kernel is linear in ``S`` (3 ms for 0.15-0.3 GB at 16,384 positions) and is replayed with the rest of the
+    layer, so it is given no name. Outside such a checkpoint the name does nothing."""
     if window is not None and window >= q.shape[1]:
         window = None                                               # the band is the whole triangle
     plain = functools.partial(_attention_xla, scale=scale, window=window)

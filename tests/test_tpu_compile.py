@@ -475,3 +475,80 @@ def test_the_mamba1_mixer_compiles_for_v5e_to_the_selective_scans_two_kernels(on
         int(n) for n in re.findall(r"f32\[2,(\d+),5120,16\]", text)]
     assert max(history, default=0) <= 8192 // 128
     assert compiled.memory_analysis().temp_size_in_bytes < (3.8e9 if grad else 1.8e9)   # 3.03 and 1.34 GB (PR 42)
+
+
+def _forward_kernels(text):
+    """The names of splash attention's forward kernels among a compiled text's custom calls."""
+    return re.findall(r"^\s*(?:ROOT )?%(splash_mha_fwd[\w.]*) = .*custom-call\(", text, flags=re.M)
+
+
+# the attention module of one decoder layer at its published sizes, the positions of its cell, and the forward
+# kernels in the gradient's text: a full-causal core's forward results are kept by the layer's remat
+# (``ops.sequence.CORE_KEPT``), a banded core is replayed with the layer
+LAYERS_UNDER_REMAT = {
+    "kimi_vl_latent_full": (lambda s: s.LatentAttention(16, 512, 128, 64, 128, dtype=jnp.bfloat16), (2, 8192, 2048), 1),
+    "laguna_sliding_window_512": (
+        lambda s: s.CausalGQAttention(36, 4, 128, dtype=jnp.bfloat16, window=512), (1, 16384, 2048), 2),
+}
+
+
+@pytest.mark.parametrize("which", LAYERS_UNDER_REMAT)
+def test_a_layers_remat_runs_a_full_causal_cores_forward_kernel_once_on_a_v5e(one_chip, which):
+    """One attention layer through ``model/token_decoder.py::decode`` with ``remat: true``, value and gradient,
+    compiled for a v5e: the text holds ONE ``splash_mha_fwd`` custom call for latent attention's core over the whole
+    triangle (two until PR 44: the replay ran it again for ``out`` and ``logsumexp``) and two for the band."""
+    from flax import linen as nn
+
+    from distar_tpu.model.token_decoder import decode
+    from distar_tpu.ops import sequence
+
+    make, (B, S, d), runs = LAYERS_UNDER_REMAT[which]
+
+    class Layer(nn.Module):
+        cfg: dict
+        index: int
+
+        @nn.compact
+        def __call__(self, x):
+            return x + make(sequence)(sequence.RMSNorm(1e-5, name="norm")(x)), {}
+
+    class Model(nn.Module):
+        cfg: dict
+
+        @nn.compact
+        def __call__(self, tokens):
+            return decode(self, tokens, Layer, 1, eps=1e-5, stacked=(), tied=True)
+
+    model = Model({"vocab_size": 256, "hidden_size": d, "dtype": "bfloat16", "remat": True})
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens))
+    fn = lambda p, t: jnp.sum(model.apply(p, t)[0] ** 2)
+    text = jax.jit(jax.value_and_grad(fn)).lower(params, tokens).compile().as_text()
+    assert len(_forward_kernels(text)) == runs
+
+
+# ``tools/tpu_compile_check.py --what``: the forward kernels of the whole step's text (one a full-causal core, two
+# a banded one: laguna 2 + 2 x 3, phi4flash 2 + 2 x 1)
+TOKEN_PROGRAMS = {"lm": 1, "nh": 1, "kimi": 6, "qwen": 1, "laguna": 8, "phi4flash": 4}
+REMAT_STARTS_AT = 15.27e9    # ``total_bytes`` above which the compiler rematerialises on its own (PERF.md 7.19 (e))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("what", TOKEN_PROGRAMS)
+def test_a_token_step_holds_one_forward_kernel_a_full_causal_core_and_fits_the_chip(topo, what):
+    """The whole train step of a published-width file, as ``tools/tpu_compile_check.py`` compiles it (1-5 minutes
+    each, so not tier-1): ``kimi``'s text holds six ``splash_mha_fwd`` custom calls, not twelve, and every
+    program's ``total_bytes`` stays under what makes the compiler rematerialise."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "tools"))
+    import tpu_compile_check as check
+
+    from distar_tpu.utils import read_config
+
+    compiled = check.check_lm(topo, read_config(check.LM_CONFIGS[what]), 0, "")
+    assert len(_forward_kernels(compiled.as_text())) == TOKEN_PROGRAMS[what]
+    mem = compiled.memory_analysis()     # donated arguments are aliased to outputs: counted once
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < REMAT_STARTS_AT
