@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..losses import compute_lm_loss
 from ..model import TOKEN_MODELS
-from ..ops.sequence import cores_kept
+from ..ops.sequence import cores_kept, heads_fused
 from ..parallel import MeshSpec, assemble_global, make_mesh
 from ..utils import deep_merge_dicts
 from .base_learner import DEFAULT_LEARNER_CONFIG, BaseLearner
@@ -178,8 +178,9 @@ class LMLearner(BaseLearner):
         self.mesh = shrink_dp(self.mesh, B)
         self.optimizer = self._build_optimizer()
         setup = self._setup_spans
-        # a whole host trace of the model, for its shardings and for what its layers' remat keeps
-        with setup.span("init_shapes"), cores_kept() as kept:
+        # a whole host trace of the model, for its shardings, for what its layers' remat keeps and for which form
+        # its attention layers' q/k preparations take
+        with setup.span("init_shapes"), cores_kept() as kept, heads_fused() as fused:
             tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
             rng = jax.random.PRNGKey(self.init_prng_seed)
             param_sh = fsdp_param_sharding(self.mesh, jax.eval_shape(self.model.init, rng, tokens))
@@ -209,6 +210,11 @@ class LMLearner(BaseLearner):
             "full-causal attention cores whose forward results go by a name a layer's remat saves").set(len(kept))
         self.metrics.gauge(
             "distar_lm_cores_kept_bytes", "bytes of those results (out and logsumexp), all cores").set(sum(kept))
+        for form, count in (("fused", sum(fused)), ("xla", len(fused) - sum(fused))):
+            self.metrics.gauge(
+                "distar_lm_heads_fused",
+                "q/k preparations (head norm, rotation, scale, the kernel's layout) by the form a TPU's program gives them",
+                form=form).set(count)
         self.logger.info(
             f"{type(self.model).__name__}: {len(kept)} full-causal attention cores name their forward results (out "
             f"and logsumexp, {sum(kept)} bytes) for the layers' remat to keep (remat={bool(self.model_cfg.remat)})")

@@ -31,6 +31,13 @@ sees the keys ``i - window < j <= i``) the work is the band's: on a TPU the same
 local mask, which visits only the key blocks that meet the band, forward and
 backward; anywhere else the same loop over query blocks with the band in its mask.
 
+What stands between ``q_proj``'s and ``k_proj``'s results and that kernel in
+``CausalGQAttention`` (the head norm, the rotation, the queries' scale and the
+move to the kernel's ``[B, heads, S, D]``) is, lowered for a TPU at shapes its
+tiles take (``head_turn.takes``: a head of whole lane tiles), ONE pass each way
+(``ops/head_turn.py``, two Pallas kernels); anywhere else, and at the other
+shapes, the XLA form it always was (``rms_norm``, ``turn_first``).
+
 ``DifferentialAttention`` (``phi4flash``) is two softmaxes a pair of heads over
 a value twice a head wide, ``a1 - lambda a2`` under an RMSNorm over the pair;
 it hands ``causal_attention`` ONE call a layer (a key head with the two query
@@ -51,6 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from . import head_turn
+
 Dtype = Any
 
 XLA_QUERY_BLOCK = 512
@@ -59,6 +68,7 @@ KERNEL_MIN_BLOCK = 128  # the kernel's tiles are multiples of this many position
 # remat that saves this name (``model/token_decoder.py::decode``) does not run the kernel again in its replay
 CORE_KEPT = "attn_core_kept"
 _kept_log: contextvars.ContextVar = contextvars.ContextVar("cores_kept", default=None)
+_fused_log: contextvars.ContextVar = contextvars.ContextVar("heads_fused", default=None)
 
 
 class CorePlan(NamedTuple):
@@ -137,24 +147,31 @@ def core_plan(S: int, D: int, Dv: int, window: Optional[int] = None) -> CorePlan
     return CorePlan(q, kv, dividing(kv, compute), fused)
 
 
+def rms_norm(x, scale, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32, rounded to ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
 class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32.
+    """``rms_norm`` with a learned scale of the last axis' size.
     ``zero_centred`` (``qwen3_next``): ``... * (1 + w)`` with ``w`` zero at
     init, so that weight decay pulls the layer towards the plain norm and not
-    towards nothing."""
+    towards nothing. ``weight(D)`` is the scale alone, for a caller that norms
+    by a function of its own (``CausalGQAttention``: ``ops/head_turn.py``)."""
 
     eps: float = 1e-5
     zero_centred: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def weight(self, D: int):
         if self.zero_centred:
-            scale = 1.0 + self.param("w", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
-        else:
-            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(x.dtype)
+            return 1.0 + self.param("w", nn.initializers.zeros, (D,), jnp.float32)
+        return self.param("scale", nn.initializers.ones, (D,), jnp.float32)
+
+    def __call__(self, x):
+        return rms_norm(x, self.weight(x.shape[-1]), self.eps)
 
 
 class LayerNorm(nn.Module):
@@ -194,18 +211,44 @@ def _turn(x, inv_freq, factor=None):
     return (x32 * cos + jnp.concatenate([-b, a], axis=-1) * sin).astype(x.dtype)
 
 
+def rope_inv_freq(rotary_dim: int, theta: float):
+    """The angle a position turns pair ``i`` of ``rotary_dim`` dimensions by: ``theta^(-2i/rotary_dim)``."""
+    return theta ** (-jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
+
+
+def turn_first(x, inv_freq, factor=None):
+    """``_turn`` over the first ``2 len(inv_freq)`` dimensions of each head, the others as they are."""
+    R = 2 * inv_freq.shape[0]
+    if R == x.shape[-1]:
+        return _turn(x, inv_freq, factor)
+    return jnp.concatenate([_turn(x[..., :R], inv_freq, factor), x[..., R:]], axis=-1)
+
+
+def head_turn_xla(x, heads: int, weight=None, inv_freq=None, factor=None, scale: Optional[float] = None, eps: float = 1e-5):
+    """``head_turn.head_turn``'s result, ``x`` [B, S, heads D] -> [B, heads, S, D], as the XLA form computes it
+    (``rms_norm``, ``turn_first``, the scale as ``_attention_splash`` multiplies by it, the kernel's layout): what the
+    kernels' tests and ``tools/bench_kernels.py --ops head_turn`` hold them against."""
+    x = x.reshape(*x.shape[:2], heads, -1)
+    if weight is not None:
+        x = rms_norm(x, weight, eps)
+    if inv_freq is not None:
+        x = turn_first(x, inv_freq, factor)
+    if scale is not None:
+        x = x * jnp.asarray(scale, x.dtype)
+    return x.transpose(0, 2, 1, 3)
+
+
 def rope(x, theta: float):
     """Rotary positions over the whole head, rotate-half convention:
     ``x * cos + rotate_half(x) * sin`` with ``rotate_half([a, b]) = [-b, a]``
     and angle ``t * theta^(-2i/D)`` for pair ``i``. ``x`` is ``[B, S, H, D]``."""
-    D = x.shape[-1]
-    return _turn(x, theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    return _turn(x, rope_inv_freq(x.shape[-1], theta))
 
 
 def rope_first(x, theta: float, rotary_dim: int):
     """``rope`` over the first ``rotary_dim`` dimensions of each head (angles
     ``t * theta^(-2i/rotary_dim)``), the others as they are."""
-    return jnp.concatenate([rope(x[..., :rotary_dim], theta), x[..., rotary_dim:]], axis=-1)
+    return turn_first(x, rope_inv_freq(rotary_dim, theta))
 
 
 def yarn_inv_freq(rotary_dim: int, theta: float, factor: float, original_max_position_embeddings: int,
@@ -230,8 +273,7 @@ def yarn_first(x, rotary_dim: int, theta: float, attention_factor: float, **yarn
     """The rotation of the first ``rotary_dim`` dimensions of each head by
     ``yarn_inv_freq``'s table, cos and sin times ``attention_factor``; the
     others as they are."""
-    inv_freq = jnp.asarray(yarn_inv_freq(rotary_dim, theta, **yarn), jnp.float32)
-    return jnp.concatenate([_turn(x[..., :rotary_dim], inv_freq, attention_factor), x[..., rotary_dim:]], axis=-1)
+    return turn_first(x, jnp.asarray(yarn_inv_freq(rotary_dim, theta, **yarn), jnp.float32), attention_factor)
 
 
 def rope_interleaved(x, theta: float):
@@ -317,23 +359,39 @@ def band_mask(S: int, window: int):
 
 
 @contextlib.contextmanager
+def heads_fused():
+    """The q/k preparations (a head norm and a rotation: two a ``CausalGQAttention`` layer with ``positions``) of
+    a trace made inside the ``with``: a list with one entry each, True where ``head_turn.takes`` handed it to the
+    fused function (for a program lowered for a TPU), False where the rule left it with the XLA form."""
+    yield from _logged(_fused_log)
+
+
+@contextlib.contextmanager
 def cores_kept():
     """The cores that were given the name ``CORE_KEPT`` by a trace made inside the ``with``: a list with one
     entry a core, the bytes of its forward results that go by the name (``out`` [B, heads, S, Dv] in the
     operands' dtype and ``logsumexp`` [B, heads, S] in float32)."""
-    kept = []
-    token = _kept_log.set(kept)
+    yield from _logged(_kept_log)
+
+
+def _logged(log: contextvars.ContextVar):
+    entries = []
+    token = log.set(entries)
     try:
-        yield kept
+        yield entries
     finally:
-        _kept_log.reset(token)
+        log.reset(token)
 
 
-def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
+def _attention_splash(q, k, v, scale: float, window: Optional[int] = None, head_major: bool = False):
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
 
-    B, S, Hkv, G, D = q.shape
+    if head_major:
+        (B, _, S, D), Hkv = q.shape, k.shape[1]
+        G = q.shape[1] // Hkv
+    else:
+        B, S, Hkv, G, D = q.shape
     bq, bkv, compute, fused = core_plan(S, D, v.shape[-1], window)
     # under a band the kernel's grid holds the key blocks that meet it and no others, in all three passes
     mask = masks.CausalMask((S, S)) if window is None else band_mask(S, window)
@@ -354,14 +412,21 @@ def _attention_splash(q, k, v, scale: float, window: Optional[int] = None):
             block_kv_dkv_compute=compute, **sizes))
     # the kernel has no scale of its own, takes one sequence ([heads, S, .]) and shares a
     # key/value head among the query heads of its group itself
-    out = jax.vmap(kernel)(heads(q * jnp.asarray(scale, q.dtype)), heads(k), heads(v))
+    if not head_major:
+        q, k, v = heads(q * jnp.asarray(scale, q.dtype)), heads(k), heads(v)
+    out = jax.vmap(kernel)(q, k, v)
     return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, v.shape[-1])
 
 
-def causal_attention(q, k, v, scale: float, window: Optional[int] = None):
+def causal_attention(q, k, v, scale: float, window: Optional[int] = None, head_major: bool = False):
     """``q`` [B, S, Hkv, G, D], ``k`` [B, S, Hkv, D], ``v`` [B, S, Hkv, Dv] ->
     [B, S, Hkv, G, Dv]: the value head has a size of its own. With a
     ``window``, query ``i`` sees the keys ``i - window < j <= i`` and no others.
+    ``head_major`` is for a caller that is itself the ``tpu`` branch of a
+    ``platform_dependent`` and has made the kernel's operands (``CausalGQAttention``,
+    by ``ops/head_turn.py``): ``q`` [B, Hkv G, S, D] already times ``scale``, ``k``
+    [B, Hkv, S, D], ``v`` [B, Hkv, S, Dv], a sequence the kernel's tiles divide;
+    they go to the kernel as they are.
 
     Over the whole triangle (no ``window``, or one that covers the sequence) the kernel's forward results, ``out``
     and ``logsumexp``, go by the name ``CORE_KEPT``: under a ``jax.checkpoint`` that saves the name
@@ -369,8 +434,10 @@ def causal_attention(q, k, v, scale: float, window: Optional[int] = None):
     forward kernel again, which is quadratic in ``S`` and the only thing that can make them. Under a band the
     kernel is linear in ``S`` (3 ms for 0.15-0.3 GB at 16,384 positions) and is replayed with the rest of the
     layer, so it is given no name. Outside such a checkpoint the name does nothing."""
-    if window is not None and window >= q.shape[1]:
+    if window is not None and window >= q.shape[2 if head_major else 1]:
         window = None                                               # the band is the whole triangle
+    if head_major:
+        return _attention_splash(q, k, v, scale, window, head_major=True)
     plain = functools.partial(_attention_xla, scale=scale, window=window)
     if q.shape[1] % KERNEL_MIN_BLOCK:
         return plain(q, k, v)
@@ -407,7 +474,12 @@ class CausalGQAttention(nn.Module):
 
     The kernel alone runs under the scope ``attn_core`` (``swa_core`` with a
     ``window``), everything else under ``attn_proj``; a model that puts the
-    whole layer under ``attention`` is read by that name, the first on the path."""
+    whole layer under ``attention`` is read by that name, the first on the path.
+
+    With ``positions``, a program lowered for a TPU prepares q and k for the
+    kernel by ``head_turn.head_turn`` where ``head_turn.takes`` the shapes
+    (counted by ``heads_fused``), and hands ``causal_attention`` the operands
+    head-major; every other platform, and every other shape, runs ``plain``."""
 
     heads: int
     kv_heads: int
@@ -428,6 +500,7 @@ class CausalGQAttention(nn.Module):
         H, Hkv, D = self.heads, self.kv_heads, self.head_dim
         if self.gate not in (False, True, "head"):
             raise ValueError(f"gate {self.gate!r}: False, True (an element) or 'head'")
+        G, scale, core = H // Hkv, D ** -0.5, "attn_core" if self.window is None else "swa_core"
         with jax.named_scope("attn_proj"):
             q = dense(H * D * (2 if self.gate is True else 1), self.dtype, "q_proj")(u).reshape(B, S, H, -1)
             if self.gate is True:
@@ -436,17 +509,52 @@ class CausalGQAttention(nn.Module):
                 gate = dense(H, self.dtype, "g_proj")(u)[..., None]
             k = dense(Hkv * D, self.dtype, "k_proj")(u).reshape(B, S, Hkv, D)
             v = dense(Hkv * D, self.dtype, "v_proj")(u).reshape(B, S, Hkv, D)
+            weights, fused = (), False
             if self.positions:
+                R = self.rotary_dim or D
                 if self.yarn is not None:
-                    turn = functools.partial(yarn_first, rotary_dim=self.rotary_dim or D, theta=self.rope_theta, **self.yarn)
+                    turn = functools.partial(yarn_first, rotary_dim=R, theta=self.rope_theta, **self.yarn)
                 elif self.rotary_dim is None:
                     turn = functools.partial(rope, theta=self.rope_theta)
                 else:
                     turn = functools.partial(rope_first, theta=self.rope_theta, rotary_dim=self.rotary_dim)
-                q = turn(RMSNorm(self.eps, self.zero_centred, name="q_norm")(q))
-                k = turn(RMSNorm(self.eps, self.zero_centred, name="k_norm")(k))
-        with jax.named_scope("attn_core" if self.window is None else "swa_core"):
-            out = causal_attention(q.reshape(B, S, Hkv, H // Hkv, D), k, v, D ** -0.5, window=self.window)
+                weights = tuple(RMSNorm(self.eps, self.zero_centred, name=name).weight(D) for name in ("q_norm", "k_norm"))
+                fused = head_turn.takes(S, D, R)
+                if _fused_log.get() is not None:
+                    _fused_log.get().extend([fused] * 2)
+
+        def normed(x, weight, name):
+            with jax.named_scope(name):
+                return rms_norm(x, weight, self.eps)
+
+        def plain(q, k, v, *weights, attend=causal_attention):
+            with jax.named_scope("attn_proj"):
+                if weights:
+                    q, k = (turn(normed(x, w, name)) for x, w, name in zip((q, k), weights, ("q_norm", "k_norm")))
+            with jax.named_scope(core):
+                return attend(q.reshape(B, S, Hkv, G, D), k, v, scale, window=self.window)
+
+        def one_pass(q, k, v, wq, wk):
+            with jax.named_scope("attn_proj"):
+                if self.yarn is not None:
+                    yarn = dict(self.yarn)
+                    factor = yarn.pop("attention_factor")
+                    inv_freq = jnp.asarray(yarn_inv_freq(R, self.rope_theta, **yarn), jnp.float32)
+                else:
+                    inv_freq, factor = rope_inv_freq(R, self.rope_theta), None
+                tables = head_turn.turn_tables(S, D, inv_freq, factor)
+                q = head_turn.head_turn(q.reshape(B, S, H * D), H, wq, tables, R, scale, self.eps)
+                k = head_turn.head_turn(k.reshape(B, S, Hkv * D), Hkv, wk, tables, R, None, self.eps)
+                v = v.transpose(0, 2, 1, 3)
+            with jax.named_scope(core):
+                return causal_attention(q, k, v, scale, window=self.window, head_major=True)
+
+        if fused:
+            # the default is what every platform ran before: the XLA form, then the loop over query blocks
+            out = jax.lax.platform_dependent(q, k, v, *weights, tpu=one_pass,
+                                             default=functools.partial(plain, attend=_attention_xla))
+        else:
+            out = plain(q, k, v, *weights)
         with jax.named_scope("attn_proj"):
             if not self.gate:
                 return dense(d, self.dtype, "o_proj")(out.reshape(B, S, H * D))

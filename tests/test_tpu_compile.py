@@ -492,17 +492,13 @@ LAYERS_UNDER_REMAT = {
 }
 
 
-@pytest.mark.parametrize("which", LAYERS_UNDER_REMAT)
-def test_a_layers_remat_runs_a_full_causal_cores_forward_kernel_once_on_a_v5e(one_chip, which):
+def _layer_text(one_chip, make, B, S, d):
     """One attention layer through ``model/token_decoder.py::decode`` with ``remat: true``, value and gradient,
-    compiled for a v5e: the text holds ONE ``splash_mha_fwd`` custom call for latent attention's core over the whole
-    triangle (two until PR 44: the replay ran it again for ``out`` and ``logsumexp``) and two for the band."""
+    compiled for a v5e: the compiled text."""
     from flax import linen as nn
 
     from distar_tpu.model.token_decoder import decode
     from distar_tpu.ops import sequence
-
-    make, (B, S, d), runs = LAYERS_UNDER_REMAT[which]
 
     class Layer(nn.Module):
         cfg: dict
@@ -524,8 +520,48 @@ def test_a_layers_remat_runs_a_full_causal_cores_forward_kernel_once_on_a_v5e(on
     params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
                           jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens))
     fn = lambda p, t: jnp.sum(model.apply(p, t)[0] ** 2)
-    text = jax.jit(jax.value_and_grad(fn)).lower(params, tokens).compile().as_text()
-    assert len(_forward_kernels(text)) == runs
+    return jax.jit(jax.value_and_grad(fn)).lower(params, tokens).compile().as_text()
+
+
+@pytest.mark.parametrize("which", LAYERS_UNDER_REMAT)
+def test_a_layers_remat_runs_a_full_causal_cores_forward_kernel_once_on_a_v5e(one_chip, which):
+    """The text holds ONE ``splash_mha_fwd`` custom call for latent attention's core over the whole triangle (two
+    until PR 44: the replay ran it again for ``out`` and ``logsumexp``) and two for the band."""
+    make, (B, S, d), runs = LAYERS_UNDER_REMAT[which]
+    assert len(_forward_kernels(_layer_text(one_chip, make, B, S, d))) == runs
+
+
+# ``laguna``'s two attention layers at their published sizes over 16,384 positions: query heads held here, the
+# rotation's width, YaRN's keys
+QK_PREPARATIONS = {"laguna_sliding_36_heads_whole_head_turned": (36, "sliding_attention", dict(window=512)),
+                   "laguna_full_24_heads_yarn_over_the_first_64": (24, "full_attention", {})}
+
+
+@pytest.mark.parametrize("which", QK_PREPARATIONS)
+def test_a_laguna_layers_qk_preparation_compiles_for_v5e_to_one_pass_each_way(one_chip, which):
+    """``CausalGQAttention`` with a head of 128 through ``decode`` with ``remat: true``, value and gradient: from
+    ``q_proj``'s and ``k_proj``'s results to the core's operands the text holds ``head_turn_fwd`` four times (q and k,
+    the forward pass and the replay) and ``head_turn_bwd`` twice, and under ``attn_proj`` NO instruction of the ENTRY
+    computation writes a float32 tensor of the heads' size (``f32[1,16384,heads,128]`` or its halves: until PR 45 the
+    normed head and the two halves of ``rotate_half`` went to memory in float32, three times a step), nor copies the
+    projection's result into another layout on its way into the kernel."""
+    from distar_tpu.model import default_laguna_config, laguna
+
+    heads, kind, more = QK_PREPARATIONS[which]
+    turn = default_laguna_config()["rope_parameters"][kind]
+    more = dict(more, rope_theta=turn["rope_theta"], rotary_dim=int(128 * turn["partial_rotary_factor"]),
+                yarn={k: turn[k] for k in laguna.YARN_KEYS} if turn["rope_type"] == "yarn" else None)
+    text = _layer_text(one_chip, lambda s: s.CausalGQAttention(heads, 4, 128, eps=1e-6, dtype=jnp.bfloat16, **more), 1, 16384, 3072)
+    calls = re.findall(r"^\s*(?:ROOT )?%(head_turn_[a-z]+)[.\d]* = .*custom-call\(", text, flags=re.M)
+    assert sorted(calls) == ["head_turn_bwd"] * 2 + ["head_turn_fwd"] * 4
+    # the ENTRY computation's instructions under the scope: (name, result type, opcode)
+    under = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", ln).groups()
+             for ln in text[text.index("\nENTRY "):].split("\n") if "/attn_proj/" in ln and " = " in ln]
+    assert len(under) > 20 and {"q_proj", "k_proj", "o_proj"} <= set(re.findall(r"/attn_proj/(\w+)/dot_general", text))
+    wide = [name for name, result, _ in under if re.search(rf"f32\[(1,)?16384,({heads}|4),(128|64)\]", result)]
+    assert not wide, wide
+    copied = [name for name, result, opcode in under if opcode == "copy" and re.search(rf"bf16\[1,16384,({heads * 128}|512)\]", result)]
+    assert not copied, copied
 
 
 # ``tools/tpu_compile_check.py --what``: the forward kernels of the whole step's text (one a full-causal core, two

@@ -4,7 +4,9 @@ token model's gated delta rule (``ops/delta.py``: one Gated DeltaNet layer's
 rule at ``qwen3_next_train_b2s8k``'s shape under ``jax.checkpoint``, its two
 Pallas kernels beside the XLA form: PR 37's fragment; ``ops/ssm.py``: one
 Mamba-2 layer's scan at ``nemotron_twotower_train_b2s8k``'s shape, the same
-way: PR 40's). ``--ops`` names the fragments to run (all of them by default).
+way: PR 40's; ``ops/head_turn.py``: one attention layer's q/k preparation at
+``laguna_train_b1s16k``'s two shapes, the same way: PR 45's). ``--ops`` names
+the fragments to run (all of them by default).
 
 Runs each kernel at actor-inference and learner-training shapes, in bf16 and
 f32, forward and forward+backward, against its jnp reference: first a
@@ -43,7 +45,7 @@ def _time(fn, args, iters=30, warmup=3):
     return (time.perf_counter() - t0) / iters * 1e6  # us
 
 
-OPS = ("masked_attention", "scatter_add", "gated_delta_rule", "mamba2_scan")
+OPS = ("masked_attention", "scatter_add", "gated_delta_rule", "mamba2_scan", "head_turn")
 
 
 def run(platform: str = "auto", iters: int = 30, ops=OPS) -> dict:
@@ -194,6 +196,36 @@ def run(platform: str = "auto", iters: int = 30, ops=OPS) -> dict:
         },
         scan_args, (0, 1, 2, 3, 4), 4e-2,
     )
+
+    # one attention layer's q/k preparation as laguna_train_b1s16k runs it, from the projections' results to the core's
+    # operands (head norm, rotation, the queries' scale, the kernel's layout), under jax.checkpoint: a sliding layer
+    # (36 query heads over 4, the whole head turned) and a full one (24 over 4, YaRN over the first 64 with its factor)
+    from distar_tpu.model import default_laguna_config
+    from distar_tpu.ops import head_turn, sequence
+
+    turns = default_laguna_config()["rope_parameters"]
+    yarn = {k: turns["full_attention"][k] for k in ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")}
+    for layer, Hq, R, inv_freq, factor in (
+            ("sliding", 36, 128, sequence.rope_inv_freq(128, turns["sliding_attention"]["rope_theta"]), None),
+            ("full", 24, 64, jnp.asarray(sequence.yarn_inv_freq(64, turns["full_attention"]["rope_theta"], **yarn), jnp.float32),
+             turns["full_attention"]["attention_factor"])):
+        S, Hq, Hkv, D = (16384, Hq, 4, 128) if native else (128, 3, 1, 128)
+        turn_args = (jnp.asarray(rng.standard_normal((1, S, Hq * D)), jnp.bfloat16),
+                     jnp.asarray(rng.standard_normal((1, S, Hkv * D)), jnp.bfloat16),
+                     jnp.asarray(1.0 + 0.1 * rng.standard_normal(D), jnp.float32),
+                     jnp.asarray(1.0 + 0.1 * rng.standard_normal(D), jnp.float32))
+
+        def xla(q, k, wq, wk):
+            return (sequence.head_turn_xla(q, Hq, wq, inv_freq, factor, D ** -0.5, 1e-6),
+                    sequence.head_turn_xla(k, Hkv, wk, inv_freq, factor, None, 1e-6))
+
+        def fused(q, k, wq, wk):
+            tables = head_turn.turn_tables(S, D, inv_freq, factor)
+            return (head_turn.head_turn(q, Hq, wq, tables, R, D ** -0.5, 1e-6, resolve_interpret(None)),
+                    head_turn.head_turn(k, Hkv, wk, tables, R, None, 1e-6, resolve_interpret(None)))
+
+        bench("head_turn", f"{layer} 1x{S}x{Hq}/{Hkv}x{D} r{R} bfloat16", xla,
+              {"xla": jax.checkpoint(xla), "pallas": jax.checkpoint(fused)}, turn_args, (0, 1, 2, 3), 4e-2)
 
     dev = jax.devices()[0]
     return {
